@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring import dense_attention
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
 def bench(fn, args, iters=20):
@@ -42,6 +43,7 @@ def main():
     p.add_argument("--head-dim", type=int, default=64)
     p.add_argument("--iters", type=int, default=20)
     args = p.parse_args()
+    enable_compile_cache()
     dt = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
     H, D = args.heads, args.head_dim
     rng = np.random.RandomState(0)

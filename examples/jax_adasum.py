@@ -28,8 +28,10 @@ def main():
     from jax.sharding import PartitionSpec as P
 
     import horovod_tpu as hvd
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
     from horovod_tpu.utils.compat import shard_map
 
+    enable_compile_cache()
     hvd.init()
 
     if hvd.mode() == "process":

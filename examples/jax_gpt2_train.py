@@ -45,12 +45,14 @@ def main():
     import optax
 
     import horovod_tpu as hvd
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
     from horovod_tpu.models.pipelined import PipelinedLM
     from horovod_tpu.models.transformer import GPT2_CONFIGS, TransformerLM
     from horovod_tpu.parallel.mesh import create_mesh
     from horovod_tpu.parallel.sharding import DEFAULT_RULES, PIPELINE_RULES
     from horovod_tpu.parallel.train import lm_loss, make_train_step
 
+    enable_compile_cache()
     hvd.init()
     axes = {k: v for k, v in
             [("pp", args.pp), ("dp", args.dp), ("ep", args.ep),

@@ -61,8 +61,10 @@ def main():
     import optax
 
     import horovod_tpu as hvd
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
     from horovod_tpu.models import MnistCNN
 
+    enable_compile_cache()
     hvd.init()
 
     x, y = (load_mnist(args.data_dir) if args.data_dir

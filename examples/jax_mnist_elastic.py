@@ -29,9 +29,11 @@ def main():
     import optax
 
     import horovod_tpu as hvd
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
     from horovod_tpu.elastic.state import JaxState
     from horovod_tpu.models import MnistCNN
 
+    enable_compile_cache()
     hvd.init()
 
     from jax_mnist import synthetic_mnist

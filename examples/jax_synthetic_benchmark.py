@@ -34,6 +34,7 @@ def main():
     import optax
 
     import horovod_tpu as hvd
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
     from horovod_tpu.models import get_model
     from horovod_tpu.parallel.mesh import create_mesh
     from horovod_tpu.parallel.train import (
@@ -42,6 +43,7 @@ def main():
         softmax_xent,
     )
 
+    enable_compile_cache()
     hvd.init()
     n = len(jax.devices())
     mesh = create_mesh({"dp": n})

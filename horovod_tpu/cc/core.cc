@@ -79,6 +79,18 @@ class Pool {
     for (int i = 0; i < workers; ++i)
       threads_.emplace_back([this] { worker_loop(); });
   }
+  // Only ever runs on a pool that lost the creation race in pool() and
+  // was handed no job: its idle workers must be joined, because
+  // destroying a joinable std::thread calls std::terminate.
+  ~Pool() {
+    {
+      std::lock_guard<std::mutex> lk(mu_);
+      stop_ = true;
+      ++epoch_;
+      cv_.notify_all();
+    }
+    for (auto& t : threads_) t.join();
+  }
   int workers() const { return static_cast<int>(threads_.size()); }
 
   bool try_run(int nchunks, const std::function<void(int)>& fn) {
@@ -123,6 +135,7 @@ class Pool {
       {
         std::unique_lock<std::mutex> lk(mu_);
         cv_.wait(lk, [&] { return epoch_ != seen; });
+        if (stop_) return;
         seen = epoch_;
         ++active_;
       }
@@ -143,6 +156,7 @@ class Pool {
   std::atomic<int> pending_{0};
   int nchunks_ = 0;
   int active_ = 0;
+  bool stop_ = false;
   uint64_t epoch_ = 0;
   std::vector<std::thread> threads_;
 };

@@ -54,13 +54,10 @@ def _prec(dtype):
     return None if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
 
 
-try:  # Pallas import kept optional: CPU-only deployments without the
-    # TPU plugin still import this module (interpret mode covers tests).
-    from jax.experimental import pallas as pl
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from .pallas_platform import call_by_platform
 
 
 def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
@@ -230,33 +227,41 @@ def _prep(q, k, v, mask, block_q: int):
 
 
 def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
-               interpret: bool) -> "tuple[jax.Array, jax.Array]":
+               interpret: Optional[bool]
+               ) -> "tuple[jax.Array, jax.Array]":
     B, S, H, D = q.shape
     scale = 1.0 / float(np.sqrt(D))
     qb, kb_arr, vb, mask2, _, bq, bk, Sq, Sk, plain = _prep(q, k, v,
                                                             mask, block_q)
     grid = (B * H, Sq // bq)
-    out, lse = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, plain=plain),
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
-        ],
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0)),
-            # mask indexed by batch = bh // H (static H via closure).
-            pl.BlockSpec((1, 1, Sk), lambda bh, qi, H=H: (bh // H, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, bq), lambda bh, qi: (bh, 0, qi)),
-        ],
-        interpret=interpret,
-    )(qb, kb_arr, vb, mask2)
+
+    def call(interp: bool):
+        return pl.pallas_call(
+            functools.partial(_kernel, scale=scale, causal=causal,
+                              block_q=bq, block_k=bk, plain=plain),
+            out_shape=[
+                jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
+            ],
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
+                pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0)),
+                pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0)),
+                # mask indexed by batch = bh // H (static H via closure).
+                pl.BlockSpec((1, 1, Sk),
+                             lambda bh, qi, H=H: (bh // H, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
+                pl.BlockSpec((1, 1, bq), lambda bh, qi: (bh, 0, qi)),
+            ],
+            interpret=interp,
+            name="flash_attention_fwd",
+        )
+
+    out, lse = call_by_platform(call, qb, kb_arr, vb, mask2,
+                                interpret=interpret)
 
     out = out[:, :S]
     # Slice lse to the real rows too, so the backward's re-pad is the
@@ -373,7 +378,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
-               interpret: bool):
+               interpret: Optional[bool]):
     """Blockwise backward: same VMEM-bounded structure as the forward —
     the (S, S) score matrix is never materialized in HBM."""
     B, S, H, D = q.shape
@@ -394,34 +399,38 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1).reshape(B * H, 1, Sq)
 
-    from jax.experimental.pallas import tpu as pltpu
-
     full_q = pl.BlockSpec((1, Sq, D), lambda bh, ki: (bh, 0, 0))
     row_q = pl.BlockSpec((1, 1, Sq), lambda bh, ki: (bh, 0, 0))
     blk_k = pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0))
 
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_dqkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, plain=plain),
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
-        ],
-        grid=(B * H, Sk // bk),
-        in_specs=[
-            full_q,
-            blk_k, blk_k,
-            pl.BlockSpec((1, 1, bk), lambda bh, ki, H=H: (bh // H, 0, ki)),
-            full_q, row_q, row_q,
-        ],
-        out_specs=[
-            full_q,       # dq: one block per (b, h), flushed on last ki
-            blk_k, blk_k,
-        ],
-        scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32)],
-        interpret=interpret,
-    )(qb, kb_arr, vb, mask2, dob, lse, delta)
+    def call(interp: bool):
+        return pl.pallas_call(
+            functools.partial(_dqkv_kernel, scale=scale, causal=causal,
+                              block_q=bq, block_k=bk, plain=plain),
+            out_shape=[
+                jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
+                jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
+            ],
+            grid=(B * H, Sk // bk),
+            in_specs=[
+                full_q,
+                blk_k, blk_k,
+                pl.BlockSpec((1, 1, bk),
+                             lambda bh, ki, H=H: (bh // H, 0, ki)),
+                full_q, row_q, row_q,
+            ],
+            out_specs=[
+                full_q,   # dq: one block per (b, h), flushed on last ki
+                blk_k, blk_k,
+            ],
+            scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32)],
+            interpret=interp,
+            name="flash_attention_bwd",
+        )
+
+    dq, dk, dv = call_by_platform(call, qb, kb_arr, vb, mask2, dob, lse,
+                                  delta, interpret=interpret)
 
     def from_bh(x, S_):
         return x[:, :S_].reshape(B, H, S_, D).transpose(0, 2, 1, 3)
@@ -438,38 +447,23 @@ def flash_attention(q, k, v, mask=None, causal: bool = True,
 
     `block_q=None` auto-selects by sequence length (128 below S=2048,
     256 at 2048, 512 beyond — measured full-train-step crossover on
-    v5e, r5); both vjp passes resolve it identically in `_prep`. `interpret=None` auto-selects:
-    compiled Pallas on TPU, interpreter elsewhere (so CPU tests and the
-    8-device virtual mesh still run)."""
-    if not HAVE_PALLAS:
-        raise ImportError(
-            "flash_attention needs jax.experimental.pallas; use "
-            "attn_impl='dense' (or ring/ulysses) on this installation"
-        )
-    out, _ = _flash_fwd(q, k, v, mask, causal, block_q,
-                        _resolve_interpret(interpret))
+    v5e, r5); both vjp passes resolve it identically in `_prep`.
+    `interpret=None` follows ops/pallas_platform.py: the interpreter
+    where the call is lowered for the CPU (the tests, the virtual
+    8-device mesh), the compiled kernel on anything else."""
+    out, _ = _flash_fwd(q, k, v, mask, causal, block_q, interpret)
     return out
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # pragma: no cover
-        return True
-
-
 def _fwd(q, k, v, mask, causal, block_q, interpret):
-    out, lse = _flash_fwd(q, k, v, mask, causal, block_q,
-                          _resolve_interpret(interpret))
+    out, lse = _flash_fwd(q, k, v, mask, causal, block_q, interpret)
     return out, (q, k, v, mask, out, lse)
 
 
 def _bwd(causal, block_q, interpret, residuals, g):
     q, k, v, mask, out, lse = residuals
     dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q,
-                            _resolve_interpret(interpret))
+                            interpret)
     return dq, dk, dv, None
 
 

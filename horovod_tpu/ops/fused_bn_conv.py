@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .pallas_platform import call_by_platform
+
 
 def _reference_bn_relu_matmul(x, mu, var, gamma, beta, w, eps):
     """Unfused composition (also the custom_vjp's differentiation
@@ -72,12 +74,20 @@ def fused_bn_relu_matmul(
     """
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     M, Cin = x.shape
     Cout = w.shape[1]
     block_m = min(block_m, M)
     block_n = min(block_n, Cout)
+    if block_n % 128:
+        # Mosaic refuses the per-block lane slices below unless they
+        # start on a 128-lane boundary ("cannot statically prove that
+        # index in dimension 1 is a multiple of 128"); interpret mode
+        # never shows it, so say so before any platform is involved.
+        raise ValueError(
+            f"block_n={block_n} (Cout={Cout}) is not a multiple of 128: "
+            "the TPU kernel slices its output-channel blocks on 128-lane "
+            "boundaries; use Cout >= 128 and a block_n that is a "
+            "multiple of 128")
     if M % block_m or Cout % block_n:
         raise ValueError(f"M={M} / Cout={Cout} not divisible by blocks "
                          f"({block_m}, {block_n})")
@@ -134,33 +144,38 @@ def fused_bn_relu_matmul(
                 s2_ref[...] = part2
 
         grid = (n_i, Cout // block_n)
-        y, s1, s2 = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, Cin), lambda i, j: (i, 0)),
-                pl.BlockSpec((Cin,), lambda i, j: (0,)),
-                pl.BlockSpec((Cin,), lambda i, j: (0,)),
-                pl.BlockSpec((Cin,), lambda i, j: (0,)),
-                pl.BlockSpec((Cin,), lambda i, j: (0,)),
-                pl.BlockSpec((Cin, block_n), lambda i, j: (0, j)),
-            ],
-            out_specs=[
-                pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-                pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
-                pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((M, Cout), x.dtype),
-                jax.ShapeDtypeStruct((1, Cout), jnp.float32),
-                jax.ShapeDtypeStruct((1, Cout), jnp.float32),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((1, Cout), jnp.float32),
-                pltpu.VMEM((1, Cout), jnp.float32),
-            ],
-            interpret=interpret,
-        )(x, mu, var, gamma, beta, w)
+
+        def call(interp: bool):
+            return pl.pallas_call(
+                kernel,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((block_m, Cin), lambda i, j: (i, 0)),
+                    pl.BlockSpec((Cin,), lambda i, j: (0,)),
+                    pl.BlockSpec((Cin,), lambda i, j: (0,)),
+                    pl.BlockSpec((Cin,), lambda i, j: (0,)),
+                    pl.BlockSpec((Cin,), lambda i, j: (0,)),
+                    pl.BlockSpec((Cin, block_n), lambda i, j: (0, j)),
+                ],
+                out_specs=[
+                    pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
+                    pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+                    pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((M, Cout), x.dtype),
+                    jax.ShapeDtypeStruct((1, Cout), jnp.float32),
+                    jax.ShapeDtypeStruct((1, Cout), jnp.float32),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((1, Cout), jnp.float32),
+                    pltpu.VMEM((1, Cout), jnp.float32),
+                ],
+                interpret=interp,
+            )
+
+        y, s1, s2 = call_by_platform(call, x, mu, var, gamma, beta, w,
+                                     interpret=interpret)
         return y, s1[0], s2[0]
 
     def kernel(x_ref, mu_ref, var_ref, gamma_ref, beta_ref, w_ref,
@@ -187,29 +202,34 @@ def fused_bn_relu_matmul(
             s2_ref[...] += part2
 
     grid = (Cout // block_n, n_i)
-    y, s1, s2 = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, Cin), lambda j, i: (i, 0)),
-            pl.BlockSpec((Cin,), lambda j, i: (0,)),
-            pl.BlockSpec((Cin,), lambda j, i: (0,)),
-            pl.BlockSpec((Cin,), lambda j, i: (0,)),
-            pl.BlockSpec((Cin,), lambda j, i: (0,)),
-            pl.BlockSpec((Cin, block_n), lambda j, i: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, block_n), lambda j, i: (i, j)),
-            pl.BlockSpec((1, block_n), lambda j, i: (0, j)),
-            pl.BlockSpec((1, block_n), lambda j, i: (0, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((M, Cout), x.dtype),
-            jax.ShapeDtypeStruct((1, Cout), jnp.float32),
-            jax.ShapeDtypeStruct((1, Cout), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, mu, var, gamma, beta, w)
+
+    def call(interp: bool):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_m, Cin), lambda j, i: (i, 0)),
+                pl.BlockSpec((Cin,), lambda j, i: (0,)),
+                pl.BlockSpec((Cin,), lambda j, i: (0,)),
+                pl.BlockSpec((Cin,), lambda j, i: (0,)),
+                pl.BlockSpec((Cin,), lambda j, i: (0,)),
+                pl.BlockSpec((Cin, block_n), lambda j, i: (0, j)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_m, block_n), lambda j, i: (i, j)),
+                pl.BlockSpec((1, block_n), lambda j, i: (0, j)),
+                pl.BlockSpec((1, block_n), lambda j, i: (0, j)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((M, Cout), x.dtype),
+                jax.ShapeDtypeStruct((1, Cout), jnp.float32),
+                jax.ShapeDtypeStruct((1, Cout), jnp.float32),
+            ],
+            interpret=interp,
+        )
+
+    y, s1, s2 = call_by_platform(call, x, mu, var, gamma, beta, w,
+                                 interpret=interpret)
     return y, s1[0], s2[0]
 
 

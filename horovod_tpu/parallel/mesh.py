@@ -22,8 +22,12 @@ import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..utils.logging import get_logger
+
+logger = get_logger()
 
 # Canonical axis order, outer (slow, DCN-tolerant) → inner (fast ICI).
 AXIS_ORDER = ("pp", "dp", "ep", "sp", "tp")
@@ -76,15 +80,10 @@ def create_mesh(
     )
     shape = tuple(axis_sizes[a] for a in names)
 
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=devices,
-            allow_split_physical_axes=allow_split_physical_axes,
-        )
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(
+        shape, devices=devices,
+        allow_split_physical_axes=allow_split_physical_axes,
+    )
     return Mesh(dev_array, axis_names=tuple(names))
 
 
@@ -102,18 +101,24 @@ def create_hybrid_mesh(
         list(ici_axis_sizes) + list(dcn_axis_sizes),
         key=lambda a: AXIS_ORDER.index(a) if a in AXIS_ORDER else len(AXIS_ORDER),
     )
+    mesh_shape = [ici_axis_sizes.get(a, 1) for a in names]
+    dcn_shape = [dcn_axis_sizes.get(a, 1) for a in names]
     try:
-        from jax.experimental import mesh_utils
-
-        mesh_shape = [ici_axis_sizes.get(a, 1) for a in names]
-        dcn_shape = [dcn_axis_sizes.get(a, 1) for a in names]
         dev_array = mesh_utils.create_hybrid_device_mesh(
             mesh_shape, dcn_shape, devices=devices
         )
-        return Mesh(dev_array, axis_names=tuple(names))
-    except Exception:
-        merged = {a: ici_axis_sizes.get(a, 1) * dcn_axis_sizes.get(a, 1) for a in names}
+    except ValueError as exc:
+        # Devices that carry no slice index (CPU, a single slice) or a
+        # slice count that does not match the DCN axes: there is no DCN
+        # boundary to respect, so build the merged single-slice mesh —
+        # and say so, because on a real multi-slice job it means the
+        # DCN axes were mis-sized.
+        logger.warning("create_hybrid_mesh: %s; building a single-slice "
+                       "mesh over the merged axes instead", exc)
+        merged = {a: ici_axis_sizes.get(a, 1) * dcn_axis_sizes.get(a, 1)
+                  for a in names}
         return create_mesh(merged, devices)
+    return Mesh(dev_array, axis_names=tuple(names))
 
 
 def data_parallel_mesh(devices: Optional[Sequence] = None, axis_name: str = HVD_AXIS) -> Mesh:

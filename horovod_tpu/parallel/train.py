@@ -26,8 +26,7 @@ from flax.core import FrozenDict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .sharding import DEFAULT_RULES, batch_spec, filter_rules, logical_sharding
-from ..utils.compat import (set_mesh as _set_mesh,
-                            tree_leaves_with_path as _tree_leaves_with_path)
+from ..utils.compat import set_mesh as _set_mesh
 
 
 @dataclasses.dataclass
@@ -161,8 +160,8 @@ def make_train_step(
         # Build opt-state shardings by structural mapping: any leaf whose
         # shape matches a param leaf gets that param's sharding, else
         # replicated. optax states are pytrees of param-shaped moments.
-        flat_params = _tree_leaves_with_path(abstract_params)
-        flat_pshard = _tree_leaves_with_path(pshard)
+        flat_params = jax.tree.leaves_with_path(abstract_params)
+        flat_pshard = jax.tree.leaves_with_path(pshard)
         pmap_by_path = {
             jax.tree_util.keystr(kp): s
             for (kp, _), (_, s) in zip(flat_params, flat_pshard)

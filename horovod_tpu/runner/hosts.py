@@ -11,6 +11,8 @@ serves ssh clusters and TPU slices.
 from __future__ import annotations
 
 import dataclasses
+import glob
+import os
 from typing import Dict, List, Optional
 
 
@@ -121,25 +123,25 @@ def get_host_assignments(
 def discover_tpu_hosts() -> Optional[List[HostInfo]]:
     """TPU-VM slice topology → hosts (one slot per host process; chips
     are addressed through the jax mesh, not extra ranks). Returns None
-    off-TPU. (Replaces the reference's ssh+NIC probing,
+    off a multi-host slice. (Replaces the reference's ssh+NIC probing,
     ref: runner/driver/driver_service.py:124-192, per SURVEY.md §5.8.)
 
-    Detection order: Cloud TPU VM metadata env (TPU_WORKER_HOSTNAMES,
-    set on every worker of a pod slice), then an initialized
-    jax.distributed process group."""
-    import os
-
+    Reads the Cloud TPU VM metadata env only (TPU_WORKER_HOSTNAMES, set
+    on every worker of a pod slice). The launcher must finish discovery
+    with no jax backend initialised: a chip belongs to one process at a
+    time, and a parent that opened it would keep it from its workers."""
     names = os.environ.get("TPU_WORKER_HOSTNAMES", "")
-    if names:
-        hosts = [h.strip() for h in names.split(",") if h.strip()]
-        if len(hosts) > 1:
-            return [HostInfo(h, 1) for h in hosts]
-    try:
-        import jax
+    hosts = [h.strip() for h in names.split(",") if h.strip()]
+    if len(hosts) > 1:
+        return [HostInfo(h, 1) for h in hosts]
+    return None
 
-        n = jax.process_count()
-        if n <= 1:
-            return None
-        return [HostInfo(f"process-{i}", 1) for i in range(n)]
-    except Exception:  # pragma: no cover
-        return None
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from the device nodes the
+    runtime opens (/dev/vfio/<n> on v5e and later, /dev/accel<n>
+    before) — without loading jax or libtpu. The PCI bus is not used:
+    it lists chips the VM was not given."""
+    return (len(glob.glob("/dev/vfio/[0-9]*"))
+            or len(glob.glob("/dev/accel[0-9]*")))
+

@@ -10,8 +10,10 @@ Per slot, the launcher exports the HOROVOD_RANK/SIZE/LOCAL_*/CROSS_* env
 (exactly the reference's gloo env contract so `hvd.init()` picks process
 mode), plus the rendezvous address of the driver's HTTP KV server the
 TCP backend full-meshes through. Remote hosts launch over ssh; TPU-VM
-slices are discovered from jax process topology instead of NIC probing
-(SURVEY.md §5.8). Elastic mode (`--min-np/--max-np/--host-discovery-
+slices are discovered from the slice's metadata env instead of NIC
+probing (SURVEY.md §5.8). The launcher itself never initialises a jax
+backend, and several local workers on an accelerator platform each get
+one chip (`_chip_env`). Elastic mode (`--min-np/--max-np/--host-discovery-
 script`) is driven by runner.elastic.driver.
 """
 from __future__ import annotations
@@ -28,7 +30,15 @@ from typing import Dict, List, Optional, Sequence
 
 from ..utils import env as env_cfg
 from . import config_parser
-from .hosts import HostInfo, SlotInfo, get_host_assignments, parse_hostfile, parse_hosts
+from .hosts import (
+    HostInfo,
+    SlotInfo,
+    discover_tpu_hosts,
+    get_host_assignments,
+    local_tpu_chips,
+    parse_hostfile,
+    parse_hosts,
+)
 from .rendezvous_server import RendezvousServer
 
 _LOCAL_NAMES = {"localhost", "127.0.0.1", "::1"}
@@ -49,6 +59,40 @@ def is_local_host(hostname: str) -> bool:
         return False
 
 
+def _chip_env(slot: SlotInfo,
+              extra_env: Optional[Dict[str, str]]) -> Dict[str, str]:
+    """One process for each chip: when a host runs several workers on
+    an accelerator platform, local rank i gets chip i and nothing else.
+    Workers inherit the platform (JAX_PLATFORMS) from the launcher's
+    environment unless `extra_env` names one; only an explicit `cpu`
+    turns the pinning off, because with the variable unset jax itself
+    picks the TPU wherever one is attached.
+
+    Established on libtpu 0.0.34 / v5e 2x2: all three variables are
+    needed — with TPU_VISIBLE_CHIPS alone every process after the first
+    aborts on libtpu's multi-process lockfile — and the process then
+    sees exactly one device (which jax numbers 0 in every process)."""
+    platforms = (extra_env or {}).get(
+        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+    if slot.local_size <= 1 or platforms.split(",")[0].strip() == "cpu":
+        return {}
+    if is_local_host(slot.hostname):
+        chips = local_tpu_chips()
+        if chips == 0:
+            return {}  # no TPU on this host: nothing to divide
+        if slot.local_size > chips:
+            raise ValueError(
+                f"{slot.local_size} workers on {slot.hostname} but only "
+                f"{chips} TPU chip(s): a chip belongs to one process at "
+                "a time — lower -np, or set JAX_PLATFORMS=cpu for "
+                "host-only workers")
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 def slot_env(
     slot: SlotInfo,
     rendezvous_addr: str,
@@ -57,7 +101,9 @@ def slot_env(
     elastic: bool = False,
     secret_key: Optional[bytes] = None,
 ) -> Dict[str, str]:
-    """The worker env contract (ref: gloo_run.py:65-198 _slot_info_to_command)."""
+    """The worker env contract (ref: gloo_run.py:65-198
+    _slot_info_to_command) — the one place worker environments are
+    built, shared by hvdrun, runner.run() and the Spark runners."""
     env = {
         env_cfg.RANK: str(slot.rank),
         env_cfg.SIZE: str(slot.size),
@@ -77,6 +123,7 @@ def slot_env(
         from .util import secret as secret_util
 
         env[env_cfg.SECRET_KEY] = secret_util.key_to_env(secret_key)
+    env.update(_chip_env(slot, extra_env))
     if extra_env:
         env.update(extra_env)
     return env
@@ -657,8 +704,6 @@ def run_commandline(argv: Optional[Sequence[str]] = None) -> int:
         # the requested -np fits the slice (np unset, or one rank per
         # pod host); otherwise keep the historical local launch so
         # `hvdrun -np 4` on a pod worker still runs 4 local processes.
-        from .hosts import discover_tpu_hosts
-
         hosts = discover_tpu_hosts()
         if hosts and args.num_proc not in (None, len(hosts)):
             hosts = None

@@ -1,180 +1,41 @@
-"""Version-compat shims for jax APIs used throughout the framework."""
+"""The one place the framework spells jax's mesh / shard_map surface.
+
+Written for the jax this repository runs on (0.9). No fallbacks for
+other versions live here; the wrappers exist so call sites share one
+spelling of partial manualization and of the VMA annotation.
+"""
 from __future__ import annotations
 
 import jax
 
-# shard_map moved from jax.experimental to the jax namespace.
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.4.35ish
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False,
-                  axis_names=None):
-        kw = {}
-        if axis_names is not None:
-            # Partial manualization: only these axes become manual;
-            # the rest stay under GSPMD inside the body.
-            kw["axis_names"] = frozenset(axis_names)
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_vma=check_rep, **kw)
-        except TypeError:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              **kw)
-
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _concrete_mesh(mesh):
-        """Resolve an AbstractMesh to the ambient CONCRETE mesh.
-
-        jax >= 0.5 accepts an abstract mesh in shard_map (devices come
-        from jax.sharding.set_mesh at run time); on this jax an
-        abstract mesh silently lowers the surrounding jit as a
-        single-device program (mhlo.num_partitions stays 1), and XLA's
-        ShardingRemover then replaces the manual-region
-        SPMDShardToFullShape custom-calls with their differently-shaped
-        operands — an INTERNAL RET_CHECK crash at compile time. The
-        concrete mesh is recovered from whichever ambient context is
-        live: set_mesh()'s thread-local, else the legacy `with mesh:`
-        resource env."""
-        import jax.sharding as _jshard
-
-        if not isinstance(mesh, getattr(_jshard, "AbstractMesh", ())):
-            return mesh  # already concrete
-        cands = [getattr(_ambient, "mesh", None)]
-        try:
-            from jax._src import mesh as _mesh_lib
-
-            cands.append(_mesh_lib.thread_resources.env.physical_mesh)
-        except Exception:  # pragma: no cover - private-API drift
-            pass
-        for cand in cands:
-            if (cand is not None and not cand.empty
-                    and cand.axis_names == tuple(mesh.axis_names)
-                    and tuple(cand.shape[a] for a in cand.axis_names)
-                    == tuple(mesh.shape[a] for a in mesh.axis_names)):
-                return cand
-        raise ValueError(
-            "shard_map over an abstract mesh needs an ambient concrete "
-            "mesh on this jax version — enter one via "
-            "horovod_tpu.utils.compat.set_mesh(mesh) (or `with mesh:`) "
-            f"matching axes {tuple(mesh.axis_names)}"
-        )
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False,
-                  axis_names=None):
-        mesh = _concrete_mesh(mesh)
-        # Partial manualization (`auto=` on the experimental API) is
-        # broken on this jax/jaxlib line: the SPMD partitioner rejects
-        # the PartitionId that `axis_index` lowers to ("PartitionId
-        # instruction is not supported for SPMD partitioning"), and even
-        # collective-only bodies trip hard CHECK failures in the
-        # partitioner's manual-subgroup handling (spmd_partitioner.cc:512,
-        # hlo_sharding_util.cc:2750 — process aborts, not exceptions).
-        # Fallback: FULL manualization. Axes absent from in_specs/
-        # out_specs are treated as replicated, so the body sees exactly
-        # the same per-shard shapes as under partial manualization and
-        # the results are identical; what is lost is only GSPMD
-        # auto-sharding of the body along the unnamed axes (a perf
-        # concern on real meshes, not a semantics change — and this
-        # branch only runs on jax versions that cannot compile the
-        # partial-manual program at all).
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_rep)
-
-
-import contextlib
-import threading
-
-_ambient = threading.local()
+def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False,
+              axis_names=None):
+    kw = {}
+    if axis_names is not None:
+        # Partial manualization: only these axes become manual;
+        # the rest stay under GSPMD inside the body.
+        kw["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep, **kw)
 
 
 def set_mesh(mesh):
-    """Ambient-mesh context (jax >= 0.5 jax.sharding.set_mesh). On older
-    jax, enter the legacy `with mesh:` context AND track the mesh in a
-    thread-local so get_abstract_mesh() below can answer at trace
-    time."""
-    try:
-        return jax.sharding.set_mesh(mesh)
-    except AttributeError:
-        pass
-
-    @contextlib.contextmanager
-    def _cm():
-        prev = getattr(_ambient, "mesh", None)
-        _ambient.mesh = mesh
-        try:
-            with mesh:
-                yield
-        finally:
-            _ambient.mesh = prev
-
-    return _cm()
+    """Ambient-mesh context: nested shard_maps inside a model resolve
+    their axes against it at trace time."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def get_abstract_mesh():
-    """jax >= 0.5 jax.sharding.get_abstract_mesh; on older jax, the
-    abstract mesh of whatever set_mesh() above made ambient (None when
-    nothing is)."""
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        m = getattr(_ambient, "mesh", None)
-        return None if m is None else m.abstract_mesh
+    return jax.sharding.get_abstract_mesh()
 
 
 def axis_size(axis_name):
-    """Static size of a named mesh axis from inside shard_map/pmap.
-    jax >= 0.5 spells it lax.axis_size; on older versions psum of the
-    literal 1 constant-folds to the same static Python int."""
-    import jax
-
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
-def force_host_device_count(n: int):
-    """Force `n` virtual CPU host devices on BOTH jax lines.
-
-    jax >= 0.5 has the `jax_num_cpu_devices` config option; older jax
-    only honors the XLA flag, and ONLY if it is set before the first
-    backend creation (clear_backends does not re-read XLA_FLAGS for an
-    already-materialized client on old jax) — so call this before any
-    `jax.devices()`/computation. An existing count in XLA_FLAGS is
-    OVERRIDDEN, not kept: a stale =1 from an earlier run silently
-    starving a multi-device benchmark is worse than clobbering."""
-    import os
-    import re
-
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   os.environ.get("XLA_FLAGS", "")).strip()
-    os.environ["XLA_FLAGS"] = (
-        flags + f" --xla_force_host_platform_device_count={n}"
-    ).strip()
-    import jax.extend.backend as _jeb
-
-    _jeb.clear_backends()
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:  # jax < 0.5: the XLA flag did the job
-        pass
-    _jeb.clear_backends()
+    """Static size of a named mesh axis from inside shard_map."""
+    return jax.lax.axis_size(axis_name)
 
 
 def axis_index(axis_name):
-    """`lax.axis_index` through the compat surface.
-
-    On jax 0.4.37 a bare `lax.axis_index` inside a partial-manual
-    shard_map lowers to an HLO PartitionId that the SPMD partitioner
-    rejects outright; the shard_map wrapper above therefore
-    full-manualizes on that version, under which this lowering is
-    valid again. Call sites that run inside shard_map bodies should
-    use this instead of `lax.axis_index` directly so the two shims
-    stay paired."""
-    import jax
-
     return jax.lax.axis_index(axis_name)
 
 
@@ -183,40 +44,4 @@ def pvary(x, axis):
     No-op under check_vma=False (our shard_map default); under VMA
     tracking it keeps jax.grad cotangents rank-local instead of
     auto-psummed, preserving Horovod's per-rank-gradient semantics."""
-    import jax
-
-    try:
-        return jax.lax.pcast(x, to="varying", axes=axis)
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.lax.pvary(x, axis)
-    except AttributeError:
-        # jax 0.4.x: no VMA tracking at all (shard_map check_rep=False
-        # is the only mode we use) — the annotation is a true no-op.
-        return x
-
-
-def tree_map(f, *trees):
-    return jax.tree.map(f, *trees)
-
-
-def tree_leaves(tree):
-    return jax.tree.leaves(tree)
-
-
-def tree_flatten(tree):
-    return jax.tree.flatten(tree)
-
-
-def tree_unflatten(treedef, leaves):
-    return jax.tree.unflatten(treedef, leaves)
-
-
-def tree_leaves_with_path(tree):
-    """jax >= 0.5 jax.tree.leaves_with_path; older jax spells it
-    jax.tree_util.tree_leaves_with_path."""
-    try:
-        return jax.tree.leaves_with_path(tree)
-    except AttributeError:
-        return jax.tree_util.tree_leaves_with_path(tree)
+    return jax.lax.pcast(x, axis, to="varying")

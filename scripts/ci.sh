@@ -26,11 +26,7 @@ echo "=== engine/transport subset, HOROVOD_DISABLE_NATIVE=1 (numpy fallback pari
 HOROVOD_DISABLE_NATIVE=1 python -m pytest $ENGINE_SUBSET -q -m 'not slow'
 
 echo "=== unit + integration tests (fast tier — FULLY GREEN tier-1) ==="
-# The 7 known jax<0.5 failures (gpipe x2 + pipelined-lm, flash-GSPMD x2,
-# bert-ring-mask, elastic-gspmd-traced) were fixed by the
-# partial-manual shard_map compat shims (utils/compat.py); tier-1 is
-# asserted fully green — ANY failed test fails CI, no known-failure
-# allowance remains.
+# ANY failed test fails CI: there is no known-failure allowance.
 if ! python -m pytest tests/ -q -m 'not slow'; then
   echo "tier-1 is no longer fully green"
   exit 1

@@ -89,6 +89,9 @@ def main():
     ap.add_argument("--chunks", type=int, default=1)
     args = ap.parse_args()
 
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     S, B, C, N = args.seq, args.batch, args.chunk, args.chunks
     full = time_variant("full", "xent", None, S, B, C, N)
     mean = time_variant("loss_mean", "mean", None, S, B, C, N)

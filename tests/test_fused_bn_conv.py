@@ -136,3 +136,12 @@ def test_resnet50_fused_stage_trains():
         losses.append(float(loss))
     assert all(np.isfinite(v) for v in losses), losses
     assert losses[-1] < losses[0], losses
+
+
+def test_block_n_not_a_lane_multiple_is_rejected_up_front():
+    """Cout=64 → block_n=64: Mosaic refuses the kernel's lane slices on
+    a chip, which interpret mode never shows, so it is a ValueError
+    before any platform is involved."""
+    args = _inputs(cout=64)
+    with pytest.raises(ValueError, match="not a multiple of 128"):
+        fused_bn_relu_matmul(*args, interpret=True)

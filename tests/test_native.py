@@ -480,3 +480,41 @@ def test_disable_native_env_all_wrappers(monkeypatch):
     assert st["disabled"] and not st["loaded"]
     monkeypatch.delenv("HOROVOD_DISABLE_NATIVE")
     assert native.available()
+
+
+def test_first_calls_from_many_threads_do_not_abort():
+    """The worker pool is created lazily by whichever thread calls in
+    first; threads that lose that race must discard their pool without
+    destroying running std::threads (std::terminate → SIGABRT, seen as
+    soon as two engines started at once on a many-core host). The race
+    exists only before a process's pool does, so every round is a
+    forked child (the pool is keyed by pid) of one fresh, still
+    single-threaded interpreter."""
+    import subprocess
+    import sys
+
+    code = (
+        "import os, sys, threading\n"
+        "import horovod_tpu.cc.native as native\n"
+        "assert native.load() is not None\n"
+        "def race():\n"
+        "    sys.setswitchinterval(1e-6)\n"
+        "    gate = threading.Barrier(16)\n"
+        "    def first_call():\n"
+        "        gate.wait(timeout=30)\n"
+        "        assert native.threads() == 16\n"
+        "    ts = [threading.Thread(target=first_call) for _ in range(16)]\n"
+        "    [t.start() for t in ts]\n"
+        "    [t.join(timeout=60) for t in ts]\n"
+        "    return any(t.is_alive() for t in ts)\n"
+        "for _ in range(20):\n"
+        "    pid = os.fork()\n"
+        "    if pid == 0:\n"
+        "        os._exit(1 if race() else 0)\n"
+        "    status = os.waitpid(pid, 0)[1]\n"
+        "    assert status == 0, f'child ended with wait status {status}'\n"
+    )
+    env = {**os.environ, "HOROVOD_NATIVE_THREADS": "16"}
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-1500:]

@@ -247,7 +247,7 @@ def test_two_engine_merged_trace_shares_ids(monkeypatch):
                for p in (0, 1)}
         shared = ids[0] & ids[1]
         assert len(shared) >= 4, (len(ids[0]), len(ids[1]), len(shared))
-        # Each shared id covers the full span taxonomy on some rank:
+        # Each shared id covers every kind of span on some rank:
         names = {e["name"] for e in xs
                  if e["args"]["trace_id"] in shared}
         assert any(n.startswith("exec.allreduce") for n in names), names
