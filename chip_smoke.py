@@ -34,11 +34,16 @@ imports jax):
             through the host engine (built from cc/core.cc, never the
             NumPy fallback).
 
-Exit code 0 and one JSON object as the last line of stdout only when
-every phase passed on a TPU. Any failed phase, a platform other than
-the TPU, or no accelerator: non-zero, and no result line. Compile
-seconds and step milliseconds in the result are information for the
-reader, not a claim.
+Exit code 0 only when every phase passed on a TPU, and then the last
+line of stdout is the result, one JSON object with exactly these keys:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+with the device as jax reported it. The stdout line before it,
+`report: {...}`, carries the versions, the cache directory, compile
+seconds, step milliseconds and memory — information for the reader,
+not a claim. Any failed phase, a platform other than the TPU, or no
+accelerator: non-zero, and nothing on stdout.
 """
 from __future__ import annotations
 
@@ -561,9 +566,8 @@ def parent() -> int:
         print(f"chip_smoke.py: FAILED: {exc}", file=sys.stderr)
         return 1
     train = phases["train"]
-    print(json.dumps({
-        "ok": True,
-        "device": train["device"],
+    # Information for the reader, not a claim: the line before the last.
+    print("report: " + json.dumps({
         "versions": train["versions"],
         "cache_dir": train["cache_dir"],
         "compile_seconds": train["compile_seconds"],
@@ -575,7 +579,10 @@ def parent() -> int:
         "kernel_max_error": {k: v for k, v in phases["kernel"].items()
                              if k != "phase_seconds"},
         "launcher": {"np": phases["launcher"]["np"]},
+        "claim": None,
     }))
+    # The result: exactly these keys, the device as jax reported it.
+    print(json.dumps({"ok": True, "device": train["device"]}), flush=True)
     return 0
 
 

@@ -181,6 +181,49 @@ def test_chip_smoke_refuses_the_cpu():
     assert out.stdout == ""  # no result line
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys(
+        monkeypatch, capsys):
+    """The driver parses the last stdout line: "ok" and "device"
+    (platform, kind, count) and nothing else. Everything informational
+    goes on the line before it."""
+    import json
+
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}
+    fake = {
+        "kernel": {"causal_s2048_block_q256": {"out": 0.004},
+                   "phase_seconds": 1.0},
+        "train": {"device": device, "versions": {"jax": "0.9.0"},
+                  "cache_dir": "/x/.jax_cache", "compile_seconds": {},
+                  "step_ms": {}, "memory_gib": {}, "losses": {},
+                  "phase_seconds": 2.0},
+        "launcher": {"np": 4, "seconds": 3.0, "phase_seconds": 3.0},
+    }
+    asked = []
+
+    def run_phase(name, extra, seconds):
+        asked.append((name, extra))
+        return fake[name]
+
+    monkeypatch.setattr(chip_smoke, "run_phase", run_phase)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert chip_smoke.parent() == 0
+    assert asked[-1] == ("launcher", ["--chips", "4"])
+    lines = capsys.readouterr().out.splitlines()
+    result = json.loads(lines[-1])
+    assert result == {"ok": True, "device": device}
+    assert set(result) == {"ok", "device"}
+    assert set(result["device"]) == {"platform", "kind", "count"}
+    assert isinstance(result["device"]["count"], int)
+    report = json.loads(lines[-2].removeprefix("report: "))
+    assert report["claim"] is None and "versions" in report
+
+
 def test_bench_unknown_device_kind_is_an_error():
     sys.path.insert(0, REPO)
     try:
