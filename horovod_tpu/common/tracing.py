@@ -78,6 +78,45 @@ CAT_STEP = "step"
 
 
 # ---------------------------------------------------------------------------
+# Under jit: regions and step spans in the XLA profile (docs/tracing.md,
+# "Under jit"). The ring below cannot see a compiled step; these names
+# go where `jax.profiler` looks. Scopes are `jax.named_scope`s: they
+# prefix the name stack XLA keeps as metadata of every op issued inside
+# them, forward and backward, and change nothing else of the program.
+# Spans are `annotate`d on the host and land in the profiler's file
+# beside the device lanes, on its clock; with no profile being taken an
+# annotation is a flag test. The benchmark's reader
+# (benchmark/trace_regions.py) keeps its own copy of this vocabulary; a
+# test compares the two.
+
+#: log-softmax, one-hot product and mean of `parallel.train.softmax_xent`.
+SCOPE_LOSS = "hvd.loss"
+#: The optimizer pass: `make_train_step`'s `tx.update` + `apply_updates`,
+#: `DistributedOptimizer`'s inner `optimizer.update`.
+SCOPE_OPTIMIZER = "hvd.optimizer"
+#: One call of a step function the library built (`make_train_step`'s
+#: step, `wrap_step`'s wrapper); arg `step` = number of this call of
+#: this function in the process.
+SPAN_STEP = "hvd.step"
+#: In `wrap_step`: flatten the arguments, make the cache key, look up.
+SPAN_WRAP_PREPARE = "hvd.wrap_step.prepare"
+#: In `wrap_step`, on a cache miss: build the `shard_map` / `jax.jit`.
+SPAN_WRAP_BUILD = "hvd.wrap_step.build"
+#: In `wrap_step`: the call of the built function, jit's dispatch.
+SPAN_WRAP_CALL = "hvd.wrap_step.call"
+
+
+def annotate(name: str, **args):
+    """A host span of the XLA profile: a context manager that records
+    `name` with `args` on the calling thread while `jax.profiler` is
+    tracing, and costs a flag test while it is not. A span's parent is
+    the span that encloses it on the same thread."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+# ---------------------------------------------------------------------------
 # Thread-local trace-id scope (the engine sets it around each response;
 # same shape as backend/base.py's channel scope).
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from ..common import basics
+from ..common import basics, tracing
 from ..common.types import ReduceOp
 from ..ops import allreduce as _allreduce_dispatch
 from ..ops.compression import Compression, NoneCompressor
@@ -188,7 +188,8 @@ def DistributedOptimizer(
             grads, op, axis_name, prescale_factor, postscale_factor,
             compression, fuse,
         )
-        return optimizer.update(red, state, params, **extra)
+        with jax.named_scope(tracing.SCOPE_OPTIMIZER):
+            return optimizer.update(red, state, params, **extra)
 
     tx = optax.GradientTransformationExtraArgs(init_fn, update_fn)
     if backward_passes_per_step > 1:

@@ -10,12 +10,13 @@ program with ICI collectives — no background thread, no negotiation.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..common import basics
+from ..common import basics, tracing
 from ..utils.compat import shard_map
 
 
@@ -60,60 +61,74 @@ def wrap_step(
     # training step would re-trace.
     cache = {}
 
+    def build(m, an, args):
+        repl = set(replicated_argnums)
+        if sharded_argnums is not None:
+            shard = set(sharded_argnums)
+            repl = set(range(len(args))) - shard
+        in_specs = tuple(
+            jax.tree.map(lambda _: P() if i in repl else P(an), args[i])
+            for i in range(len(args))
+        )
+        out_spec = P() if out_replicated else P(an)
+
+        # Mark replicated inputs as axis-varying inside the body.
+        # Without this, jax's manual-axes tracking auto-psums the
+        # cotangent of any replicated input, so a user's jax.grad
+        # inside the step already returns the cross-rank SUM and a
+        # subsequent hvd.allreduce(AVERAGE) cannot recover the
+        # per-rank average (it sees identical values on every
+        # shard). pvary keeps grads rank-local — the reference's
+        # semantics, where each rank owns its gradient until the
+        # explicit allreduce (ref: horovod/torch/optimizer.py:114-149).
+        def local_fn(*inner):
+            from ..utils.compat import pvary
+
+            inner = tuple(
+                jax.tree.map(lambda x: pvary(x, an), a)
+                if i in repl else a
+                for i, a in enumerate(inner)
+            )
+            return fn(*inner)
+
+        # out_specs is a prefix pytree: one spec covers the whole
+        # output tree (eval_shape-ing fn here would trace its
+        # collectives outside the mesh and hit unbound axis names).
+        sm = shard_map(
+            local_fn, mesh=m,
+            in_specs=in_specs,
+            out_specs=out_spec,
+        )
+        if jit:
+            sm = jax.jit(sm, donate_argnums=donate_argnums)
+        return sm
+
+    # Each call is one step span of the XLA profile, numbered from 0,
+    # with the wrapper's own parts as its children (docs/tracing.md
+    # "Under jit").
+    calls = itertools.count()
+
     @functools.wraps(fn)
     def wrapped(*args):
-        m = mesh if mesh is not None else basics.mesh()
-        an = axis_name if axis_name is not None else basics.axis_name()
-        if m is None:
-            raise RuntimeError("wrap_step requires mesh mode (hvd.init())")
-        leaves, treedef = jax.tree.flatten(args)
-        key = (
-            id(m), treedef,
-            tuple((getattr(l, "shape", ()), str(getattr(l, "dtype", type(l))))
-                  for l in leaves),
-        )
-        sm = cache.get(key)
-        if sm is None:
-            repl = set(replicated_argnums)
-            if sharded_argnums is not None:
-                shard = set(sharded_argnums)
-                repl = set(range(len(args))) - shard
-            in_specs = tuple(
-                jax.tree.map(lambda _: P() if i in repl else P(an), args[i])
-                for i in range(len(args))
-            )
-            out_spec = P() if out_replicated else P(an)
-
-            # Mark replicated inputs as axis-varying inside the body.
-            # Without this, jax's manual-axes tracking auto-psums the
-            # cotangent of any replicated input, so a user's jax.grad
-            # inside the step already returns the cross-rank SUM and a
-            # subsequent hvd.allreduce(AVERAGE) cannot recover the
-            # per-rank average (it sees identical values on every
-            # shard). pvary keeps grads rank-local — the reference's
-            # semantics, where each rank owns its gradient until the
-            # explicit allreduce (ref: horovod/torch/optimizer.py:114-149).
-            def local_fn(*inner):
-                from ..utils.compat import pvary
-
-                inner = tuple(
-                    jax.tree.map(lambda x: pvary(x, an), a)
-                    if i in repl else a
-                    for i, a in enumerate(inner)
+        with tracing.annotate(tracing.SPAN_STEP, step=next(calls)):
+            m = mesh if mesh is not None else basics.mesh()
+            an = axis_name if axis_name is not None else basics.axis_name()
+            if m is None:
+                raise RuntimeError(
+                    "wrap_step requires mesh mode (hvd.init())")
+            with tracing.annotate(tracing.SPAN_WRAP_PREPARE):
+                leaves, treedef = jax.tree.flatten(args)
+                key = (
+                    id(m), treedef,
+                    tuple((getattr(l, "shape", ()),
+                           str(getattr(l, "dtype", type(l))))
+                          for l in leaves),
                 )
-                return fn(*inner)
-
-            # out_specs is a prefix pytree: one spec covers the whole
-            # output tree (eval_shape-ing fn here would trace its
-            # collectives outside the mesh and hit unbound axis names).
-            sm = shard_map(
-                local_fn, mesh=m,
-                in_specs=in_specs,
-                out_specs=out_spec,
-            )
-            if jit:
-                sm = jax.jit(sm, donate_argnums=donate_argnums)
-            cache[key] = sm
-        return sm(*args)
+                sm = cache.get(key)
+            if sm is None:
+                with tracing.annotate(tracing.SPAN_WRAP_BUILD):
+                    sm = cache[key] = build(m, an, args)
+            with tracing.annotate(tracing.SPAN_WRAP_CALL):
+                return sm(*args)
 
     return wrapped

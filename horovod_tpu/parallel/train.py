@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -26,6 +27,7 @@ from flax.core import FrozenDict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .sharding import DEFAULT_RULES, batch_spec, filter_rules, logical_sharding
+from ..common import tracing
 from ..utils.compat import set_mesh as _set_mesh
 
 
@@ -58,10 +60,14 @@ def softmax_xent(logits, labels) -> jax.Array:
     the reduction (a compare-select epilogue — the (B,S,V) one-hot is
     never materialized), while take_along_axis lowers to a TPU gather
     that measures 12-20% SLOWER on the loss at both BERT and GPT-2
-    bench shapes (v5e, fwd+bwd in-jit loops, r4)."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=jnp.float32)
-    return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
+    bench shapes (v5e, fwd+bwd in-jit loops, r4).
+
+    Its ops, forward and backward, carry the loss scope in the XLA
+    profile (`tracing.SCOPE_LOSS`; docs/tracing.md "Under jit")."""
+    with jax.named_scope(tracing.SCOPE_LOSS):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=jnp.float32)
+        return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
 
 
 def lm_loss(logits, ids) -> jax.Array:
@@ -247,8 +253,9 @@ def make_train_step(
 
         (loss, new_extra), grads = jax.value_and_grad(
             compute_loss, has_aux=True)(state.params)
-        upd, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, upd)
+        with jax.named_scope(tracing.SCOPE_OPTIMIZER):
+            upd, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, upd)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -282,8 +289,17 @@ def make_train_step(
 
             return run
 
+        # Each call of the step is one step span of the XLA profile,
+        # numbered from 0 (docs/tracing.md "Under jit").
+        calls = itertools.count()
+
+        @functools.wraps(step_jit)
+        def wrapped_step(*a, **kw):
+            with tracing.annotate(tracing.SPAN_STEP, step=next(calls)), \
+                    _set_mesh(mesh):
+                return step_jit(*a, **kw)
+
         wrapped_init = with_mesh(init_jit)
-        wrapped_step = with_mesh(step_jit)
         # The raw (untraced) step lets callers embed the step in a larger
         # jit — e.g. a lax.scan over K steps — without nesting pjit
         # inside jit, which compiles far slower than tracing the body
