@@ -10,9 +10,12 @@ TPU-native re-design of the reference optimizer wrappers:
 In JAX, optimizers are pure gradient transformations (optax), so the
 wrapper is itself an optax transformation that allreduces the incoming
 gradient pytree before the inner optimizer sees it. Under jit, the
-allreduce lowers to ICI psum ops that XLA overlaps with the backward
-pass — the same overlap the reference gets from per-layer async hooks,
-achieved by the compiler instead of a background thread.
+allreduce lowers to ICI psum ops that XLA may overlap with the backward
+pass — the overlap the reference gets from per-layer async hooks, left
+to the compiler instead of a background thread. On TPU the compiler
+does not do so by default (measured for the GSPMD trainer, whose step
+now asks for it: parallel/train.py); this wrapper's step gets the
+defaults and has no multi-chip measurement yet.
 
 `backward_passes_per_step` local accumulation maps to optax.MultiSteps
 wrapping (accumulate locally, communicate once per effective step),

@@ -86,5 +86,10 @@ def test_gpt2_small_train_step_compiles_for_v5e(v5e_devices, n):
                                         sharding=step_fn.shardings[1]))
     assert "tpu_custom_call" in lowered.as_text()
     compiled = lowered.compile()
+    text = compiled.as_text()
     if n > 1:
-        assert "all-reduce" in compiled.as_text()
+        assert "all-reduce" in text
+    # The data-parallel options (make_train_step sets them: the described
+    # devices are TPU chips) are taken by this libtpu and change the
+    # schedule: asynchronous, fused reduces at dp=4, none on one chip.
+    assert ("async-collective-start" in text) == (n > 1)
