@@ -42,28 +42,34 @@ def transformer(dims: dict, seq: int) -> float:
     return 3.0 * (matmul + attention)
 
 
-def attention_kernel_cost(batch: int, seq: int, heads: int, head_dim: int,
-                          causal: bool, backward: bool,
+def attention_kernel_cost(batch: int, seq: int, heads: int, qk_head_dim: int,
+                          v_head_dim: int, causal: bool, backward: bool,
                           itemsize: int = 2) -> tuple[float, float]:
-    """(FLOPs, HBM bytes) one call of a fused attention kernel needs.
+    """(FLOPs, HBM bytes) one call of a fused attention kernel needs, for
+    heads whose queries and keys are `qk_head_dim` wide and whose values
+    are `v_head_dim` wide (the same number in GPT-2 and BERT; a latent
+    attention's heads differ).
 
-    Forward: S = Q K^T and O = P V, 2 S^2 D each per (batch, head).
-    Backward: dV = P^T dO, dP = dO V^T, dQ = dS K, dK = dS^T Q — four
-    matmuls of that size. The score recomputation a flash backward does
-    is the kernel's choice, not the model's, and is not counted (the
-    same rule as for `mfu`). Causal halves both.
+    Forward: S = Q K^T, 2 S^2 Dqk, and O = P V, 2 S^2 Dv, per (batch,
+    head). Backward: dQ = dS K and dK = dS^T Q at Dqk, dV = P^T dO and
+    dP = dO V^T at Dv: twice the forward. The score recomputation a
+    flash backward does is the kernel's choice, not the model's, and is
+    not counted (the same rule as for `mfu`). Causal halves both.
 
-    Bytes are the tensors that must cross HBM once: forward reads Q, K,
-    V and writes O and the per-row logsumexp (f32); backward reads Q, K,
-    V, O, dO and the logsumexp and writes dQ, dK, dV.
+    Bytes are the tensors that must cross HBM once: forward reads Q, K
+    (Dqk) and V (Dv) and writes O (Dv) and the per-row logsumexp (f32);
+    backward reads Q, K, V, O, dO and the logsumexp and writes dQ, dK,
+    dV: four tensors of each width.
     """
-    tensor = batch * seq * heads * head_dim * itemsize
+    per_dim = batch * seq * heads * itemsize
     rows = batch * heads * seq * 4
-    matmuls, tensors = (4, 8) if backward else (2, 4)
-    flops = matmuls * 2.0 * batch * heads * seq * seq * head_dim
+    passes = 2 if backward else 1
+    flops = passes * 2.0 * batch * heads * seq * seq * (qk_head_dim
+                                                        + v_head_dim)
     if causal:
         flops /= 2
-    return flops, float(tensors * tensor + rows)
+    tensors = 2 * passes * per_dim * (qk_head_dim + v_head_dim)
+    return flops, float(tensors + rows)
 
 
 def least_seconds(flops: float, bytes_: float, peaks) -> tuple[float, str]:

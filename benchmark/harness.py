@@ -17,12 +17,12 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import importlib
 import importlib.metadata
 import json
 import math
 import pathlib
-import shutil
 import statistics
 import tempfile
 import time
@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import correct, trace_reduce
+from benchmark import correct, trace_reduce, trace_regions
 
 SPANS = ("feed", "dispatch", "log_fetch")
 WARMUP_INTERVALS = 2
@@ -109,7 +109,7 @@ def load_cell(index_path: pathlib.Path, name: str) -> Cell:
     )
 
 
-def _named(dotted: str):
+def named(dotted: str):
     """`module.function` under this package, e.g. `flops.transformer`."""
     module, _, attr = dotted.rpartition(".")
     return getattr(importlib.import_module(f"benchmark.{module}"), attr)
@@ -178,14 +178,20 @@ class Loop:
     dispatch_s: list = dataclasses.field(default_factory=list)
     hbm_bytes: list = dataclasses.field(default_factory=list)  # per device
     first_call_s: float = 0.0
+    unmoved: int = 0     # leaves the first step left as they were
 
     def start(self):
         """Make the state and take the first step alone, on the clock:
-        that call compiles the step or loads it from the cache."""
+        that call compiles the step or loads it from the cache. Off the
+        clock, the parameters' checksums before and after it."""
         self.state = jax.block_until_ready(self.trainer.init())
+        before = jax.block_until_ready(
+            correct.leaf_checksums(self.trainer.params(self.state)))
         t0 = time.perf_counter()
         self.interval(steps=1)
         self.first_call_s = time.perf_counter() - t0
+        self.unmoved = correct.leaves_unmoved(
+            before, correct.leaf_checksums(self.trainer.params(self.state)))
 
     def interval(self, steps: Optional[int] = None, record: bool = False):
         t0 = time.perf_counter()
@@ -286,6 +292,17 @@ class Context:
     peaks: Any
     phases: dict                              # name -> Phase
     tables: Optional[trace_reduce.Tables]     # None in an untraced run
+    trace_file: Optional[str] = None          # the traced run's .xplane.pb
+
+    @functools.cached_property
+    def regions(self) -> Optional[trace_regions.Regions]:
+        """The same window by the names the program gives itself
+        (trace_regions.py), read from the file on first use; None in an
+        untraced run."""
+        if self.trace_file is None:
+            return None
+        return trace_regions.reduce(trace_regions.load(self.trace_file),
+                                    self.cell.traffic["log_every"])
 
 
 def _quantile(samples, q: float) -> float:
@@ -316,8 +333,6 @@ def _set_up(cell: Cell, devices, seed: int, stages: dict):
     program compared with the reference. Returns the loops by phase name
     and the reference errors; `stages` receives the seconds of each
     part."""
-    from horovod_tpu.parallel.train import lm_loss
-
     model = make_model(cell)
     build = importlib.import_module(
         f"benchmark.trainers.{cell.traffic['trainer']}").build
@@ -336,9 +351,9 @@ def _set_up(cell: Cell, devices, seed: int, stages: dict):
         if label == first:
             at = time.perf_counter()
             errors = correct.measure_against_reference(
-                lambda p, ids: model.apply({"params": p}, ids), lm_loss,
-                reference, trainer.params(loop.state), cell.dims,
-                cell.traffic["seq"], seed)
+                trainer.objective, reference, trainer.params(loop.state),
+                cell.dims, cell.traffic["seq"], seed,
+                cell.config["grad_leaves"])
             stages["reference comparison"] = time.perf_counter() - at
         at = time.perf_counter()
         for _ in range(WARMUP_INTERVALS):
@@ -349,14 +364,28 @@ def _set_up(cell: Cell, devices, seed: int, stages: dict):
     return loops, errors
 
 
-def _reduce_trace(kept: str, keep: bool, log_every: int):
-    try:
-        path = next(pathlib.Path(kept).rglob("*.xplane.pb"))
-        return trace_reduce.reduce(trace_reduce.load(str(path), SPANS),
-                                   log_every)
-    finally:
-        if not keep:
-            shutil.rmtree(kept, ignore_errors=True)
+def _reduce_trace(kept: str, log_every: int) -> tuple:
+    """The traced run's file and its tables."""
+    path = str(next(pathlib.Path(kept).rglob("*.xplane.pb")))
+    return path, trace_reduce.reduce(trace_reduce.load(path, SPANS),
+                                     log_every)
+
+
+def _compared(errors: dict, unmoved: int, loss: float, expect: dict,
+              compiles: int, not_finite: int, checksums: list) -> dict:
+    """Every number `correct` compares, beside its limit."""
+    rows = {name: {"value": value, "limit": correct.tolerance(name)}
+            for name, value in errors.items()}
+    rows["leaves_unmoved"] = {"value": unmoved, "limit": 0}
+    rows["loss_after_20"] = {"value": loss, "limit": [
+        expect["loss_after_20"] - expect["loss_band"],
+        expect["loss_after_20"] + expect["loss_band"]]}
+    rows["compiles_in_window"] = {"value": compiles, "limit": 0}
+    rows["losses_not_finite"] = {"value": not_finite, "limit": 0}
+    if checksums:
+        rows["distinct_replica_checksums"] = {
+            "value": len(set(checksums)), "limit": 1}
+    return rows
 
 
 def run_cell(index_path, name: str, *, seed: int, seconds: float,
@@ -371,7 +400,7 @@ def run_cell(index_path, name: str, *, seed: int, seconds: float,
         raise RuntimeError(f"cell {name} needs {cell.chips} chips; jax "
                            f"reports {len(devices)}")
     devices = list(devices[:cell.chips])
-    flops_per_token = _named(cell.config["flops_per_token"])(
+    flops_per_token = named(cell.config["flops_per_token"])(
         cell.dims, cell.traffic["seq"])
     log_every = cell.traffic["log_every"]
 
@@ -381,11 +410,15 @@ def run_cell(index_path, name: str, *, seed: int, seconds: float,
         loops, errors = _set_up(cell, devices, seed, stages)
     _info(cell=name, seed=seed, versions=software,
           flops_per_token=flops_per_token, reference_errors=errors,
-          tolerances={"logits": correct.LOGITS_TOL, "grad": correct.GRAD_TOL},
           setup_stages_s=stages, setup_jax=in_setup.summary())
 
     # The window: each phase for its share, one state alive at a time.
-    kept = trace_dir or (tempfile.mkdtemp(prefix="trace-") if trace else None)
+    # A traced run's files stay until the per-layer readers have run:
+    # in `trace_dir` where one is given, else in a directory of its own
+    # that goes with the run (also when the run ends in an error).
+    scratch = (tempfile.TemporaryDirectory(prefix="trace-")
+               if trace and trace_dir is None else None)
+    kept = trace_dir or (scratch.name if scratch else None)
     setup_s = time.perf_counter() - t0
     with JaxEvents() as in_window:
         for phase in cell.phases:
@@ -402,8 +435,8 @@ def run_cell(index_path, name: str, *, seed: int, seconds: float,
     checksums = main.trainer.checksums(main.state) if cell.chips > 1 else []
     for loop in loops.values():
         loop.trainer.close()
-    tables = _reduce_trace(kept, trace_dir is not None,
-                           log_every) if trace else None
+    trace_file, tables = (_reduce_trace(kept, log_every) if trace
+                          else (None, None))
     phases = {
         name: Phase(
             name=name, chips=len(loop.devices),
@@ -419,11 +452,13 @@ def run_cell(index_path, name: str, *, seed: int, seconds: float,
               first_call_s=p.first_call_s)
 
     loss = main.losses.get(LOSS_AT_STEP, math.nan)
+    unmoved = sum(loop.unmoved for loop in loops.values())
     checks = {
         "platform_is_tpu": devices[0].platform == "tpu",
         "no_compile_in_window": in_window.programs == 0,
         "losses_finite": all(loop.failed == 0 for loop in loops.values()),
         "agrees_with_reference": not correct.beyond_tolerance(errors),
+        "state_moves": unmoved == 0,
         "loss_in_band": correct.loss_in_band(
             loss, cell.expect["loss_after_20"], cell.expect["loss_band"]),
         "replicas_agree": len(set(checksums)) <= 1,
@@ -437,6 +472,8 @@ def run_cell(index_path, name: str, *, seed: int, seconds: float,
         "attempted": sum(loop.attempted for loop in loops.values()),
         "failed": sum(loop.failed for loop in loops.values()),
     }
+    compared = _compared(errors, unmoved, loss, cell.expect,
+                         in_window.programs, result["failed"], checksums)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(jax.devices()),
               "memory_peak_bytes": max(
@@ -461,21 +498,26 @@ def run_cell(index_path, name: str, *, seed: int, seconds: float,
         result["metrics"] = {k: {"value": values[k], "unit": unit}
                              for k, unit in cell.end_to_end.items()}
         result["device"] = device
+        result["compared"] = compared
         return result
 
     _info(heaviest_single_ops_ms_per_step=[
         [text, s / tables.steps * 1e3] for text, s in tables.heaviest])
-    ctx = Context(cell=cell, peaks=peaks, phases=phases, tables=tables)
+    ctx = Context(cell=cell, peaks=peaks, phases=phases, tables=tables,
+                  trace_file=trace_file)
     result["metrics"] = {}
     for metric, unit in cell.per_layer.items():
         value = importlib.import_module(
             f"benchmark.layer_metrics.{metric}").compute(ctx)
         if value is not None:
             result["metrics"][metric] = {"value": value, "unit": unit}
+    if scratch is not None:
+        scratch.cleanup()
     result["device"] = dict(device, busy_s=tables.busy_s_mean,
                             window_s=tables.window_s)
     result["breakdown"] = {
         "device_ops": tables.top_ops(10),
         "idle_gaps": [[span, s] for span, s in tables.idle_gaps],
     }
+    result["compared"] = compared
     return result

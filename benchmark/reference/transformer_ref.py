@@ -84,21 +84,25 @@ def _f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
 
 
-def forward(params, ids, dims: dict):
-    """Logits (B, S, V) in float32. Each block is its own jitted call,
-    so only one layer's S x S scores are alive at a time."""
+def forward(params, ids, dims: dict, choices=None):
+    """(logits (B, S, V) in float32, None): this stack chooses nothing,
+    so `choices` is ignored and there is no `choice_slack` to return.
+    Each block is its own jitted call, so only one layer's S x S scores
+    are alive at a time."""
     one_block = jax.jit(functools.partial(block, causal=dims["causal"]))
     with jax.default_matmul_precision("highest"):
         params = _f32(params)
         x = jax.jit(embed)(params, ids)
         for i in range(dims["n_layers"]):
             x = one_block(x, params["stack"][f"layer_{i}"])
-        return jax.jit(head)(params, x)
+        return jax.jit(head)(params, x), None
 
 
-def loss(params, ids, dims: dict):
-    """Mean next-token cross-entropy (the cells' training loss), as one
-    differentiable function for the gradient comparison."""
+def loss(params, ids, dims: dict, choices=None):
+    """Mean next-token cross-entropy, the float32 counterpart of the
+    objective the cells' trainers differentiate (`lm_objective` in
+    trainers/__init__.py), as one differentiable function for the
+    gradient comparison. `choices` is ignored."""
     with jax.default_matmul_precision("highest"):
         params = _f32(params)
         x = embed(params, ids)

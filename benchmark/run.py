@@ -57,6 +57,12 @@ def main() -> int:
         seconds=args.seconds, trace=bool(args.trace), devices=devices,
         peaks=chip_peaks, t0=T0, trace_dir=args.trace_dir)
     print(json.dumps(result), flush=True)
+    # The numbers `correct` rests on, each beside its limit, as the last
+    # lines of stderr: what a record of a failed run keeps.
+    for name, row in result["compared"].items():
+        print(f"compared: {name} {row['value']} limit {row['limit']}",
+              file=sys.stderr)
+    print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -39,19 +39,16 @@ that XLA fuses into each weight-gradient matmul is `backward` (or
 fusions of their own; in a user's step the update fused under the root
 of their own unnamed `optax.apply_updates` is `unscoped`.
 
-How a later PR adds a metric that reads a region or a span. The harness
-(`harness.py`, which a PR that is not a `benchmark` PR may not edit)
-deletes a traced run's files before the per-layer readers run and hands
-them `ctx.tables` only, so no reader under `layer_metrics/` can reach
-this file's tables yet. A `benchmark` PR gives `harness.Context` one
-field, loaded on first use from the kept `.xplane.pb` (`load` and
-`reduce` below), and moves `_reduce_trace`'s `rmtree` behind the metrics
-loop; then each name of `METRICS` is a three-line module
-(`return ctx.regions.metrics()[__name__...]`) and an entry at the end of
+How a later PR adds a metric that reads a region or a span. A reader
+under `layer_metrics/` reaches this file's tables as `ctx.regions`
+(`harness.Context` loads them on first use from the traced run's file,
+which stays until the readers have run): each name of `METRICS` is a
+module there (`return ctx.regions.metrics()[<name>]`) and an entry of
 `per_layer`. A new region is a name in `REGIONS` and a rule in
-`region_of`; a new span is a name in the program's vocabulary and here.
-Until then `run.py --trace 1 --trace-dir <dir>` keeps the file and this
-module's command reads it.
+`region_of`, a new span a name in the program's vocabulary and here:
+both are edits to this file, a `benchmark` PR's business.
+`run.py --trace 1 --trace-dir <dir>` keeps the file for this module's
+command.
 """
 from __future__ import annotations
 

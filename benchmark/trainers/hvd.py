@@ -12,7 +12,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
 from benchmark.correct import replica_checksums
-from benchmark.trainers import Trainer, optimizer
+from benchmark.trainers import Trainer, lm_objective, optimizer
 from horovod_tpu.parallel.train import lm_loss
 
 
@@ -59,6 +59,7 @@ def build(model, phase: dict, devices, seed: int) -> Trainer:
         step=step,
         put=lambda ids: jax.device_put(ids, batch_sharding),
         params=lambda state: state[0],
+        objective=lm_objective(model, lm_loss),
         checksums=lambda state: replica_checksums(state[0]),
         close=hvd.shutdown,
     )

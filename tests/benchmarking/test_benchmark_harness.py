@@ -6,6 +6,8 @@ Nothing here is a measurement: times from these runs are never looked
 at, only that every declared metric comes out and the result object is
 the contract's.
 """
+import dataclasses
+import functools
 import json
 import math
 import pathlib
@@ -15,12 +17,15 @@ import sys
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark import correct, flops, harness, layer_metrics, peaks
 from benchmark.reference import transformer_ref
 from benchmark.trace_reduce import DeviceTrace, Event, Trace
+from benchmark.trace_regions import Op, RegionTrace, Span
+from benchmark.trainers import lm_objective
 from horovod_tpu.parallel.train import lm_loss
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -49,6 +54,29 @@ def _chip_trace(chips: int, steps: int = 16) -> Trace:
     return Trace(devices={i: device for i in range(chips)}, host_spans=spans)
 
 
+def _chip_regions(steps: int = 16) -> RegionTrace:
+    """Chip 0 of `_chip_trace` as `trace_regions.load` would give it: the
+    same ops with name stacks as the program's scopes shape them, and
+    the program's host spans. A step: forward 4, backward 2, loss and
+    head 2, optimizer 0.9, unscoped 0.1 ms."""
+    stacks = ["jit(train_step)/jvp(TransformerLM)/stack/layer_0/mlp/wi/dot:",
+              "jit(train_step)/jvp(TransformerLM)/stack/layer_0/attn/call:",
+              "jit(train_step)/transpose(jvp(TransformerLM))/stack/attn/call:",
+              "", "jit(train_step)/hvd.optimizer/mul:",
+              "jit(train_step)/transpose(jvp(TransformerLM))/hvd.loss/sub:"]
+    device = _chip_trace(1, steps).devices[0]
+    ops = tuple(Op(ev.name, ev.start, ev.end, stacks[i % 6], "")
+                for i, ev in enumerate(device.ops))
+    spans = []
+    for i in range(steps):
+        s = i * 0.010
+        spans += [Span("dispatch", s, s + 0.0015, 1, None),
+                  Span("hvd.step", s + 0.0001, s + 0.0011, 1, i),
+                  Span("hvd.wrap_step.prepare", s + 0.0002, s + 0.0004, 1,
+                       None)]
+    return RegionTrace(ops=ops, programs=device.programs, spans=tuple(spans))
+
+
 def _run(index, name, trace, monkeypatch, seconds=4.0):
     """Four seconds hold several intervals of the tiny step even when
     the suite's other workers load every core (a traced run needs one
@@ -56,6 +84,8 @@ def _run(index, name, trace, monkeypatch, seconds=4.0):
     chips = harness.load_cell(index, name).chips
     monkeypatch.setattr(harness.trace_reduce, "load",
                         lambda path, spans: _chip_trace(chips))
+    monkeypatch.setattr(harness.trace_regions, "load",
+                        lambda path: _chip_regions())
     # The chip's cells check the loss after 20 steps; how many steps a
     # loaded CPU manages in a short window is not this test's business.
     monkeypatch.setattr(harness, "LOSS_AT_STEP", 5)
@@ -66,7 +96,8 @@ def _run(index, name, trace, monkeypatch, seconds=4.0):
 
 def _check_contract(result, declared, trace):
     assert set(result) == ({"correct", "attempted", "failed", "metrics",
-                            "device"} | ({"breakdown"} if trace else set()))
+                            "device", "compared"}
+                           | ({"breakdown"} if trace else set()))
     assert set(result["metrics"]) == set(declared)
     for name, metric in result["metrics"].items():
         assert set(metric) == {"value", "unit"}
@@ -118,6 +149,70 @@ def test_tiny_cell_yields_every_declared_metric(name, trace, monkeypatch,
             assert "dp1_tokens_per_s_per_chip" in result["metrics"]
         else:
             assert "scaling_efficiency" in result["metrics"]
+
+
+def _with_step(monkeypatch, broken_step):
+    """The gspmd trainer with `broken_step(real_step, state, batch)` in
+    its step's place: the timed path broken underneath `run_cell`."""
+    from benchmark.trainers import gspmd
+
+    real = gspmd.build
+
+    def build(model, phase, devices, seed):
+        trainer = real(model, phase, devices, seed)
+        return dataclasses.replace(trainer, step=functools.partial(
+            broken_step, trainer.step))
+
+    monkeypatch.setattr(gspmd, "build", build)
+
+
+def _state_unchanged(step, state, batch):
+    # The real step donates its state: it gets a copy.
+    _, loss = step(jax.tree.map(jnp.copy, state), batch)
+    return state, loss
+
+
+def _one_module_frozen(step, state, batch):
+    new, loss = step(jax.tree.map(jnp.copy, state), batch)
+    new.params = dict(new.params, ln_f=state.params["ln_f"])
+    return new, loss
+
+
+@pytest.mark.parametrize("broken_step,unmoved", [
+    (None, 0), (_state_unchanged, "all"), (_one_module_frozen, 2)])
+def test_a_step_that_leaves_parameters_unmoved_is_not_correct(
+        broken_step, unmoved, monkeypatch, capsys):
+    """`run_cell` with the step broken underneath: a step that returns
+    its state unchanged, or never updates one module (the final
+    LayerNorm's scale and bias), passes every other check (the loss
+    after a few steps at 1e-4 lies in any band that holds the initial
+    loss) and fails `state_moves`. Every number `correct` rests on
+    comes beside its limit, last in the result."""
+    if broken_step is not None:
+        _with_step(monkeypatch, broken_step)
+    result = _run(INDEX, "tiny-gspmd-1c", False, monkeypatch, seconds=2.0)
+    checks = next(json.loads(line[len("info: "):])["checks"]
+                  for line in capsys.readouterr().out.splitlines()
+                  if line.startswith('info: {"checks"'))
+    failed = {k for k, ok in checks.items() if not ok}
+    assert failed == {"platform_is_tpu"} | (
+        {"state_moves"} if broken_step else set())
+    assert result["correct"] is False
+
+    compared = result["compared"]
+    assert list(result)[-1] == "compared"
+    assert all(set(row) == {"value", "limit"} for row in compared.values())
+    assert {"logits", "grad_norm", "leaves_unmoved", "loss_after_20",
+            "compiles_in_window", "losses_not_finite"} <= set(compared)
+    assert compared["logits"]["limit"] == correct.LOGITS_TOL
+    assert compared["grad_norm"]["limit"] == correct.GRAD_TOL
+    assert compared["leaves_unmoved"]["limit"] == 0
+    cell = harness.load_cell(INDEX, "tiny-gspmd-1c")
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: harness.make_model(cell).init(
+            jax.random.PRNGKey(0), np.zeros((1, 256), np.int32)))))
+    assert compared["leaves_unmoved"]["value"] == (
+        leaves if unmoved == "all" else unmoved)
 
 
 def test_files_added_in_a_copy_are_found_by_name(tmp_path, monkeypatch):
@@ -179,6 +274,32 @@ def test_index_names_only_files_that_exist():
         held = json.loads((ROOT / config["file"]).read_text())
         assert held["source"] == config["source"]
         assert held["reduced"] == config["reduced"]
+
+
+@pytest.mark.parametrize("path", [
+    *sorted((ROOT / "benchmark" / "configs").glob("*.json")),
+    *sorted((CELLS / "configs").glob("*.json"))], ids=lambda p: p.stem)
+def test_a_transformer_configuration_states_the_kernel_work_its_dims_give(
+        path):
+    """`attention` and `kernels` restate what the dims of a model of
+    `reference/transformer_ref.py`'s block fix: every layer calls the
+    attention once, on `n_heads` heads of `d_model // n_heads` for
+    queries, keys and values alike, and the step holds the flash kernels
+    exactly where `attn_impl` asks for them. A depth cut that forgets
+    `calls_per_step` would halve a roofline share unseen."""
+    from benchmark.layer_metrics import _flash
+
+    held = json.loads(path.read_text())
+    assert held["reference"] == "transformer_ref"
+    dims = {kw: held[key] for kw, key in held["model_kwargs"].items()}
+    dims.update(held["model_options"])
+    head = dims["d_model"] // dims["n_heads"]
+    assert held["attention"] == {
+        "heads": dims["n_heads"], "qk_head_dim": head, "v_head_dim": head,
+        "calls_per_step": dims["n_layers"]}
+    assert held["kernels"] == ([_flash.FORWARD, _flash.BACKWARD]
+                               if dims["attn_impl"] == "flash" else [])
+    assert held["matmul_params"] == "flops.transformer_matmul_params"
 
 
 def test_the_command_refuses_the_cpu_and_prints_no_result():
@@ -252,13 +373,14 @@ def tiny():
                                  np.zeros((1, 256), np.int32))["params"]
     params = jax.tree.map(lambda x: x.value if hasattr(x, "value") else x,
                           params, is_leaf=lambda x: hasattr(x, "value"))
-    return cell, (lambda p, ids: model.apply({"params": p}, ids)), params
+    return cell, lm_objective(model, lm_loss), params
 
 
 def test_reference_agrees_with_the_program_on_the_tiny_model(tiny):
-    cell, apply_fn, params = tiny
+    cell, objective, params = tiny
     errors = correct.measure_against_reference(
-        apply_fn, lm_loss, transformer_ref, params, cell.dims, 256, seed=1)
+        objective, transformer_ref, params, cell.dims, 256, seed=1,
+        grad_leaves=cell.config["grad_leaves"])
     assert set(errors) == {"logits", "grad_norm", "grad.embedding",
                            "grad.layer_1.qkv", "grad.ln_f.scale"}
     assert correct.beyond_tolerance(errors) == {}
@@ -266,10 +388,10 @@ def test_reference_agrees_with_the_program_on_the_tiny_model(tiny):
 
 @pytest.mark.parametrize("broken", [{"causal": False}, {"n_layers": 1}])
 def test_reference_comparison_fails_without_the_mask_or_a_layer(tiny, broken):
-    cell, apply_fn, params = tiny
+    cell, objective, params = tiny
     errors = correct.measure_against_reference(
-        apply_fn, lm_loss, transformer_ref, params,
-        dict(cell.dims, **broken), 256, seed=1)
+        objective, transformer_ref, params, dict(cell.dims, **broken), 256,
+        seed=1, grad_leaves=cell.config["grad_leaves"])
     assert "logits" in correct.beyond_tolerance(errors)
     assert errors["logits"] > 5 * correct.LOGITS_TOL
 
