@@ -94,6 +94,20 @@ SCOPE_LOSS = "hvd.loss"
 #: The optimizer pass: `make_train_step`'s `tx.update` + `apply_updates`,
 #: `DistributedOptimizer`'s inner `optimizer.update`.
 SCOPE_OPTIMIZER = "hvd.optimizer"
+#: A latent attention's own work around the attention kernel: the five
+#: projections, the two inner norms, the rotary positions
+#: (`models/latent_moe.py::LatentAttention`); not the kernel.
+SCOPE_ATTN_LATENT = "hvd.attn.latent"
+#: A routed layer's routing: router, top-k, gates, the sort into
+#: expert order, the gather into the dispatch buffer, and the weighted
+#: gather back (`models/latent_moe.py::RoutedExperts`).
+SCOPE_MOE_ROUTE = "hvd.moe.route"
+#: A routed layer's expert work: the grouped products over the experts
+#: held here and the shared expert.
+SCOPE_MOE_EXPERTS = "hvd.moe.experts"
+#: The multi-token-prediction module, outermost: its norms, projection
+#: and block, and the shared head applied to it.
+SCOPE_MTP = "hvd.mtp"
 #: One call of a step function the library built (`make_train_step`'s
 #: step, `wrap_step`'s wrapper); arg `step` = number of this call of
 #: this function in the process.

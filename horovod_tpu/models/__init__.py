@@ -1,5 +1,6 @@
 """Model zoo: the reference's benchmark/example workloads as TPU-first
 flax models (SURVEY.md §6 / BASELINE.json north-star configs)."""
+from .latent_moe import LATENT_MOE_CONFIGS, LatentMoEConfig, LatentMoELM
 from .mnist import MnistCNN, MnistMLP
 from .registry import REGISTRY, ModelSpec, get_model, list_models
 from .resnet import (

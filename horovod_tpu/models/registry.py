@@ -12,6 +12,7 @@ from typing import Any, Callable, Dict, Tuple
 
 import numpy as np
 
+from .latent_moe import LATENT_MOE_CONFIGS, LatentMoELM
 from .mnist import MnistCNN, MnistMLP
 from .resnet import RESNET_CONFIGS
 from .transformer import (
@@ -72,6 +73,13 @@ def _registry() -> Dict[str, ModelSpec]:
                 dataclasses.replace(c, **kw) if kw else c)))(cfg),
             _token_batch(min(cfg.max_len, 128), cfg.vocab_size),
             "encoder",
+        )
+    for name, cfg in LATENT_MOE_CONFIGS.items():
+        reg[name] = ModelSpec(
+            name,
+            (lambda c: (lambda **kw: LatentMoELM(
+                dataclasses.replace(c, **kw) if kw else c)))(cfg),
+            _token_batch(512, cfg.vocab_size), "lm",
         )
     for name, cfg in VIT_CONFIGS.items():
         reg[name] = ModelSpec(

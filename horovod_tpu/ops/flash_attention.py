@@ -64,9 +64,11 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
             causal: bool, block_q: int, block_k: int, plain: bool):
     """One (batch*head, q-block) grid step, streaming k-blocks.
 
-    q_ref: (1, block_q, D); k_ref/v_ref: (1, S_pad, D) VMEM-resident;
-    mask_ref: (1, 1, S_pad); o_ref: (1, block_q, D);
-    lse_ref: (1, 1, block_q) per-row logsumexp residual
+    q_ref: (1, block_q, Dqk); k_ref: (1, S_pad, Dqk) and v_ref:
+    (1, S_pad, Dv), VMEM-resident; mask_ref: (1, 1, S_pad);
+    o_ref: (1, block_q, Dv); lse_ref: (1, 1, block_q) per-row logsumexp
+    residual. Dqk and Dv are one number in GPT-2 / BERT / ViT; a latent
+    attention's query/key heads are wider than its value heads.
 
     Flash-style: a fori_loop folds (block_q, block_k) score tiles into a
     running (max, normalizer, accumulator) state, so peak VMEM for
@@ -85,8 +87,8 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
 
     # Native-dtype matmuls with f32 accumulation: bf16 inputs hit the
     # MXU's fast path; only the accumulator/softmax run in f32.
-    q = q_ref[0]                               # (block_q, D)
-    D = q.shape[-1]
+    q = q_ref[0]                               # (block_q, Dqk)
+    D = v_ref.shape[-1]                        # the accumulator's: Dv
     s_pad = k_ref.shape[1]
 
     def tile(kb, carry, masked):
@@ -167,11 +169,26 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
 
 
 DEFAULT_BLOCK_K = 512
+# Mosaic gives a kernel 16 MiB of VMEM unless told otherwise. The
+# backward keeps Q, dO, dQ and an f32 dQ accumulator of the whole
+# sequence resident; at heads wider than one 128-lane tile (192 fills
+# two) and S = 4096 that is 17.3 MiB. A v5e core has 128 MiB.
+WIDE_HEAD_VMEM_BYTES = 64 * 2 ** 20
+
+
+def _compiler_params(*head_dims: int) -> dict:
+    """`pallas_call` keywords: nothing for heads within one lane tile
+    (the call, and its lowered text, are then what they always were),
+    a raised VMEM limit for wider ones."""
+    if max(head_dims) <= 128:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=WIDE_HEAD_VMEM_BYTES)}
 
 
 def _prep(q, k, v, mask, block_q: int):
     """Shared layout/padding for forward and backward: (B,S,H,D) ->
-    (B*H,S,D) with queries padded to a block_q multiple (garbage rows
+    (B*H,S,D), each tensor at its own D, with queries padded to a block_q multiple (garbage rows
     sliced off after) and keys/values/mask padded to a block_k multiple
     (padded keys carry mask 0, so they never contribute). Both passes
     MUST use identical block/pad arithmetic for the saved lse residual
@@ -181,7 +198,7 @@ def _prep(q, k, v, mask, block_q: int):
     needed no block padding — the kernels then take the mask-free fast
     path on below-diagonal tiles (the key-validity mask is the only
     thing key padding relies on, so it must force the masked path)."""
-    B, S, H, D = q.shape
+    B, S, H, _ = q.shape
     if block_q is None:
         # Measured on v5e (B4 H12 D64, full GPT-2 train step, r5,
         # mask-free fast path + fused single-sweep backward): at
@@ -203,7 +220,7 @@ def _prep(q, k, v, mask, block_q: int):
 
     # (B, S, H, D) -> (B*H, S, D): attention is independent per (b, h).
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+        return x.transpose(0, 2, 1, 3).reshape(B * H, S, x.shape[-1])
 
     qb, kb_arr, vb = to_bh(q), to_bh(k), to_bh(v)
     if pad_q:
@@ -230,6 +247,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
                interpret: Optional[bool]
                ) -> "tuple[jax.Array, jax.Array]":
     B, S, H, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
     qb, kb_arr, vb, mask2, _, bq, bk, Sq, Sk, plain = _prep(q, k, v,
                                                             mask, block_q)
@@ -240,24 +258,25 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
             functools.partial(_kernel, scale=scale, causal=causal,
                               block_q=bq, block_k=bk, plain=plain),
             out_shape=[
-                jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
             ],
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
                 pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0)),
-                pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0)),
+                pl.BlockSpec((1, Sk, Dv), lambda bh, qi: (bh, 0, 0)),
                 # mask indexed by batch = bh // H (static H via closure).
                 pl.BlockSpec((1, 1, Sk),
                              lambda bh, qi, H=H: (bh // H, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
+                pl.BlockSpec((1, bq, Dv), lambda bh, qi: (bh, qi, 0)),
                 pl.BlockSpec((1, 1, bq), lambda bh, qi: (bh, 0, qi)),
             ],
             interpret=interp,
             name="flash_attention_fwd",
+            **_compiler_params(D, Dv),
         )
 
     out, lse = call_by_platform(call, qb, kb_arr, vb, mask2,
@@ -266,7 +285,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
     out = out[:, :S]
     # Slice lse to the real rows too, so the backward's re-pad is the
     # single true padding (padded-row lse is kernel garbage here).
-    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3), lse[:, :, :S]
+    return out.reshape(B, H, S, Dv).transpose(0, 2, 1, 3), lse[:, :, :S]
 
 
 def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
@@ -288,9 +307,9 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     Padded q rows carry lse=+inf, killing their p rows — which is what
     keeps the `plain` fast path valid under q padding."""
     ki = pl.program_id(1)
-    k = k_ref[0]                                 # (bk, D)
-    v = v_ref[0]
-    D = k.shape[-1]
+    k = k_ref[0]                                 # (bk, Dqk)
+    v = v_ref[0]                                 # (bk, Dv)
+    D, Dv = k.shape[-1], v.shape[-1]
     sq_pad = q_ref.shape[1]
     num_kb = pl.num_programs(1)
 
@@ -327,7 +346,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
             p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=_prec(do_blk.dtype),
-        )                                        # (bk, D)
+        )                                        # (bk, Dv)
         dp = jax.lax.dot_general(
             do_blk, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -351,7 +370,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     if causal:
         start_qb = (ki * block_k) // block_q
     carry = (jnp.zeros((block_k, D), jnp.float32),
-             jnp.zeros((block_k, D), jnp.float32))
+             jnp.zeros((block_k, Dv), jnp.float32))
     if plain and causal:
         diag_end = jnp.minimum(
             ((ki + 1) * block_k + block_q - 1) // block_q, num_qb)
@@ -382,6 +401,7 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
     """Blockwise backward: same VMEM-bounded structure as the forward —
     the (S, S) score matrix is never materialized in HBM."""
     B, S, H, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
     qb, kb_arr, vb, mask2, to_bh, bq, bk, Sq, Sk, plain = _prep(
         q, k, v, mask, block_q)
@@ -399,9 +419,12 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1).reshape(B * H, 1, Sq)
 
+    # Q, K, dQ, dK at Dqk; V, dO, dV at Dv.
     full_q = pl.BlockSpec((1, Sq, D), lambda bh, ki: (bh, 0, 0))
+    full_do = pl.BlockSpec((1, Sq, Dv), lambda bh, ki: (bh, 0, 0))
     row_q = pl.BlockSpec((1, 1, Sq), lambda bh, ki: (bh, 0, 0))
     blk_k = pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0))
+    blk_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: (bh, ki, 0))
 
     def call(interp: bool):
         return pl.pallas_call(
@@ -410,30 +433,31 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
             out_shape=[
                 jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
                 jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
-                jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
+                jax.ShapeDtypeStruct((B * H, Sk, Dv), v.dtype),
             ],
             grid=(B * H, Sk // bk),
             in_specs=[
                 full_q,
-                blk_k, blk_k,
+                blk_k, blk_v,
                 pl.BlockSpec((1, 1, bk),
                              lambda bh, ki, H=H: (bh // H, 0, ki)),
-                full_q, row_q, row_q,
+                full_do, row_q, row_q,
             ],
             out_specs=[
                 full_q,   # dq: one block per (b, h), flushed on last ki
-                blk_k, blk_k,
+                blk_k, blk_v,
             ],
             scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32)],
             interpret=interp,
             name="flash_attention_bwd",
+            **_compiler_params(D, Dv),
         )
 
     dq, dk, dv = call_by_platform(call, qb, kb_arr, vb, mask2, dob, lse,
                                   delta, interpret=interpret)
 
     def from_bh(x, S_):
-        return x[:, :S_].reshape(B, H, S_, D).transpose(0, 2, 1, 3)
+        return x[:, :S_].reshape(B, H, S_, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return from_bh(dq, S), from_bh(dk, S), from_bh(dv, S)
 
@@ -442,8 +466,10 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
 def flash_attention(q, k, v, mask=None, causal: bool = True,
                     block_q: Optional[int] = None,
                     interpret: Optional[bool] = None):
-    """Fused attention. q/k/v: (B, S, H, D); mask: optional (B, S) key
-    validity (1 = attend). Returns (B, S, H, D) in q.dtype.
+    """Fused attention. q/k: (B, S, H, Dqk), v: (B, S, H, Dv); mask:
+    optional (B, S) key validity (1 = attend). Returns (B, S, H, Dv) in
+    q.dtype; scores are scaled by 1/sqrt(Dqk). Dqk == Dv in GPT-2 /
+    BERT / ViT; a latent attention has 192 beside 128.
 
     `block_q=None` auto-selects by sequence length (128 below S=2048,
     256 at 2048, 512 beyond — measured full-train-step crossover on

@@ -28,6 +28,7 @@ DEFAULT_RULES: Tuple[Tuple[str, Any], ...] = (
     ("mlp", ("tp",)),            # d_ff column-split
     ("heads", ("tp",)),          # attention heads split
     ("kv", None),
+    ("latent", None),            # latent attention's low ranks: replicated
     ("vocab", ("tp",)),          # embedding/lm-head vocab split
     ("expert", ("ep",)),         # MoE experts → expert parallel
     ("expert_mlp", ("tp",)),
@@ -50,6 +51,7 @@ FSDP_RULES: Tuple[Tuple[str, Any], ...] = (
     ("mlp", ("tp",)),
     ("heads", ("tp",)),
     ("kv", None),
+    ("latent", None),
     ("vocab", ("tp",)),
     ("expert", ("ep",)),
     ("expert_mlp", ("tp",)),

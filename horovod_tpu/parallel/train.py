@@ -89,6 +89,27 @@ def lm_loss(logits, ids) -> jax.Array:
     return softmax_xent(logits[:, :-1], ids[:, 1:])
 
 
+# The flax collection a model sows into what a second term of the loss
+# is computed from (`make_train_step`'s `aux_loss_fn`): arrays, such as a
+# multi-token-prediction module's logits, not a scalar it reduced itself.
+AUX_COLLECTION = "aux_outputs"
+
+
+def mtp_loss(weight: float) -> Callable:
+    """The multi-token-prediction term of an objective, as an
+    `aux_loss_fn` of `make_train_step`: `weight` x the mean
+    cross-entropy of the logits a model sowed as `logits` (position i
+    predicts token i + 2; `models/latent_moe.py`) against the ids two
+    places on. It takes the logits themselves, so a caller can restrict
+    the term to the first n positions by slicing what was sown."""
+
+    def term(sown, ids) -> jax.Array:
+        logits, = sown["logits"]
+        return weight * softmax_xent(logits[:, :-2], ids[:, 2:])
+
+    return term
+
+
 # Compiler options of a step whose gradients are all-reduced over a data
 # axis on TPU chips (`overlap_compiler_options` says when, the module
 # docstring what was measured). Found and tuned on libtpu 0.0.34, TPU
@@ -161,6 +182,7 @@ def make_train_step(
     shard_seq: bool = False,
     has_batch_stats: bool = False,
     moe_aux_weight: float = 0.0,
+    aux_loss_fn: Optional[Callable] = None,
     donate: bool = True,
     dropout: bool = False,
     dropout_seed: int = 0,
@@ -171,6 +193,13 @@ def make_train_step(
 
     loss_fn(logits, batch_labels) -> scalar. The model's first input is
     batch[0]; labels are batch[1] (or batch[0] again for LMs).
+
+    `aux_loss_fn(sown, batch_labels) -> scalar` adds a second term
+    computed from what the model sowed into the flax collection
+    `AUX_COLLECTION` — arrays, not a scalar the model reduced itself:
+    the model is applied with that collection mutable and the term is
+    added to `loss_fn`'s (e.g. `mtp_loss(0.3)` for a model with a
+    multi-token-prediction module). Without it the step is unchanged.
 
     `dropout=True` runs the model with deterministic=False and threads a
     per-step dropout rng (folded from `dropout_seed` and the step
@@ -301,6 +330,8 @@ def make_train_step(
                 mutable = list(state.extra.keys())
             if moe_aux_weight > 0.0:
                 mutable = mutable + ["losses"]
+            if aux_loss_fn is not None:
+                mutable = mutable + [AUX_COLLECTION]
             kwargs = {}
             if has_batch_stats:
                 kwargs["train"] = True
@@ -324,7 +355,10 @@ def make_train_step(
                 aux = sum(jnp.sum(jnp.asarray(v))
                           for v in jax.tree.leaves(updates["losses"]))
                 loss = loss + moe_aux_weight * aux
-            new_extra = {k: v for k, v in updates.items() if k != "losses"}
+            if aux_loss_fn is not None:
+                loss = loss + aux_loss_fn(updates[AUX_COLLECTION], labels)
+            new_extra = {k: v for k, v in updates.items()
+                         if k not in ("losses", AUX_COLLECTION)}
             return loss, new_extra
 
         (loss, new_extra), grads = jax.value_and_grad(
