@@ -91,17 +91,19 @@ def check(cond: bool, what: str):
 
 
 def require_tpu():
-    """The devices, or a failure: the TPU platform and a device kind the
-    program knows (bench.py's table of peaks is what it knows)."""
+    """The devices, or a failure: the TPU platform and a device kind
+    the benchmark can measure (benchmark/peaks.py is the one table)."""
     import jax
 
-    from bench import PEAK_FLOPS
+    from benchmark import peaks
 
     devices = jax.devices()
-    check(devices[0].platform == "tpu",
-          f"platform is 'tpu' (got {devices[0].platform!r})")
-    check(devices[0].device_kind in PEAK_FLOPS,
-          f"device kind {devices[0].device_kind!r} is in bench.PEAK_FLOPS")
+    try:
+        peaks.for_device(devices[0])
+    except peaks.UnknownDevice as exc:
+        check(False, str(exc))
+    check(True, f"a TPU of kind {devices[0].device_kind!r}, which "
+          "benchmark/peaks.py knows")
     return devices
 
 

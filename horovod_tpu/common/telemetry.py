@@ -319,7 +319,7 @@ def histogram(name: str, help: str = "", labels: LabelDict = None,
 # ---------------------------------------------------------------------------
 # Build identity + uptime (standard practice for any scraped process;
 # the perf regression reporter stamps the same dict into its JSON so
-# every BENCH round is attributable to a build).
+# every perf report is attributable to a build).
 
 _PROCESS_START_MONO = time.monotonic()
 

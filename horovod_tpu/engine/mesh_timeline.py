@@ -76,8 +76,7 @@ class MeshTimeline:
     # ------------------------------------------------------------------
     def _splice(self, profile_dir: str):
         # Shared glob/gzip/parse helper (utils/chrome_trace) — the same
-        # reader scripts/profile_step.py and the tracing plane's
-        # analyzers use.
+        # reader the tracing plane's analyzers use.
         events = chrome_trace.load_profiler_events(profile_dir)
         if events is None:
             return

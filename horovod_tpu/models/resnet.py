@@ -46,84 +46,14 @@ class ResNetBlock(nn.Module):
         return self.act(residual + y)
 
 
-class FusedBNReluConv1x1(nn.Module):
-    """BN-apply + ReLU + 1x1-conv in ONE pass over the activation via
-    the Pallas kernel (`ops/fused_bn_conv.py` — 1.36x the XLA unfused
-    chain on the stage-2 shape, docs/kernels.md). Owns the same
-    BN state flax.BatchNorm would (batch stats in train, running-stat
-    EMA) plus the conv kernel, so it is a drop-in for the
-    [norm → act → conv1x1] tail of a bottleneck block."""
-
-    features: int
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x, train: bool = True):
-        from ..ops.fused_bn_conv import bn_relu_conv1x1
-
-        cin = x.shape[-1]
-        scale = self.param("scale", nn.initializers.ones_init(), (cin,),
-                           jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros_init(), (cin,),
-                          jnp.float32)
-        kernel = self.param(
-            "kernel",
-            nn.initializers.variance_scaling(2.0, "fan_out", "normal"),
-            (cin, self.features), self.param_dtype,
-        )
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros((cin,), jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones((cin,), jnp.float32))
-        x2d = x.reshape(-1, cin)
-        if train:
-            xf = x2d.astype(jnp.float32)
-            mu = jnp.mean(xf, axis=0)
-            # E[x^2]-E[x]^2 can round below 0 for near-constant
-            # channels of large magnitude; clamp so rsqrt(var+eps)
-            # in the kernel can't go NaN.
-            var = jnp.maximum(
-                jnp.mean(jnp.square(xf), axis=0) - jnp.square(mu), 0.0)
-            if not self.is_initializing():
-                m = self.momentum
-                ra_mean.value = m * ra_mean.value + (1 - m) * mu
-                ra_var.value = m * ra_var.value + (1 - m) * var
-        else:
-            mu, var = ra_mean.value, ra_var.value
-        # The kernel requires M % block_m == 0 (block_m = min(512, M)):
-        # pad rows with zeros and slice them back off — padded rows'
-        # outputs are garbage-but-finite and never read (batch stats
-        # were computed on the unpadded rows above; the kernel's own
-        # epilogue stats are discarded here).
-        m = x2d.shape[0]
-        pad = (-m) % 512 if m > 512 else 0
-        if pad:
-            x2d = jnp.pad(x2d, ((0, pad), (0, 0)))
-        y2d, _, _ = bn_relu_conv1x1(
-            x2d, mu, var, scale, bias, kernel.astype(self.dtype),
-            self.epsilon,
-        )
-        return y2d[:m].reshape(*x.shape[:-1], self.features)
-
-
 class BottleneckResNetBlock(nn.Module):
-    """1x1 → 3x3(stride) → 1x1 bottleneck (ResNet-50/101/152).
-
-    `fuse_bn_conv1x1=True` routes the [norm → act → 1x1-conv] tail
-    through the Pallas fused kernel (see FusedBNReluConv1x1) — the
-    flag exists to measure that kernel's end-to-end contribution
-    (bench.py measures it by default for resnet50; `--no-fused-bn`
-    skips)."""
+    """1x1 → 3x3(stride) → 1x1 bottleneck (ResNet-50/101/152)."""
 
     filters: int
     conv: ModuleDef
     norm: ModuleDef
     act: Callable
     strides: Tuple[int, int] = (1, 1)
-    fuse_bn_conv1x1: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -132,20 +62,6 @@ class BottleneckResNetBlock(nn.Module):
         y = self.norm()(y)
         y = self.act(y)
         y = self.conv(self.filters, (3, 3), self.strides)(y)
-        if self.fuse_bn_conv1x1:
-            # The ResNet trunk encodes train/eval in the norm partial;
-            # mirror it so the fused site keeps BatchNorm semantics.
-            train = not self.norm.keywords.get("use_running_average",
-                                               False)
-            y = FusedBNReluConv1x1(
-                self.filters * 4, dtype=y.dtype, name="fused_bn_conv3",
-            )(y, train=train)
-            y = self.norm(scale_init=nn.initializers.zeros_init())(y)
-            if residual.shape != y.shape:
-                residual = self.conv(self.filters * 4, (1, 1), self.strides,
-                                     name="conv_proj")(residual)
-                residual = self.norm(name="norm_proj")(residual)
-            return self.act(residual + y)
         y = self.norm()(y)
         y = self.act(y)
         y = self.conv(self.filters * 4, (1, 1))(y)
@@ -168,10 +84,6 @@ class ResNet(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     act: Callable = nn.relu
-    # Stages (0-based) whose bottleneck 1x1 tails run the Pallas fused
-    # BN+ReLU+conv kernel — measurement flag, bottleneck blocks only
-    # (see FusedBNReluConv1x1; docs/kernels.md for which shapes win).
-    fuse_bn_conv_stages: Sequence[int] = ()
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -194,14 +106,9 @@ class ResNet(nn.Module):
         for i, block_size in enumerate(self.stage_sizes):
             for j in range(block_size):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                kw = {}
-                if (i in self.fuse_bn_conv_stages
-                        and self.block_cls is BottleneckResNetBlock):
-                    kw["fuse_bn_conv1x1"] = True
                 x = self.block_cls(
                     self.num_filters * 2**i,
                     conv=conv, norm=norm, act=self.act, strides=strides,
-                    **kw,
                 )(x)
         x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=self.dtype,

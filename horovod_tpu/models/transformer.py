@@ -67,14 +67,14 @@ class TransformerConfig:
     # Output logits dtype. f32 is the DEFAULT: model.apply logits are a
     # public surface (sampling, logprob extraction, custom losses), and
     # silently narrowing them costs external consumers precision
-    # (ADVICE r14). The measured bench/train paths OPT INTO bf16
-    # explicitly (bench.py, examples/jax_gpt2_train.py): the (B, S, V)
-    # logits tensor is the largest activation in the model (1.65 GB in
-    # f32 at the GPT-2 bench shape) and every loss in this repo upcasts
-    # to f32 *inside* its softmax reduction (parallel/train.py
-    # softmax_xent), so emitting bf16 there saves the lm-head region's
-    # HBM traffic — measured 6.0 ms of a 98 ms step on v5e
-    # (docs/benchmarks.md, r5) — without changing the loss numerics.
+    # (ADVICE r14). The measured train paths OPT INTO bf16 explicitly
+    # (benchmark/configs/, chip_smoke.py, examples/jax_gpt2_train.py):
+    # the (B, S, V) logits tensor is the largest activation in the
+    # model (1.65 GB in f32 at 8192 tokens of GPT-2's vocabulary) and
+    # every loss in this repo upcasts to f32 *inside* its softmax
+    # reduction (parallel/train.py softmax_xent), so emitting bf16
+    # halves the lm-head region's HBM traffic (its time is the ledger's
+    # `loss_head_ms_per_step`) without changing the loss numerics.
     logits_dtype: Dtype = jnp.float32
     # Learned (gpt2/bert/vit) vs fixed sinusoidal positions.
     learned_pos: bool = True

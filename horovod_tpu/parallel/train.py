@@ -414,7 +414,7 @@ def make_train_step(
         # The raw (untraced) step lets callers embed the step in a larger
         # jit — e.g. a lax.scan over K steps — without nesting pjit
         # inside jit, which compiles far slower than tracing the body
-        # directly (bench.py's scan loop uses this).
+        # directly (tests/test_step_regions.py lowers the step so).
         wrapped_step.raw = train_step
         wrapped_step.shardings = (ssh,) + bsh
         return wrapped_init, wrapped_step, ssh

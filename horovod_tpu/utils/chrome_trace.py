@@ -1,11 +1,9 @@
 """Chrome-trace file plumbing shared by every trace consumer.
 
-The glob/gzip/parse dance over a ``jax.profiler`` output directory used
-to be duplicated between ``engine/mesh_timeline.py`` (device-lane
-splicing) and ``scripts/profile_step.py`` (per-op step breakdown); the
-tracing plane's analyzers (scripts/critical_path.py) need the same
-readers for merged traces and post-mortems. One module, three
-consumers.
+The glob/gzip/parse dance over a ``jax.profiler`` output directory is
+needed by ``engine/mesh_timeline.py`` (device-lane splicing) and by
+the tracing plane's analyzers (scripts/critical_path.py) for merged
+traces and post-mortems. One module for both.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
-Called by entry programs (chip_smoke.py, bench.py, the profiling
-scripts, the jax examples) before their first compile — never by the
+Called by entry programs (chip_smoke.py, benchmark/run.py, the jax
+examples) before their first compile — never by the
 library's import or `hvd.init()`: a library that relocates a user's
 cache is a surprise.
 

@@ -293,7 +293,7 @@ def run_killall(args) -> int:
 def run_overhead(args) -> int:
     """Per-step overhead of the durability plane, checkpointing off vs
     on. Each "step" is a fixed amount of real compute (matmul reps
-    calibrated to ``--step-ms``, the scale of a bench.py model step) +
+    calibrated to ``--step-ms``, the scale of a model's train step) +
     ``state.commit()``'s host-copy save; the checkpointed run adds the
     snapshot/enqueue on the training thread and the pickle+write on the
     background writer, whose cost must overlap the compute — the <5%
@@ -309,7 +309,7 @@ def run_overhead(args) -> int:
     steps = args.overhead_steps
     interval = args.overhead_interval
 
-    # Fixed work per step at ~step_ms, the scale of a bench.py model
+    # Fixed work per step at ~step_ms, the scale of a model's train
     # step. Default `sleep` models the acceptance context — a
     # device-bound step: the training thread blocks on the accelerator
     # and the host CPU is free, which is exactly what the background
@@ -351,7 +351,7 @@ def run_overhead(args) -> int:
         return time.perf_counter() - t0
 
     # Order-alternated paired rounds, median overhead (the repo's
-    # measurement idiom — see benchmarks.md): a sequential base-then-
+    # measurement idiom, as in scripts/perf_report.py): a sequential base-then-
     # checkpointed pair measures box-load drift as much as checkpoint
     # cost on a shared CI box; alternation cancels the drift and the
     # median rejects the outlier rounds.

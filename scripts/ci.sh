@@ -65,16 +65,8 @@ python scripts/checkpoint_smoke.py --overhead
 echo "=== serving smoke (4-rank continuous batching: p50/p99 under concurrent load, weight hot-swap mid-traffic, wedged-replica eviction) ==="
 python scripts/serving_smoke.py
 
-echo "=== perf report (warn vs committed BENCH_BASELINE.json; docs/health.md) ==="
+echo "=== perf report (host loopback stages; warn vs committed scripts/perf_baseline.json; docs/health.md) ==="
 python scripts/perf_report.py --quick --out /tmp/hvd_perf1.json
-
-# Resume the BENCH trajectory (empty since r05): archive this run's
-# perf report as the next BENCH_r<NN>.json next to BENCH_BASELINE.json.
-last=$( (ls BENCH_r[0-9]*.json 2>/dev/null || true) \
-  | sed -E 's/.*BENCH_r0*([0-9]+)\.json/\1/' | sort -n | tail -1)
-next=$(( ${last:-0} + 1 ))
-cp /tmp/hvd_perf1.json "$(printf 'BENCH_r%02d.json' "$next")"
-echo "BENCH trajectory: archived $(printf 'BENCH_r%02d.json' "$next")"
 
 echo "=== perf gate self-test (clean back-to-back must pass; injected 2x slowdown must trip) ==="
 python scripts/perf_report.py --quick --out /tmp/hvd_perf2.json \
@@ -87,8 +79,5 @@ fi
 
 echo "=== multichip sharding dryrun (8 virtual devices) ==="
 python __graft_entry__.py
-
-echo "=== bench smoke (CPU) ==="
-python bench.py --cpu --no-scaling
 
 echo "CI OK"

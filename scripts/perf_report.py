@@ -6,13 +6,18 @@ ring / segmented-ring bandwidth, the tcp-vs-shm transport pair, the
 two-level hierarchical allreduce, the 16MB reduce-scatter leg, the
 np=4 ZeRO-1 optimizer step (plus its measured per-rank state bytes),
 and a serving round-trip — and emits
-a BENCH-style JSON: medians over order-alternated rounds (the house
+one JSON report: medians over order-alternated rounds (the house
 methodology from the PR 3/4/8 acceptance measurements: on a shared box,
 sequential arms measure load drift, so stage order alternates per
 round and the median of rounds is the stage value). The report stamps
 ``horovod_build_info`` (version + jax) so every number is attributable
-to a build — the BENCH trajectory stopped being recorded after PR 5;
-this file is how it restarts.
+to a build.
+
+Every stage is a host timing over loopback TCP / shared memory on this
+machine's CPU cores. They guard the CPU data plane (engine, transports,
+serving front door) against regressions; none is a speed of the chip.
+The chip's numbers come from ``benchmark/run.py`` and live in
+``PERF_LEDGER.jsonl`` and ``PERF.md``.
 
 Comparison: every stage is lower-is-better; a stage regresses when
 ``value / baseline > 1 + tolerance`` (strictly — the boundary passes).
@@ -21,7 +26,7 @@ Tolerances are per-stage (the baseline file may carry a
 noisy and a flaky gate is worse than none.
 
 CI wiring (scripts/ci.sh): warn-by-default against the committed
-``BENCH_BASELINE.json``; gating is the explicit opt-in (``--gate``).
+``scripts/perf_baseline.json``; gating is the explicit opt-in (``--gate``).
 The gate itself is proven live on every CI run: a clean back-to-back
 run must pass, and a ``--replay --inject-slowdown 2.0`` of the same
 measurements must trip it.
@@ -44,7 +49,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-DEFAULT_BASELINE = os.path.join(REPO, "BENCH_BASELINE.json")
+DEFAULT_BASELINE = os.path.join(REPO, "scripts", "perf_baseline.json")
 DEFAULT_TOLERANCE = 0.5
 
 SCHEMA = 1
@@ -667,7 +672,7 @@ def main() -> int:
     ap.add_argument("--out", help="write the measured report JSON here")
     ap.add_argument("--baseline", default=DEFAULT_BASELINE,
                     help="baseline report to compare against "
-                         "(default: BENCH_BASELINE.json)")
+                         "(default: scripts/perf_baseline.json)")
     ap.add_argument("--gate", action="store_true",
                     help="exit 1 on regression/missing/invalid "
                          "(default: warn only)")

@@ -2,7 +2,8 @@
 """Data-plane perf smoke: a real 2-worker loopback run over every ring
 schedule, asserting completion and EXACT byte accounting — no flaky
 throughput thresholds (CI boxes are too noisy for those; the numbers
-live in examples/microbench_allreduce.py and BENCH runs instead).
+live in examples/microbench_allreduce.py and scripts/perf_report.py
+instead).
 
 What it pins down:
 

@@ -224,10 +224,12 @@ def test_chip_smoke_result_line_has_exactly_the_contract_keys(
     assert report["claim"] is None and "versions" in report
 
 
-def test_bench_unknown_device_kind_is_an_error():
+def test_chip_smoke_unknown_device_kind_is_an_error(monkeypatch, capsys):
+    """`require_tpu` knows the device kinds of benchmark/peaks.py and
+    no others, and says where a new kind is added."""
     sys.path.insert(0, REPO)
     try:
-        import bench
+        import chip_smoke
     finally:
         sys.path.remove(REPO)
 
@@ -235,10 +237,16 @@ def test_bench_unknown_device_kind_is_an_error():
         platform = "tpu"
         device_kind = "TPU v99"
 
-    with pytest.raises(SystemExit, match="unknown device kind 'TPU v99'"):
-        bench._peak_flops(Device())
+    monkeypatch.setattr(jax, "devices", lambda: [Device()])
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match=r"'TPU v99' is not in benchmark/peaks\.py"):
+        chip_smoke.require_tpu()
     Device.device_kind = "TPU v5 lite"
-    assert bench._peak_flops(Device()) == 197e12
+    assert chip_smoke.require_tpu()[0].device_kind == "TPU v5 lite"
+    assert "ok: a TPU of kind 'TPU v5 lite'" in capsys.readouterr().out
+    Device.platform = "cpu"
+    with pytest.raises(chip_smoke.SmokeFailure, match="not 'tpu'"):
+        chip_smoke.require_tpu()
 
 
 @pytest.mark.slow

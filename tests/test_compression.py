@@ -855,7 +855,7 @@ def test_engine_training_loss_parity_bf16_vs_none():
     """Accuracy-parity check through the REAL engine data plane: a
     2-rank data-parallel least-squares model trained with gradient
     allreduce under bf16+error-feedback must reach the same final loss
-    as the uncompressed run within noise (the bench.py models ride the
+    as the uncompressed run within noise (the benchmark's cells ride the
     traced/XLA path, which the wire codec never touches — this loop is
     the eager engine's equivalent)."""
     rng = np.random.default_rng(5)

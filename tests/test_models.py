@@ -15,6 +15,7 @@ from horovod_tpu.models import (
     get_model,
     list_models,
 )
+from horovod_tpu.models.resnet import RESNET_CONFIGS
 from horovod_tpu.parallel.mesh import create_mesh
 from horovod_tpu.parallel.train import lm_loss, make_train_step, softmax_xent
 
@@ -75,6 +76,26 @@ def test_resnet_batchstats_update():
     before = variables["batch_stats"]["bn_init"]["mean"]
     after = updates["batch_stats"]["bn_init"]["mean"]
     assert not np.allclose(np.asarray(before), np.asarray(after))
+
+
+# The published parameter counts (torchvision's resnet18 ... resnet152,
+# 1000 classes): the default path's parameter tree is pinned.
+RESNET_PARAMETERS = {"resnet18": 11_689_512, "resnet34": 21_797_672,
+                     "resnet50": 25_557_032, "resnet101": 44_549_160,
+                     "resnet152": 60_192_808}
+
+
+@pytest.mark.parametrize("name", sorted(RESNET_CONFIGS))
+def test_resnet_parameter_tree_is_the_published_one(name):
+    model = get_model(name).make_model()
+    images = jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32)
+    variables = jax.eval_shape(
+        lambda x: model.init(jax.random.PRNGKey(0), x, train=False), images)
+    leaves = jax.tree_util.tree_leaves_with_path(variables["params"])
+    assert sum(leaf.size for _, leaf in leaves) == RESNET_PARAMETERS[name]
+    names = {str(getattr(key, "key", key)) for path, _ in leaves
+             for key in path}
+    assert [n for n in names if n.startswith("fused_")] == []
 
 
 def test_scan_remat_matches_loop():

@@ -116,13 +116,13 @@ def test_render_table():
 
 
 def test_committed_baseline_is_loadable_and_complete():
-    """The checked-in BENCH_BASELINE.json must stay valid: every stage
+    """The checked-in scripts/perf_baseline.json must stay valid: every stage
     the harness measures is present with a usable value, so the CI
     warn-compare actually compares."""
     import json
 
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCH_BASELINE.json")
+        os.path.abspath(__file__))), "scripts", "perf_baseline.json")
     base = json.load(open(path))
     assert base.get("kind") == "horovod_perf_report"
     assert base.get("build", {}).get("version")

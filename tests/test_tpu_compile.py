@@ -64,7 +64,7 @@ def test_flash_forward_backward_compiles_for_v5e(v5e_devices, S, causal,
 
 @pytest.mark.parametrize("n", [1, 4])
 def test_gpt2_small_train_step_compiles_for_v5e(v5e_devices, n):
-    """The flagship step of bench.py and chip_smoke.py: gpt2-small as
+    """The step chip_smoke.py runs: gpt2-small as
     the registry publishes it, seq 2048, batch 4 per chip, flash, bf16
     logits, adamw — on one device and on a dp=4 mesh."""
     seq, batch = 2048, 4 * n
