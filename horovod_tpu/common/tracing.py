@@ -98,12 +98,12 @@ SCOPE_OPTIMIZER = "hvd.optimizer"
 #: projections, the two inner norms, the rotary positions
 #: (`models/latent_moe.py::LatentAttention`); not the kernel.
 SCOPE_ATTN_LATENT = "hvd.attn.latent"
-#: A routed layer's routing: router, top-k, gates, the sort into
-#: expert order, the gather into the dispatch buffer, and the weighted
-#: gather back (`models/latent_moe.py::RoutedExperts`).
+#: A routed layer's routing: router, top-k, gates, the sorts, the
+#: kernels that move rows into the dispatch buffer and sum them back
+#: (`models/latent_moe.py::RoutedExperts`, `ops/routed_rows.py`).
 SCOPE_MOE_ROUTE = "hvd.moe.route"
 #: A routed layer's expert work: the grouped products over the experts
-#: held here and the shared expert.
+#: held here, the activation between them, and the shared expert.
 SCOPE_MOE_EXPERTS = "hvd.moe.experts"
 #: The multi-token-prediction module, outermost: its norms, projection
 #: and block, and the shared head applied to it.

@@ -26,9 +26,10 @@ mechanism (field names are the published `config.json` keys):
   nothing stands in for the absent experts: their part of the sum is
   left out. **No token is dropped under any routing**: the dispatch
   buffer has a row for every (token, expert) pair, the chosen-and-held
-  pairs sorted to its front by expert, and `ops/grouped_matmul.py`
-  multiplies the rows that hold one; its work follows the rows present
-  and nothing branches on the device (`held_experts`);
+  pairs sorted to its front by expert; `ops/routed_rows.py` moves and
+  `ops/grouped_matmul.py` multiplies the rows that hold one: the work
+  follows the rows present and nothing branches on the device
+  (`held_experts`);
 * a per-layer pattern: `first_k_dense_replace` leading dense layers,
   routed layers after;
 * **a multi-token-prediction module** (`MTPModule`, DeepSeek-V3 report
@@ -54,7 +55,6 @@ Regions of the XLA profile: `hvd.attn.latent`, `hvd.moe.route`,
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -63,6 +63,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..common import telemetry, tracing
+from ..ops import routed_rows
 from ..ops.grouped_matmul import grouped_matmul
 from ..parallel.train import AUX_COLLECTION
 from .transformer import _attention_dispatch, _dense, default_kernel_init
@@ -78,7 +79,8 @@ CHOSEN_EXPERTS = "moe_chosen_experts"
 
 _BUFFER_HELP = ("Routed-expert layer as built: experts held here, the "
                 "shares the experts are divided into, rows of the "
-                "dispatch buffer (kind: expected, buffer)")
+                "dispatch buffer (kind: expected, buffer) and the rows one "
+                "program of its kernels moves (kind: tile)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -265,64 +267,6 @@ def buffer_rows(tokens: int, cfg: LatentMoEConfig) -> tuple:
     return worst * cfg.held // cfg.n_routed_experts, worst
 
 
-def _held_rows(y, place, held, k: int):
-    """(T, k, D) float32: each pair's row of `y`, nought for a pair not
-    held here (its row is never read as a number)."""
-    rows = jnp.where(held[:, None], y[place], 0).astype(jnp.float32)
-    return rows.reshape(-1, k, y.shape[-1])
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(tokens, order, place, held, k: int):
-    """The dispatch buffer: row p holds the token of the p-th (token,
-    expert) pair in expert order, `tokens[order[p] // k]`, (T k, D).
-    Backward without a scatter: a token's gradient is the sum of its k
-    rows' (`place` is the inverse of `order`), those of pairs not held
-    here left out (nothing computed their rows)."""
-    return tokens[order // k]
-
-
-def _dispatch_fwd(tokens, order, place, held, k):
-    return tokens[order // k], (place, held)
-
-
-def _dispatch_bwd(k, residuals, g):
-    place, held = residuals
-    return (_held_rows(g, place, held, k).sum(1).astype(g.dtype),
-            None, None, None)
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _combine(y, gates, order, place, held, k: int):
-    """sum over a token's held pairs of gate x the pair's row of `y`
-    (T k, D), (T, D) float32: a gather by `place` and a sum over k, no
-    scatter. Backward: row p's gradient is its pair's gate x its
-    token's gradient, a gather again; the gathered rows are kept for
-    the gates' gradient."""
-    return _combine_fwd(y, gates, order, place, held, k)[0]
-
-
-def _combine_fwd(y, gates, order, place, held, k):
-    rows = _held_rows(y, place, held, k)
-    return (jnp.einsum("tk,tkd->td", gates.reshape(-1, k), rows),
-            (rows.astype(y.dtype), gates, order))
-
-
-def _combine_bwd(k, residuals, g):
-    rows, gates, order = residuals
-    per_token = g.astype(rows.dtype)
-    d_y = per_token[order // k] * gates[order][:, None].astype(rows.dtype)
-    d_gates = jnp.einsum("tkd,td->tk", rows, g,
-                         preferred_element_type=jnp.float32).reshape(-1)
-    return d_y, d_gates, None, None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
-
-
 def held_experts(tokens, gates, w_in, w_out, chosen, first: int, held: int):
     """sum over the chosen-and-held (token, expert) pairs of gate x
     Expert(token), (T, D) float32, for ANY routing. tokens (T, D), gates
@@ -332,28 +276,27 @@ def held_experts(tokens, gates, w_in, w_out, chosen, first: int, held: int):
     Every shape is static and nothing branches on the device: the
     dispatch buffer has a row for every pair, T k (every token may choose
     only experts held here), the pairs held sorted to its front by
-    expert; the grouped products visit the rows that hold a pair and
-    cost nothing for the rest (ops/grouped_matmul.py), whose contents
-    are never read as numbers. What does scale with the buffer is the
-    gather into it, the activation between the products and the gather
-    back."""
+    expert. Everything that touches the rows follows the rows that hold
+    a pair, a count known on the device: the grouped products
+    (ops/grouped_matmul.py), the gather into the buffer, the activation
+    between the products and the weighted sum back
+    (ops/routed_rows.py); the rows behind are never written and never
+    read as numbers. What still scales with the buffer: its allocation,
+    the sort into expert order (one more in the backward pass, of the
+    gates' gradients) and integer vectors of its length."""
     T, k = chosen.shape
-    f = w_out.shape[1]
     with jax.named_scope(tracing.SCOPE_MOE_ROUTE):
         local = chosen.reshape(T * k) - first
-        here = (local >= 0) & (local < held)
-        key = jnp.where(here, local, held)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        place = jnp.argsort(order).astype(jnp.int32)
-        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
-                        dtype=jnp.int32)
-        buffer = _dispatch(tokens, order, place, here, k)
+        routing = routed_rows.route(
+            jnp.where((local >= 0) & (local < held), local, held), gates,
+            held)
+        buffer = routed_rows.dispatch(tokens, routing)
     with jax.named_scope(tracing.SCOPE_MOE_EXPERTS):
-        h = grouped_matmul(buffer, w_in, sizes)
-        h = nn.silu(h[:, :f]) * h[:, f:]
-        y = grouped_matmul(h, w_out, sizes)
+        h = grouped_matmul(buffer, w_in, routing.sizes)
+        h = routed_rows.gated_activation(h, routing.held_rows)
+        y = grouped_matmul(h, w_out, routing.sizes)
     with jax.named_scope(tracing.SCOPE_MOE_ROUTE):
-        return _combine(y, gates.reshape(T * k), order, place, here, k)
+        return routed_rows.combine(y, gates, routing)
 
 
 class RoutedExperts(nn.Module):
@@ -370,7 +313,8 @@ class RoutedExperts(nn.Module):
         total, f = cfg.n_routed_experts, cfg.moe_intermediate_size
         first = cfg.expert_share * held
         labels = {"experts_held": str(held), "shares": str(total // held)}
-        for kind, n in zip(("expected", "buffer"), buffer_rows(T, cfg)):
+        for kind, n in zip(("expected", "buffer", "tile"),
+                           (*buffer_rows(T, cfg), routed_rows.ROW_TILE)):
             telemetry.gauge("horovod_moe_dispatch_rows", _BUFFER_HELP,
                             {**labels, "kind": kind}).set(n)
 
@@ -408,8 +352,12 @@ class RoutedExperts(nn.Module):
             self.sow(CHOICES_COLLECTION, "routed",
                      chosen.reshape(B, S, k).astype(jnp.int32))
             # (Not `top_k`'s own values: where the block is recomputed
-            # the gates are the scores of the choices that were kept.)
-            gates = jnp.take_along_axis(scores, chosen, axis=-1)
+            # the gates are the scores of the choices that were kept.
+            # Picked by comparison: a gather of T k scalars costs this
+            # chip more than the router's product.)
+            gates = jnp.sum(
+                jnp.where(chosen[:, :, None] == jnp.arange(total),
+                          scores[:, None, :], 0), axis=-1)
             if cfg.norm_topk_prob:
                 gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
                                  + 1e-20)
@@ -443,14 +391,19 @@ class Block(nn.Module):
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
+# One object for every block: jax caches what recomputation makes of a
+# jitted call inside a block (ops/routed_rows.py's, jnp's own) by the
+# policy's identity, so a policy built per block gives each block its
+# own copy of every such call to lower.
+_KEEP_CHOICES = jax.checkpoint_policies.save_only_these_names(CHOSEN_EXPERTS)
+
+
 def _block(cfg: LatentMoEConfig):
     """`Block`, recomputed in the backward pass where `cfg.remat`: all of
     it but the routed layer's discrete choices."""
     if not cfg.remat:
         return Block
-    return nn.remat(
-        Block, prevent_cse=True,
-        policy=jax.checkpoint_policies.save_only_these_names(CHOSEN_EXPERTS))
+    return nn.remat(Block, prevent_cse=True, policy=_KEEP_CHOICES)
 
 
 class TokenEmbedding(nn.Module):

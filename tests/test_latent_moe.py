@@ -4,11 +4,15 @@ the benchmark's plain float32 reference, the shares of the routed
 experts adding up to the uncut layer, no token dropped under any
 routing, the rotary positions against their formula, the attention
 kernel with query/key heads wider than value heads, the grouped
-product, the step's second loss term and the scopes in the lowered
-step."""
+product, the kernels that move a routed layer's rows against the plain
+formulas they replaced, what they cost the host to trace and lower, the
+step's second loss term and the scopes in the lowered step."""
+import collections
 import dataclasses
+import functools
 import json
 import pathlib
+import re
 
 import flax.linen as nn
 import jax
@@ -23,6 +27,7 @@ from benchmark.trainers import gspmd_mtp
 from horovod_tpu.common import telemetry, tracing
 from horovod_tpu.models import LATENT_MOE_CONFIGS, get_model, latent_moe
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import routed_rows
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
 from horovod_tpu.parallel.mesh import create_mesh
 from horovod_tpu.parallel.ring import dense_attention
@@ -151,20 +156,30 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("skew", ["all-held", "none-held", "one-expert"])
+@pytest.mark.parametrize("skew", ["all-held", "none-held", "one-expert",
+                                  "one-row-short-of-a-tile"])
 def test_no_token_is_dropped_under_skew(skew):
     """A router forced so that every token chooses only experts held
     here (every row of the dispatch buffer holds a pair), so that none
-    does (no row does), and so that every token sends one choice to the
-    same held expert: values and gradients are the reference's, which
+    does (no row does), so that every token sends one choice to the
+    same held expert, and so that the rows held end one short of a row
+    tile of the kernels (128 tokens, all but one choosing held experts
+    only): values and gradients are the reference's plain loop, which
     multiplies every token by every expert."""
     cfg, x, params = _layer_params()
     cfg = dataclasses.replace(cfg, experts_held=2, expert_share=1)
     x = x.at[..., 0].set(4.0)              # a component the router can key on
     router = jnp.zeros_like(params["router"])
     favoured = {"all-held": [2, 3], "none-held": [0, 7],
-                "one-expert": [3, 6]}[skew]
+                "one-expert": [3, 6],
+                "one-row-short-of-a-tile": [2, 3]}[skew]
     router = router.at[0, jnp.asarray(favoured)].set(3.0)
+    if skew == "one-row-short-of-a-tile":
+        # One token keys on another component, for experts 3 and 6.
+        x = jnp.concatenate([x, x[::-1]]).at[..., 1].set(0.0)
+        x = x.at[1, 5, :2].set(jnp.asarray([0.0, 4.0]))
+        router = router.at[1, jnp.asarray([3, 6])].set(3.0)
+        assert routed_rows.ROW_TILE == 2 * x.shape[0] * x.shape[1]
     router = router + 0.01 * params["router"]
     held = dict(params, router=router, gate_up=params["gate_up"][2:4],
                 down=params["down"][2:4])
@@ -183,7 +198,8 @@ def test_no_token_is_dropped_under_skew(skew):
         program, argnums=(0, 1), has_aux=True)(held, x)
     want, want_grads = jax.value_and_grad(reference, argnums=(0, 1))(held, x)
     here = int(jnp.sum((chosen >= 2) & (chosen < 4)))
-    assert here == {"all-held": 128, "none-held": 0, "one-expert": 64}[skew]
+    assert here == {"all-held": 128, "none-held": 0, "one-expert": 64,
+                    "one-row-short-of-a-tile": 255}[skew]
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
     for g, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
@@ -197,12 +213,218 @@ def test_the_layer_reports_what_it_was_built_with():
     labels = {"experts_held": "2", "shares": "4"}
     rows = {kind: telemetry.gauge("horovod_moe_dispatch_rows",
                                   labels={**labels, "kind": kind}).value
-            for kind in ("expected", "buffer")}
-    assert rows == {"expected": 32, "buffer": 128}
+            for kind in ("expected", "buffer", "tile")}
+    assert rows == {"expected": 32, "buffer": 128,
+                    "tile": routed_rows.ROW_TILE}
     with pytest.raises(ValueError):
         dataclasses.replace(TINY, experts_held=3)
     with pytest.raises(ValueError):
         dataclasses.replace(TINY, experts_held=2, expert_share=4)
+
+
+# ------------------------------------------ the kernels that move the rows
+
+ROUTED_HELD, ROUTED_K, ROUTED_T, ROUTED_D, ROUTED_F = 4, 2, 512, 128, 64
+ROUTINGS = ["even", "all-held", "none-held", "one-expert", "ragged",
+            "several-a-token"]
+
+
+def _routing(case: str):
+    """512 tokens (two blocks of the token-major kernel) x 2 choices
+    among 8 experts, the first 4 held (four row tiles): `even` random
+    distinct choices; every pair held (the buffer full); none; every
+    token's first choice the same held expert; group sizes 3, 17, 250
+    and 1, no multiple of a row tile or a chunk, their tokens anywhere;
+    a third of the tokens with both choices held and the rest with
+    none."""
+    rng = np.random.default_rng(5)
+    T, held = ROUTED_T, ROUTED_HELD
+    if case == "even":
+        chosen = np.argsort(rng.random((T, 2 * held)), axis=1)[:, :ROUTED_K]
+    elif case == "all-held":
+        chosen = np.argsort(rng.random((T, held)), axis=1)[:, :ROUTED_K]
+    elif case == "none-held":
+        chosen = held + np.argsort(rng.random((T, held)),
+                                   axis=1)[:, :ROUTED_K]
+    elif case == "one-expert":
+        chosen = np.stack([np.full(T, 2), rng.integers(held, 2 * held, T)], 1)
+    elif case == "ragged":
+        first = np.repeat([0, 1, 2, 3, 5], [3, 17, 250, 1, T - 271])
+        chosen = rng.permutation(np.stack([first, np.full(T, 6)], 1))
+    else:
+        both = rng.random(T) < 1 / 3
+        chosen = np.where(both[:, None], rng.permuted(
+            np.tile([[0, 1], [2, 3]], (T // 2, 1)), axis=1), [[4, 7]])
+    key = np.minimum(chosen, held).reshape(-1).astype(np.int32)
+    gates = jnp.asarray(rng.random((T, ROUTED_K)), jnp.float32)
+    routing = routed_rows.route(jnp.asarray(key), gates, held)
+    held_rows = int(np.sum(key < held))
+    assert int(routing.held_rows[0]) == held_rows
+    np.testing.assert_array_equal(np.asarray(routing.sizes),
+                                  np.bincount(key, minlength=held + 1)[:held])
+    if case == "several-a-token":
+        assert 0 < held_rows == 2 * int(np.sum(chosen[:, 0] < held))
+    return key, gates, routing, held_rows
+
+
+def _poisoned(x, held_rows):
+    """`x` with every row behind `held_rows` NaN: whatever reads one as
+    a number shows."""
+    return x.at[held_rows:].set(jnp.nan)
+
+
+# What the kernels replaced (models/latent_moe.py before ops/routed_rows.py),
+# on a buffer of T k rows: gathers at the size of the buffer. `order` is
+# the pair at each row, `place` its inverse, `here` whether a pair is held.
+
+def _plain_held_rows(y, place, here):
+    rows = jnp.where(here[:, None], y[place], 0).astype(jnp.float32)
+    return rows.reshape(-1, ROUTED_K, y.shape[-1])
+
+
+def _plain_dispatch(tokens, order):
+    return tokens[order // ROUTED_K]
+
+
+def _plain_dispatch_bwd(g, place, here):
+    return _plain_held_rows(g, place, here).sum(1).astype(g.dtype)
+
+
+def _plain_combine(y, gates, place, here):
+    return jnp.einsum("tk,tkd->td", gates, _plain_held_rows(y, place, here))
+
+
+def _plain_combine_bwd(y, gates, order, place, here, g):
+    rows = _plain_held_rows(y, place, here).astype(y.dtype)
+    d_y = (g.astype(y.dtype)[order // ROUTED_K]
+           * gates.reshape(-1)[order][:, None].astype(y.dtype))
+    d_gates = jnp.einsum("tkd,td->tk", rows, g,
+                         preferred_element_type=jnp.float32)
+    return d_y, d_gates
+
+
+def _plain_activation(h):
+    f = h.shape[1] // 2
+    return nn.silu(h[:, :f]) * h[:, f:]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", ROUTINGS)
+def test_the_kernels_equal_the_formulas_they_replaced(case, dtype):
+    """`dispatch`, `gated_activation` and `combine`, forward and every
+    gradient (tokens, `h`, `y`, gates), on the rows that hold a pair,
+    with what comes in NaN behind them: the plain gathers' values, equal
+    where the arithmetic is the same (moved rows, gate x gradient) and to
+    float32 rounding where a sum runs in another order."""
+    key, gates, routing, held_rows = _routing(case)
+    pairs, rows = len(key), routed_rows.padded_rows(len(key))
+    rng = np.random.default_rng(1)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape),
+                                        jnp.float32)
+    order = np.argsort(key, kind="stable")
+    place, here = np.argsort(order), key < ROUTED_HELD
+    as_f32 = lambda x: np.asarray(x, np.float32)
+    exact = dict(rtol=0, atol=0)
+    summed = dict(rtol=1e-5, atol=1e-5)
+
+    # Into the buffer, and its transpose.
+    tokens = normal(ROUTED_T, ROUTED_D).astype(dtype)
+    buffer, pull = jax.vjp(lambda t: routed_rows.dispatch(t, routing), tokens)
+    assert buffer.shape == (rows, ROUTED_D) and buffer.dtype == dtype
+    np.testing.assert_allclose(
+        as_f32(buffer[:held_rows]),
+        as_f32(_plain_dispatch(tokens, order)[:held_rows]), **exact)
+    g = _poisoned(normal(rows, ROUTED_D).astype(dtype), held_rows)
+    d_tokens, = pull(g)
+    assert d_tokens.dtype == dtype
+    np.testing.assert_allclose(
+        as_f32(d_tokens), as_f32(_plain_dispatch_bwd(g[:pairs], place, here)),
+        **(summed if dtype == jnp.float32 else dict(rtol=1e-2, atol=1e-2)))
+
+    # Between the grouped products.
+    h = normal(rows, 2 * ROUTED_F).astype(dtype)
+    act, pull = jax.vjp(lambda h: routed_rows.gated_activation(
+        _poisoned(h, held_rows), routing.held_rows), h)
+    want, plain_pull = jax.vjp(
+        lambda h: _plain_activation(h.astype(jnp.float32)), h)
+    rounded = (summed if dtype == jnp.float32
+               else dict(rtol=1e-2, atol=1e-2))
+    np.testing.assert_allclose(as_f32(act[:held_rows]),
+                               as_f32(want[:held_rows]), **rounded)
+    g = normal(rows, ROUTED_F).astype(dtype)
+    d_h, = pull(_poisoned(g, held_rows))
+    assert d_h.dtype == dtype
+    np.testing.assert_allclose(
+        as_f32(d_h[:held_rows]),
+        as_f32(plain_pull(g.astype(jnp.float32))[0][:held_rows]), **rounded)
+
+    # Back to the tokens, and the rows' and the gates' gradients.
+    y = _poisoned(normal(rows, ROUTED_D).astype(dtype), held_rows)
+    out, pull = jax.vjp(lambda y, gates: routed_rows.combine(
+        y, gates, routing), y, gates)
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_plain_combine(y[:pairs], gates, place,
+                                                   here)), **summed)
+    g = normal(ROUTED_T, ROUTED_D)
+    d_y, d_gates = pull(g)
+    want_y, want_gates = _plain_combine_bwd(y[:pairs], gates, order, place,
+                                            here, g)
+    assert d_y.dtype == dtype and d_gates.dtype == jnp.float32
+    np.testing.assert_allclose(as_f32(d_y[:held_rows]),
+                               as_f32(want_y[:held_rows]), **exact)
+    np.testing.assert_allclose(np.asarray(d_gates), np.asarray(want_gates),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("case", ROUTINGS)
+def test_the_layer_reads_no_row_behind_the_pairs(case):
+    """`held_experts`' chain with each buffer between its kernels
+    poisoned behind the rows that hold a pair (the dispatch buffer, `h`,
+    the activation, `y`, and on the way back their gradients): values
+    and gradients are finite and those of plain indexing over every
+    pair."""
+    key, gates, routing, held_rows = _routing(case)
+    rng = np.random.default_rng(4)
+    tokens = jnp.asarray(rng.standard_normal((ROUTED_T, ROUTED_D)),
+                         jnp.float32)
+    w_in = jnp.asarray(rng.standard_normal(
+        (ROUTED_HELD, ROUTED_D, 2 * ROUTED_F)) * 0.1, jnp.float32)
+    w_out = jnp.asarray(rng.standard_normal(
+        (ROUTED_HELD, ROUTED_F, ROUTED_D)) * 0.1, jnp.float32)
+
+    @jax.custom_vjp
+    def poison(x):
+        return _poisoned(x, held_rows)
+
+    poison.defvjp(lambda x: (poison(x), None),
+                  lambda _, g: (_poisoned(g, held_rows),))
+
+    def kernels(tokens, gates, w_in, w_out):
+        buffer = poison(routed_rows.dispatch(tokens, routing))
+        h = poison(grouped_matmul(buffer, w_in, routing.sizes))
+        h = poison(routed_rows.gated_activation(h, routing.held_rows))
+        y = poison(grouped_matmul(h, w_out, routing.sizes))
+        return jnp.sum(jnp.sin(routed_rows.combine(y, gates, routing)))
+
+    def plain(tokens, gates, w_in, w_out):
+        expert = jnp.minimum(key, ROUTED_HELD - 1)
+        x = tokens[jnp.arange(len(key)) // ROUTED_K]
+        h = jnp.einsum("pd,pdf->pf", x, w_in[expert])
+        y = jnp.einsum("pf,pfd->pd", _plain_activation(h), w_out[expert])
+        y = jnp.where((key < ROUTED_HELD)[:, None], y, 0)
+        return jnp.sum(jnp.sin(jnp.einsum(
+            "tk,tkd->td", gates, y.reshape(ROUTED_T, ROUTED_K, ROUTED_D))))
+
+    args = (tokens, gates, w_in, w_out)
+    got, got_grads = jax.value_and_grad(kernels, argnums=(0, 1, 2, 3))(*args)
+    want, want_grads = jax.value_and_grad(plain, argnums=(0, 1, 2, 3))(*args)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for g, w in zip(got_grads, want_grads):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
 
 
 # ------------------------------------------------------- rotary positions
@@ -426,6 +648,99 @@ def test_the_lowered_step_names_the_new_regions():
     assert "transpose(jvp(LatentMoELM))" in text
     for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
         assert kernel in text
+    # The rows move in kernels behind jits of their own, called under
+    # the layer's scopes forward, in the recomputed forward and backward;
+    # nothing under the routing scope gathers at the size of the dispatch
+    # buffer (2 x 64 tokens x 2 choices, 64 wide).
+    route, experts = tracing.SCOPE_MOE_ROUTE, tracing.SCOPE_MOE_EXPERTS
+    calls = set(re.findall(r'loc\("([^"]*)/jit\((_\w+)\)"', text))
+    for scope, entry, passes in (
+            (route, "_route", ("jvp", "rematted_computation")),
+            (route, "_gather_rows", ("jvp", "rematted_computation")),
+            (route, "_sum_rows", ("jvp", "transpose")),
+            (route, "_combine_bwd_rows", ("transpose",)),
+            (experts, "_silu_gate", ("jvp", "rematted_computation")),
+            (experts, "_silu_gate_bwd", ("transpose",))):
+        stacks = [stack for stack, name in calls if name == entry]
+        assert stacks and all(stack.endswith(f"/moe/{scope}")
+                              for stack in stacks), (entry, stacks)
+        for part in passes:
+            assert any(part in stack for stack in stacks), (entry, part)
+    pairs = 2 * SEQ * TINY.num_experts_per_tok
+    sized = f"({pairs}|{routed_rows.padded_rows(pairs)})x{TINY.hidden_size}x"
+    wide = [line for line in text.splitlines()
+            if "stablehlo.gather" in line and route in line
+            and re.search(rf"-> tensor<{sized}", line)]
+    assert wide == []
+
+
+KERNEL_BODIES = ("_gather_kernel", "_sum_kernel", "_combine_bwd_kernel",
+                 "_silu_gate_kernel", "_silu_gate_bwd_kernel")
+
+
+def test_a_kernel_is_traced_and_lowered_once_however_many_layers_call_it(
+        monkeypatch):
+    """What the routed layer's kernels cost the host, by counts.
+    Building and lowering the step of a model with two applications of
+    the layer (one routed layer and the module's block; `remat`) traces
+    each kernel body a fixed number of times: once per branch of the
+    platform rule, per output dtype and per tracing context (jax keys a
+    jit's trace by the mesh in scope, and `init`, the step and the
+    derivative rules are traced under three). A model with four
+    applications, built afterwards, traces none again: a module-level
+    jit keeps its trace for the life of the process. The step lowered
+    for the TPU holds each kernel's body once for the forward pass and,
+    where a block recomputes it, once for the recomputation (jax's
+    partial evaluation copies a jit it only evaluates), whatever the
+    number of layers. The widths, 96 and 48, are no other test's:
+    nothing here is in the caches beforehand."""
+    traced = collections.Counter()
+
+    def counting(name, body):
+        @functools.wraps(body)
+        def counted(*args, **kw):
+            traced[name] += 1
+            return body(*args, **kw)
+        return counted
+
+    for name in KERNEL_BODIES:
+        monkeypatch.setattr(routed_rows, name,
+                            counting(name, getattr(routed_rows, name)))
+
+    def lowered_kernels(layers: int):
+        model = _model(experts_held=2, remat=True, num_hidden_layers=layers,
+                       hidden_size=96, moe_intermediate_size=48)
+        mesh = create_mesh({"dp": 1}, devices=jax.devices()[:1])
+        ids = np.zeros((2, 40), np.int32)
+        init, step, _ = make_train_step(
+            model, optax.adamw(1e-3), lm_loss, mesh=mesh, donate=False,
+            aux_loss_fn=mtp_loss(0.3))(jax.random.PRNGKey(0), ids)
+        text = step.__wrapped__.trace(
+            init(jax.random.PRNGKey(0)), ids).lower(
+                lowering_platforms=("tpu",)).as_text()
+        found = collections.Counter(
+            re.findall(r'kernel_name = "(routed_\w+)"', text))
+        calls = collections.Counter(
+            re.findall(r"call @(_[a-z_]+?)(?:_\d+)?\(", text))
+        return found, calls
+
+    once = {"routed_gather_rows": 2, "routed_gated_activation": 2,
+            "routed_sum_rows": 2,       # float32 out, and the model's dtype
+            "routed_combine_bwd_rows": 1, "routed_gated_activation_bwd": 1}
+    two, calls = lowered_kernels(2)
+    first = dict(traced)
+    assert set(first) == set(KERNEL_BODIES)
+    branches, contexts = 2, 3
+    for name, count in first.items():
+        dtypes = 2 if name == "_sum_kernel" else 1
+        assert count % branches == 0, first
+        assert count <= branches * contexts * dtypes, first
+    assert two == once
+    assert calls["_gather_rows"] == 4 and calls["_sum_rows"] == 4
+    four, calls = lowered_kernels(4)
+    assert dict(traced) == first
+    assert four == once
+    assert calls["_gather_rows"] == 8 and calls["_sum_rows"] == 8
 
 
 # -------------------------------------------------------------- the registry
