@@ -98,6 +98,16 @@ SCOPE_OPTIMIZER = "hvd.optimizer"
 #: projections, the two inner norms, the rotary positions
 #: (`models/latent_moe.py::LatentAttention`); not the kernel.
 SCOPE_ATTN_LATENT = "hvd.attn.latent"
+#: A grouped-query attention's own work around the attention kernel: the
+#: q / k / v / gate / output projections, the rotary positions and the
+#: gate's product (`models/window_moe.py::GatedAttention`); not the
+#: kernel.
+SCOPE_ATTN_PROJ = "hvd.attn.proj"
+#: The attention call of a sliding-window layer, forward and backward
+#: (the kernels and the layout copies around them).
+SCOPE_ATTN_WINDOW = "hvd.attn.window"
+#: The attention call of a full (global) layer of the same model.
+SCOPE_ATTN_FULL = "hvd.attn.full"
 #: A routed layer's routing: router, top-k, gates, the sorts, the
 #: kernels that move rows into the dispatch buffer and sum them back
 #: (`models/latent_moe.py::RoutedExperts`, `ops/routed_rows.py`).

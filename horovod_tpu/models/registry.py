@@ -23,6 +23,7 @@ from .transformer import (
     TransformerLM,
 )
 from .vit import VIT_CONFIGS, ViT
+from .window_moe import WINDOW_MOE_CONFIGS, WindowMoELM
 
 
 @dataclasses.dataclass
@@ -78,6 +79,13 @@ def _registry() -> Dict[str, ModelSpec]:
         reg[name] = ModelSpec(
             name,
             (lambda c: (lambda **kw: LatentMoELM(
+                dataclasses.replace(c, **kw) if kw else c)))(cfg),
+            _token_batch(512, cfg.vocab_size), "lm",
+        )
+    for name, cfg in WINDOW_MOE_CONFIGS.items():
+        reg[name] = ModelSpec(
+            name,
+            (lambda c: (lambda **kw: WindowMoELM(
                 dataclasses.replace(c, **kw) if kw else c)))(cfg),
             _token_batch(512, cfg.vocab_size), "lm",
         )

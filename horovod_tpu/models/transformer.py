@@ -27,7 +27,6 @@ Logical axis vocabulary (mapped in parallel/sharding.py):
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
@@ -35,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.compat import get_abstract_mesh as _get_abstract_mesh
+from ..ops.attention import attention
 
 Dtype = Any
 
@@ -110,124 +109,13 @@ def _dense(features, cfg: TransformerConfig, name: str, logical_axes,
     )
 
 
-def _dense_attention_masked(cfg: TransformerConfig, q, k, v, mask):
-    Hd = q.shape[-1]
-    S = q.shape[1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(Hd)
-    scores = scores.astype(jnp.float32)
-    valid = None
-    if cfg.causal:
-        valid = jnp.tril(jnp.ones((S, S), dtype=bool))[None, None]
-    if mask is not None:
-        # mask: (B, S) 1 = attend, 0 = pad.
-        km = mask[:, None, None, :].astype(bool)
-        valid = km if valid is None else jnp.logical_and(valid, km)
-    if valid is not None:
-        scores = jnp.where(valid, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    if valid is not None:
-        # Fully-masked query rows yield zeros, not a uniform average of
-        # every value — matching the sp kernels' convention
-        # (parallel/ring.py _flash_block_update).
-        probs = jnp.where(valid, probs, 0.0)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype), v)
-
-
-def _attention_dispatch(cfg: TransformerConfig, q, k, v, mask):
-    """Choose dense vs flash (Pallas) vs sequence-parallel attention.
-    The sp kernels run in a nested shard_map that manualizes only
-    `cfg.sp_axis`; batch/head sharding stays under GSPMD."""
-    if cfg.attn_impl == "flash":
-        # Fused Pallas kernel (ops/flash_attention.py): compiled on TPU,
-        # interpreter elsewhere. Not combined with sp sharding — for
-        # sequence parallelism use ring/ulysses. Under a GSPMD mesh the
-        # opaque pallas_call would otherwise force full replication
-        # (GSPMD can't partition through it), so batch/head axes are
-        # manualized with shard_map; attention is independent per
-        # (batch, head), so no collectives are needed inside.
-        from ..ops.flash_attention import flash_attention
-
-        am = _get_abstract_mesh()
-        manual = [
-            ax for ax in ("dp", "tp") if am is not None
-            and ax in am.axis_names and am.shape[ax] > 1
-        ]
-        if not manual:
-            return flash_attention(q, k, v, mask, causal=cfg.causal).astype(
-                cfg.dtype)
-        from jax.sharding import PartitionSpec as P
-
-        from ..utils.compat import shard_map
-
-        dp = "dp" if "dp" in manual else None
-        tp = "tp" if "tp" in manual else None
-        qkv_spec = P(dp, None, tp, None)   # (B, S, H, D)
-        mask_spec = P(dp, None)            # (B, S)
-
-        if mask is None:
-            fn = shard_map(
-                lambda q, k, v: flash_attention(q, k, v,
-                                                causal=cfg.causal),
-                mesh=am, in_specs=(qkv_spec,) * 3, out_specs=qkv_spec,
-                axis_names=set(manual),
-            )
-            return fn(q, k, v).astype(cfg.dtype)
-        fn = shard_map(
-            lambda q, k, v, m: flash_attention(q, k, v, m,
-                                               causal=cfg.causal),
-            mesh=am, in_specs=(qkv_spec,) * 3 + (mask_spec,),
-            out_specs=qkv_spec, axis_names=set(manual),
-        )
-        return fn(q, k, v, mask).astype(cfg.dtype)
-    if cfg.attn_impl not in ("ring", "ulysses"):
-        return _dense_attention_masked(cfg, q, k, v, mask)
-    am = _get_abstract_mesh()
-    if am is None or cfg.sp_axis not in am.axis_names \
-            or am.shape[cfg.sp_axis] == 1:
-        return _dense_attention_masked(cfg, q, k, v, mask)
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.ring import ring_attention
-    from ..parallel.ulysses import ulysses_attention
-    from ..utils.compat import shard_map
-
-    if cfg.attn_impl == "ring":
-        impl = ring_attention
-    else:
-        impl = functools.partial(ulysses_attention,
-                                 use_flash=cfg.sp_use_flash)
-    manual = {cfg.sp_axis}
-    dp = tp = None
-    if cfg.attn_impl != "ring" and cfg.sp_use_flash:
-        # The flash pallas_call is opaque to GSPMD: batch/head axes must
-        # be manualized too, or every dp/tp rank replicates the full
-        # attention (same reason as the attn_impl="flash" branch above).
-        dp = "dp" if "dp" in am.axis_names and am.shape["dp"] > 1 else None
-        tp = "tp" if "tp" in am.axis_names and am.shape["tp"] > 1 else None
-        manual |= {ax for ax in (dp, tp) if ax}
-    spec = P(dp, cfg.sp_axis, tp)       # (B, S, H, D)
-    mask_spec = P(dp, cfg.sp_axis)      # (B, S)
-
-    if mask is None:
-        fn = shard_map(
-            lambda q, k, v: impl(q, k, v, cfg.sp_axis, causal=cfg.causal),
-            mesh=am,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            axis_names=manual,
-        )
-        return fn(q, k, v)
-    # Padding mask rides sequence-sharded like K/V; each kernel handles
-    # distribution itself (ring rotates it, Ulysses all-gathers it).
-    fn = shard_map(
-        lambda q, k, v, m: impl(q, k, v, cfg.sp_axis, causal=cfg.causal,
-                                mask=m),
-        mesh=am,
-        in_specs=(spec, spec, spec, mask_spec),
-        out_specs=spec,
-        axis_names=manual,
-    )
-    return fn(q, k, v, mask)
+def _attention_dispatch(cfg, q, k, v, mask):
+    """`ops/attention.py::attention` told what a configuration of this
+    family (or of `models/latent_moe.py`) says about its attention."""
+    return attention(q, k, v, causal=cfg.causal, mask=mask,
+                     impl=cfg.attn_impl,
+                     sp_axis=getattr(cfg, "sp_axis", "sp"),
+                     sp_use_flash=getattr(cfg, "sp_use_flash", False))
 
 
 class MultiHeadAttention(nn.Module):

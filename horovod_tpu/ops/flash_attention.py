@@ -98,10 +98,12 @@ def _scaled(x, scale: float):
     return (x.astype(jnp.float32) * scale).astype(x.dtype)
 
 
-def _valid_t(mask_col, kb, qi, block_k: int, block_q: int, causal: bool):
+def _valid_t(mask_col, kb, qi, block_k: int, block_q: int, causal: bool,
+             window: Optional[int] = None):
     """(block_k, block_q) validity of a key-major score tile: `mask_col`
     is the (block_k, 1) key-padding mask or None (no padding), the
-    causal rule compares global key and query positions."""
+    causal rule compares global key and query positions, and a window
+    keeps the keys less than `window` positions behind the query."""
     valid = None if mask_col is None else mask_col > 0
     if causal:
         kpos = kb * block_k + jax.lax.broadcasted_iota(
@@ -109,12 +111,15 @@ def _valid_t(mask_col, kb, qi, block_k: int, block_q: int, causal: bool):
         qpos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_k, block_q), 1)
         below = kpos <= qpos
+        if window is not None:
+            below = jnp.logical_and(below, qpos - kpos < window)
         valid = below if valid is None else jnp.logical_and(valid, below)
     return valid
 
 
 def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
-            causal: bool, block_q: int, block_k: int, plain: bool):
+            causal: bool, block_q: int, block_k: int, plain: bool,
+            window: Optional[int] = None):
     """One (batch*head, q-block) grid step, streaming k-blocks.
 
     q_ref: (1, block_q, Dqk); k_ref: (1, S_pad, Dqk) and v_ref:
@@ -138,6 +143,17 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
     exp(NEG_INF - max) is exactly 0. The masked twin (a padding mask,
     or padded keys) keeps the second select: a tile may hold no valid
     key at all.
+
+    `window` (causal only): the sweep starts at the key tile that holds
+    the oldest key the q-block's first query sees, so a q-block visits
+    the tiles that intersect its band and no other (two 512-wide tiles
+    at a window of 512, whatever S); the tiles the band's lower edge
+    crosses are masked like the diagonal's, the ones between run the
+    mask-free body. One select still suffices on the `plain` path,
+    though the first tile swept may hold no key a given row sees: that
+    row's maximum stays NEG_INF, its probabilities read exp(0) = 1, and
+    the first tile with a visible key (its own position's, at the
+    latest) multiplies what they summed to by exp(NEG_INF - max) = 0.
     """
     qi = pl.program_id(1)
     fold = _folds_exactly(scale)
@@ -163,7 +179,8 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
         if masked:
             mask_col = None if plain else mask_ref[
                 0, 0, pl.ds(kb * block_k, block_k)][:, None]
-            valid = _valid_t(mask_col, kb, qi, block_k, block_q, causal)
+            valid = _valid_t(mask_col, kb, qi, block_k, block_q, causal,
+                             window)
             s_t = jnp.where(valid, s_t, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s_t, axis=0, keepdims=True))
         p_t = jnp.exp(s_t - m_new)
@@ -190,7 +207,26 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
         jnp.zeros((1, block_q), jnp.float32),
     )
     n_full = 0
-    if plain and causal:
+    if window is not None:
+        first_q = qi * block_q
+        # The tile of the oldest key the first query sees.
+        n_full = jnp.minimum(
+            jnp.maximum(first_q - window + 1, 0) // block_k, num_kb)
+        if plain:
+            # Tiles from the first whose every key the LAST query still
+            # sees, up to the last wholly under the first query.
+            inside = jnp.clip(
+                (jnp.maximum(last_q + 1 - window, 0) + block_k - 1)
+                // block_k, n_full, num_kb)
+            under = jnp.clip(first_q // block_k, inside, num_kb)
+            carry = jax.lax.fori_loop(
+                n_full, inside, lambda kb, c: tile(kb, c, masked=True),
+                carry)
+            carry = jax.lax.fori_loop(
+                inside, under, lambda kb, c: tile(kb, c, masked=False),
+                carry)
+            n_full = under
+    elif plain and causal:
         # Tiles whose last key row sits at/below this q-block's first
         # query row need no causal masking at all.
         n_full = (qi * block_q) // block_k
@@ -210,17 +246,21 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
 
 
 # Mosaic gives a kernel 16 MiB of VMEM unless told otherwise. The
-# backward keeps Q, dO, dQ and an f32 dQ accumulator of the whole
-# sequence resident; at heads wider than one 128-lane tile (192 fills
-# two) and S = 4096 that is 17.3 MiB. A v5e core has 128 MiB.
+# backward keeps Q, dO, dQ (two buffers each) and an f32 dQ accumulator
+# of the whole sequence resident; at heads wider than one 128-lane tile
+# (192 fills two) and S = 4096 that is 17.3 MiB, and as much at one
+# lane tile and S = 8192 (the forward's K and V there: 8 MiB before its
+# tiles). A v5e core has 128 MiB.
 WIDE_HEAD_VMEM_BYTES = 64 * 2 ** 20
+LONG_SEQUENCE = 4096
 
 
-def _compiler_params(*head_dims: int) -> dict:
+def _compiler_params(*head_dims: int, seq: int = 0) -> dict:
     """`pallas_call` keywords: nothing for heads within one lane tile
-    (the call, and its lowered text, are then what they always were),
-    a raised VMEM limit for wider ones."""
-    if max(head_dims) <= 128:
+    at sequences up to LONG_SEQUENCE (the call, and its lowered text,
+    are then what they always were), a raised VMEM limit for wider
+    heads or longer sequences."""
+    if max(head_dims) <= 128 and seq <= LONG_SEQUENCE:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=WIDE_HEAD_VMEM_BYTES)}
@@ -260,9 +300,28 @@ def _blocks(S: int, Dqk: int, Dv: int) -> "tuple[int, int]":
     return block, block
 
 
+def key_tiles(S: int, Dqk: int, Dv: int, window: Optional[int] = None
+              ) -> "tuple[int, int]":
+    """(visited, under_diagonal): key tiles the forward kernel's sweeps
+    touch for one (batch, head) at sequence length S, causal, with the
+    tiles `_blocks` gives, and the tiles at or under the diagonal, which
+    a kernel that masked the window without skipping would touch. The
+    backward kernel's sweeps touch the same (key tile, query tile)
+    pairs."""
+    bq, bk = _blocks(S, Dqk, Dv)
+    visited = under = 0
+    for qi in range(-(-S // bq)):
+        diagonal = min(((qi + 1) * bq - 1) // bk, -(-S // bk) - 1)
+        first = 0 if window is None else max(qi * bq - window + 1, 0) // bk
+        visited += diagonal - first + 1
+        under += diagonal + 1
+    return visited, under
+
+
 def _prep(q, k, v, mask, block_q: Optional[int]):
     """Shared layout/padding for forward and backward: (B,S,H,D) ->
-    (B*H,S,D), each tensor at its own D, with queries padded to a
+    (B*H,S,D), each tensor at its own D and its own H (grouped keys and
+    values have fewer heads than the queries), with queries padded to a
     block_q multiple (garbage rows sliced off after) and keys/values/
     mask padded to a block_k multiple (padded keys carry mask 0, so they
     never contribute). Both passes MUST use identical block/pad
@@ -283,7 +342,8 @@ def _prep(q, k, v, mask, block_q: Optional[int]):
 
     # (B, S, H, D) -> (B*H, S, D): attention is independent per (b, h).
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, S, x.shape[-1])
+        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S,
+                                               x.shape[-1])
 
     qb, kb_arr, vb = to_bh(q), to_bh(k), to_bh(v)
     if pad_q:
@@ -306,20 +366,53 @@ def _prep(q, k, v, mask, block_q: Optional[int]):
             plain)
 
 
+def _kv_block(H: int, H_kv: int):
+    """Index of a (batch, query head) program's key/value head in the
+    (B * H_kv, S, D) layout: itself where every query head has its own
+    (the index map, and the lowered call, are then what they always
+    were), else head `h // (H / H_kv)` of its batch row. A group's query
+    heads are neighbours on the grid, so a group's K and V are fetched
+    once."""
+    if H_kv == H:
+        return lambda bh: bh
+    group = H // H_kv
+    return lambda bh: (bh // H) * H_kv + (bh % H) // group
+
+
+def _shares_the_older_calls_text(q, k, window) -> bool:
+    """Whether a call is one the kernels took before they knew grouped
+    heads and windows. Such a call is traced in line, as it always was:
+    the benchmark's older cells keep their lowered steps to the letter.
+    Every other call goes through a module-level `jax.jit`, so that a
+    model's layers of one shape trace and lower the kernel once (a
+    `custom_vjp` around a jit keeps recomputation's rules out of it;
+    PERF.md section 6, PR 34)."""
+    return window is None and k.shape[2] == q.shape[2]
+
+
 def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
-               interpret: Optional[bool]
+               interpret: Optional[bool], window: Optional[int] = None
                ) -> "tuple[jax.Array, jax.Array]":
+    if _shares_the_older_calls_text(q, k, window):
+        return _fwd_call(q, k, v, mask, causal, block_q, interpret, None)
+    return _fwd_call_once(q, k, v, mask, causal, block_q, interpret, window)
+
+
+def _fwd_call(q, k, v, mask, causal: bool, block_q: int,
+              interpret: Optional[bool], window: Optional[int]):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
     qb, kb_arr, vb, mask2, _, bq, bk, Sq, Sk, plain = _prep(q, k, v,
                                                             mask, block_q)
     grid = (B * H, Sq // bq)
+    kv = _kv_block(H, k.shape[2])
 
     def call(interp: bool):
         return pl.pallas_call(
             functools.partial(_kernel, scale=scale, causal=causal,
-                              block_q=bq, block_k=bk, plain=plain),
+                              block_q=bq, block_k=bk, plain=plain,
+                              window=window),
             out_shape=[
                 jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
@@ -327,8 +420,8 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
-                pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0)),
-                pl.BlockSpec((1, Sk, Dv), lambda bh, qi: (bh, 0, 0)),
+                pl.BlockSpec((1, Sk, D), lambda bh, qi: (kv(bh), 0, 0)),
+                pl.BlockSpec((1, Sk, Dv), lambda bh, qi: (kv(bh), 0, 0)),
                 # mask indexed by batch = bh // H (static H via closure).
                 pl.BlockSpec((1, 1, Sk),
                              lambda bh, qi, H=H: (bh // H, 0, 0)),
@@ -339,7 +432,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
             ],
             interpret=interp,
             name="flash_attention_fwd",
-            **_compiler_params(D, Dv),
+            **_compiler_params(D, Dv, seq=Sk),
         )
 
     out, lse = call_by_platform(call, qb, kb_arr, vb, mask2,
@@ -351,9 +444,13 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
     return out.reshape(B, H, S, Dv).transpose(0, 2, 1, 3), lse[:, :, :S]
 
 
+_fwd_call_once = jax.jit(_fwd_call, static_argnums=(4, 5, 6, 7))
+
+
 def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dk_ref, dv_ref, dq_acc, *, scale: float,
-                 causal: bool, block_q: int, block_k: int, plain: bool):
+                 causal: bool, block_q: int, block_k: int, plain: bool,
+                 window: Optional[int] = None):
     """FUSED backward: grid (B*H, k-block), ki innermost. One sweep
     computes dK/dV for this k-block AND accumulates every q-block's dQ
     contribution into a persistent f32 VMEM scratch (written out once,
@@ -374,7 +471,16 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     sequentially with the last dim innermost, so dq_acc persists across
     the ki sweep of one (b, h) program and is re-zeroed at ki=0.
     Padded q rows carry lse=+inf, killing their p rows — which is what
-    keeps the `plain` fast path valid under q padding."""
+    keeps the `plain` fast path valid under q padding.
+
+    `window`: the sweep ends at the q-block of the last query that
+    still sees this k-block's last key; the q-blocks the band's far
+    edge crosses are masked like the diagonal's, those between run the
+    mask-free body.
+
+    Grouped heads: a program is a QUERY head's; it reads its group's K
+    and V block (`_kv_block`) and writes dK / dV of its own, which
+    `_flash_bwd` sums over the group."""
     ki = pl.program_id(1)
     fold = _folds_exactly(scale)
     k = k_ref[0]                                 # (bk, Dqk)
@@ -403,7 +509,8 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         if masked:
             mask_col = None if plain else mask_ref[0, 0][:, None]
             p_t = jnp.where(
-                _valid_t(mask_col, ki, qi, block_k, block_q, causal),
+                _valid_t(mask_col, ki, qi, block_k, block_q, causal,
+                         window),
                 p_t, 0.0)
         dv_t = dv_t + _dot(do_blk, p_t.astype(do_blk.dtype), (0, 1))
         dp_t = _dot(v, do_blk, (1, 1))           # (bk, bq)
@@ -416,7 +523,26 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     start_qb = (ki * block_k) // block_q if causal else 0
     carry = (jnp.zeros((D, block_k), jnp.float32),
              jnp.zeros((Dv, block_k), jnp.float32))
-    if plain and causal:
+    if window is not None:
+        first_k = ki * block_k
+        # One past the q-block of the last query that sees the last key.
+        num_qb = jnp.minimum(
+            (first_k + block_k + window - 2) // block_q + 1, num_qb)
+    if window is not None and plain:
+        diag_end = jnp.minimum(
+            (first_k + block_k + block_q - 1) // block_q, num_qb)
+        # q-blocks whose LAST query still sees this k-block's first key.
+        inside = jnp.clip((first_k + window) // block_q, diag_end, num_qb)
+        carry = jax.lax.fori_loop(
+            start_qb, diag_end, lambda qi, c: tile(qi, c, masked=True),
+            carry)
+        carry = jax.lax.fori_loop(
+            diag_end, inside, lambda qi, c: tile(qi, c, masked=False),
+            carry)
+        carry = jax.lax.fori_loop(
+            inside, num_qb, lambda qi, c: tile(qi, c, masked=True), carry)
+        start_qb = num_qb
+    elif plain and causal:
         # q-blocks straddling the diagonal first, then the mask-free rest.
         diag_end = jnp.minimum(
             ((ki + 1) * block_k + block_q - 1) // block_q, num_qb)
@@ -425,7 +551,8 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
             carry)
         start_qb = diag_end
     carry = jax.lax.fori_loop(
-        start_qb, num_qb, lambda qi, c: tile(qi, c, masked=not plain),
+        start_qb, num_qb,
+        lambda qi, c: tile(qi, c, masked=not plain or window is not None),
         carry)
     dk_t, dv_t = carry
     dk_ref[0] = (dk_t * scale).T.astype(dk_ref.dtype)
@@ -438,10 +565,31 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
-               interpret: Optional[bool]):
+               interpret: Optional[bool], window: Optional[int] = None):
+    if _shares_the_older_calls_text(q, k, window):
+        return _bwd_call(q, k, v, mask, out, lse, g, causal, block_q,
+                         interpret, None)
+    return _bwd_call_once(q, k, v, mask, out, lse, g, causal, block_q,
+                          interpret, window)
+
+
+def _bwd_call(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
+              interpret: Optional[bool], window: Optional[int]):
     """Blockwise backward: same VMEM-bounded structure as the forward —
-    the (S, S) score matrix is never materialized in HBM."""
+    the (S, S) score matrix is never materialized in HBM.
+
+    Grouped heads: the kernel writes dK and dV per QUERY head and the
+    group's are summed here, in float32, where XLA fuses the sum into
+    the transpose back to (B, S, H_kv, D) that follows anyway. That
+    costs HBM traffic: H / H_kv times the write and the read of dK and
+    dV (at B 2, S 8192, 64 query heads over 8, head 128, bf16: 0.54 GB
+    written and read again instead of 0.07, about 1.2 ms of a 819 GB/s
+    chip a call). Summing inside the kernel would take a grid whose
+    inner axis is the group, and with it either the group's Q, dO and
+    dQ resident at once (8 x 6 MiB at S 8192) or a dQ accumulator per
+    group member: not worth 1% of the step."""
     B, S, H, D = q.shape
+    H_kv = k.shape[2]
     Dv = v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
     qb, kb_arr, vb, mask2, to_bh, bq, bk, Sq, Sk, plain = _prep(
@@ -466,11 +614,15 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
     row_q = pl.BlockSpec((1, 1, Sq), lambda bh, ki: (bh, 0, 0))
     blk_k = pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0))
     blk_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: (bh, ki, 0))
+    kv = _kv_block(H, H_kv)
+    in_k = pl.BlockSpec((1, bk, D), lambda bh, ki: (kv(bh), ki, 0))
+    in_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: (kv(bh), ki, 0))
 
     def call(interp: bool):
         return pl.pallas_call(
             functools.partial(_dqkv_kernel, scale=scale, causal=causal,
-                              block_q=bq, block_k=bk, plain=plain),
+                              block_q=bq, block_k=bk, plain=plain,
+                              window=window),
             out_shape=[
                 jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
                 jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
@@ -479,7 +631,7 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
             grid=(B * H, Sk // bk),
             in_specs=[
                 full_q,
-                blk_k, blk_v,
+                in_k, in_v,
                 pl.BlockSpec((1, 1, bk),
                              lambda bh, ki, H=H: (bh // H, 0, ki)),
                 full_do, row_q, row_q,
@@ -491,7 +643,7 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
             scratch_shapes=[pltpu.VMEM((D, Sq), jnp.float32)],
             interpret=interp,
             name="flash_attention_bwd",
-            **_compiler_params(D, Dv),
+            **_compiler_params(D, Dv, seq=Sq),
         )
 
     dq, dk, dv = call_by_platform(call, qb, kb_arr, vb, mask2, dob, lse,
@@ -500,17 +652,33 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
     def from_bh(x, S_):
         return x[:, :S_].reshape(B, H, S_, x.shape[-1]).transpose(0, 2, 1, 3)
 
-    return from_bh(dq, S), from_bh(dk, S), from_bh(dv, S)
+    if H_kv == H:
+        return from_bh(dq, S), from_bh(dk, S), from_bh(dv, S)
+
+    def group_sum(x):
+        per_head = x[:, :S].reshape(B, H_kv, H // H_kv, S, x.shape[-1])
+        return jnp.sum(per_head.astype(jnp.float32), axis=2).astype(
+            x.dtype).transpose(0, 2, 1, 3)
+
+    return from_bh(dq, S), group_sum(dk), group_sum(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+_bwd_call_once = jax.jit(_bwd_call, static_argnums=(7, 8, 9, 10))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention(q, k, v, mask=None, causal: bool = True,
                     block_q: Optional[int] = None,
-                    interpret: Optional[bool] = None):
-    """Fused attention. q/k: (B, S, H, Dqk), v: (B, S, H, Dv); mask:
-    optional (B, S) key validity (1 = attend). Returns (B, S, H, Dv) in
-    q.dtype; scores are scaled by 1/sqrt(Dqk). Dqk == Dv in GPT-2 /
-    BERT / ViT; a latent attention has 192 beside 128.
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """Fused attention. q: (B, S, H, Dqk), k: (B, S, H_kv, Dqk), v:
+    (B, S, H_kv, Dv); mask: optional (B, S) key validity (1 = attend).
+    Returns (B, S, H, Dv) in q.dtype; scores are scaled by 1/sqrt(Dqk).
+    Dqk == Dv in GPT-2 / BERT / ViT; a latent attention has 192 beside
+    128. H_kv divides H: query head h attends key/value head
+    h // (H / H_kv). `window` (causal only): key j is visible to query
+    i iff 0 <= i - j < window; both kernels then visit the key tiles a
+    query tile's band intersects and no other.
 
     Tile sizes come from `_blocks(S, Dqk, Dv)`; an explicit `block_q`
     replaces the first. Both vjp passes resolve the pair identically in
@@ -518,19 +686,21 @@ def flash_attention(q, k, v, mask=None, causal: bool = True,
     `interpret=None` follows ops/pallas_platform.py: the interpreter
     where the call is lowered for the CPU (the tests, the virtual
     8-device mesh), the compiled kernel on anything else."""
-    out, _ = _flash_fwd(q, k, v, mask, causal, block_q, interpret)
+    if window is not None and not causal:
+        raise ValueError("a window is a causal window")
+    out, _ = _flash_fwd(q, k, v, mask, causal, block_q, interpret, window)
     return out
 
 
-def _fwd(q, k, v, mask, causal, block_q, interpret):
-    out, lse = _flash_fwd(q, k, v, mask, causal, block_q, interpret)
+def _fwd(q, k, v, mask, causal, block_q, interpret, window):
+    out, lse = _flash_fwd(q, k, v, mask, causal, block_q, interpret, window)
     return out, (q, k, v, mask, out, lse)
 
 
-def _bwd(causal, block_q, interpret, residuals, g):
+def _bwd(causal, block_q, interpret, window, residuals, g):
     q, k, v, mask, out, lse = residuals
     dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q,
-                            interpret)
+                            interpret, window)
     return dq, dk, dv, None
 
 

@@ -226,6 +226,174 @@ def test_scale_fold_only_where_the_scale_is_a_power_of_two(D, folds,
     assert same(taken, as_fold) is folds
 
 
+# ------------------------------------------- grouped heads and a window
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """16 x 16 tiles, so that a sequence of 80 positions is five tiles
+    each way at interpreter cost. The grouped / windowed entries sit
+    behind a module-level jit keyed by shapes, not by the tile table:
+    drop what it holds before and after."""
+    def clear():
+        fa._fwd_call_once.clear_cache()
+        fa._bwd_call_once.clear_cache()
+
+    clear()
+    monkeypatch.setattr(fa, "_blocks", lambda S, Dqk, Dv: (16, 16))
+    yield
+    clear()
+
+
+def _windowed_dense(q, k, v, window, mask=None):
+    from horovod_tpu.ops.attention import dense_attention as door_dense
+
+    return door_dense(q, k, v, causal=True, window=window, mask=mask)
+
+
+def _assert_flash_matches_windowed_dense(q, k, v, window, block_q=None,
+                                         mask=None, tol=2e-5):
+    """Forward and all three gradients, a cotangent on every position."""
+    w = jnp.asarray(np.random.RandomState(7).randn(
+        *q.shape[:3], v.shape[-1]), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, True, block_q, True, window)
+
+    got, vjp = jax.vjp(flash, q, k, v)
+    want, vjp_dense = jax.vjp(
+        lambda q, k, v: _windowed_dense(q, k, v, window, mask), q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+    for name, a, b in zip("qkv", vjp(w), vjp_dense(w)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=10 * tol, atol=10 * tol,
+                                   err_msg=f"d{name}")
+
+
+def _grouped_qkv(S, H, H_kv, D=16, B=1, seed=0):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(B, S, h, D), jnp.float32)
+                 for h in (H, H_kv, H_kv))
+
+
+@pytest.mark.parametrize("window", [None, 10, 16, 24, 200],
+                         ids=["none", "under-a-tile", "a-tile",
+                              "a-tile-and-a-half", "over-S"])
+@pytest.mark.parametrize("H_kv", [24, 4, 3], ids=["H", "H/6", "H/8"])
+def test_grouped_heads_and_window_match_dense(small_tiles, H_kv, window):
+    """Five tiles each way: query head h attends key/value head
+    h // (H / H_kv), key j is visible iff 0 <= i - j < window; dK and dV
+    are the group's sums."""
+    q, k, v = _grouped_qkv(80, 24, H_kv)
+    _assert_flash_matches_windowed_dense(q, k, v, window)
+
+
+@pytest.mark.parametrize("case", ["bq-under-bk", "q-and-k-padded",
+                                  "padding-mask", "one-key-tile"])
+def test_window_on_the_masked_paths(small_tiles, monkeypatch, case):
+    """The window where the mask-free body may not run (padded keys, a
+    padding mask), at block_q != block_k, and within one key tile."""
+    S, block_q, mask = 80, None, None
+    if case == "bq-under-bk":
+        monkeypatch.setattr(fa, "_blocks", lambda S, Dqk, Dv: (16, 32))
+        S = 96
+    elif case == "q-and-k-padded":
+        S = 75
+    elif case == "padding-mask":
+        mask = np.ones((1, S), np.float32)
+        mask[0, 60:] = 0.0
+        mask = jnp.asarray(mask)
+    else:
+        monkeypatch.setattr(fa, "_blocks", lambda S, Dqk, Dv: (16, 128))
+    q, k, v = _grouped_qkv(S, 4, 2, seed=1)
+    _assert_flash_matches_windowed_dense(q, k, v, 24, block_q, mask)
+
+
+def test_window_and_groups_at_the_default_geometry():
+    """512 x 512 tiles as `_blocks` gives them, four each way, a window
+    of a tile and a half; 6 query heads over one key/value head."""
+    q, k, v = _grouped_qkv(2048, 6, 1, seed=2)
+    _assert_flash_matches_windowed_dense(q, k, v, 768)
+
+
+def test_key_tiles_outside_the_window_are_skipped_not_masked(small_tiles):
+    """Values poisoned with NaN in every key tile that query tile 3's
+    band does not intersect: a kernel that visited those tiles and
+    masked their scores would still multiply 0 by NaN. Its rows of the
+    output and of dQ stay finite; so do dK and dV of key tile 1 when the
+    cotangent is poisoned on every query tile outside its band."""
+    S, W, tile = 80, 24, 16
+    q, k, v = _grouped_qkv(S, 4, 2, seed=3)
+    rows = slice(3 * tile, 4 * tile)          # queries 48-63 see keys 25-63
+    poisoned = v.at[:, :tile].set(jnp.nan).at[:, 4 * tile:].set(jnp.nan)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, None, True, None, True, W)
+
+    out, vjp = jax.vjp(flash, q, k, poisoned)
+    cot = jnp.zeros_like(out).at[:, rows].set(1.0)
+    dq, _, _ = vjp(cot)
+    assert np.isfinite(np.asarray(out[:, rows])).all()
+    assert np.isfinite(np.asarray(dq[:, rows])).all()
+    assert np.isnan(np.asarray(out[:, :tile])).any()
+    # Key tile 1 (keys 16-31) is seen by queries 16-54: tiles 1 to 3.
+    out, vjp = jax.vjp(flash, q, k, v)
+    cot = jnp.ones_like(out).at[:, :tile].set(jnp.nan).at[
+        :, 4 * tile:].set(jnp.nan)
+    _, dk, dv = vjp(cot)
+    keys = slice(tile, 2 * tile)
+    assert np.isfinite(np.asarray(dk[:, keys])).all()
+    assert np.isfinite(np.asarray(dv[:, keys])).all()
+
+
+@pytest.mark.parametrize("S,window,want", [
+    (8192, None, (136, 136)),    # 16 x 17 / 2 tiles under the diagonal
+    (8192, 512, (31, 136)),      # two a query tile but the first
+    (8192, 514, (45, 136)),      # two keys further: a third tile each
+    (2048, 512, (7, 10)),
+    (300, 64, (1, 1)),
+])
+def test_key_tiles_counts_the_forward_sweeps(S, window, want):
+    assert fa.key_tiles(S, 128, 128, window) == want
+
+
+# sha256 of `str(jax.make_jaxpr(...))` of one forward and one backward
+# call, bf16, causal, no mask, made with the kernels as they stood
+# before they took grouped heads and windows (PR 34's tree): a call
+# with H_kv == H and no window is still that kernel, to the letter. To
+# make them anew after a deliberate change to the kernels:
+# `_older_calls_jaxpr_sha(...)` on the tree before the change.
+_OLDER_CALLS = {
+    (4, 4096, 12, 64, 64):
+        "32a3237c524167f810395c4f0de9e08059c4129b06b995d06b991ef11c3afe2c",
+    (8, 2048, 12, 64, 64):
+        "7d2234b6a0479a5f9fa2b6ab150f7b4ed477ebc2aeae84e2d0728cab51b09310",
+    (2, 4096, 32, 192, 128):
+        "700bde44d0450fc16b2888f853c1d6b4d0ddea308ede4a4eb159360b4a67346e",
+}
+
+
+def _older_calls_jaxpr_sha(B, S, H, Dqk, Dv):
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((B, S, H, Dqk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((B, S, H, Dv), jnp.bfloat16)
+
+    def both(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, None, True, None, False), q, k, v)
+        return out, vjp(out)
+
+    return hashlib.sha256(
+        str(jax.make_jaxpr(both)(q, q, v)).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("shape", list(_OLDER_CALLS),
+                         ids=["gpt2-s4096", "gpt2-s2048", "joyai"])
+def test_ungrouped_unwindowed_call_is_the_kernel_it_was(shape):
+    assert _older_calls_jaxpr_sha(*shape) == _OLDER_CALLS[shape]
+
+
 def test_transformer_flash_impl_matches_dense():
     """Model-level: attn_impl='flash' produces the same forward as
     attn_impl='dense' (incl. padding mask)."""
