@@ -9,8 +9,9 @@ ledger — the standard goodput/badput accounting used to operate large
 training fleets, fed by four sources:
 
 * **Step demarcation** — an ``hvd.step()`` scope (or automatic
-  boundaries from ``optim/distributed.py``'s update path and
-  ``elastic/state.py`` commits) marks the edges of productive steps.
+  boundaries from ``parallel/step.py``'s ``wrap_step`` calls,
+  ``optim/distributed.py``'s update path and ``elastic/state.py``
+  commits) marks the edges of productive steps.
   Each completed step emits a ``step`` span into the PR 6 flight
   recorder with its exposed-comm share in the args.
 
@@ -65,11 +66,14 @@ KV_SCOPE = "goodput"
 KV_KEY = "status"
 
 # Step-boundary sources, ranked: an explicit hvd.step() scope always
-# wins; the optimizer update path beats elastic commits (a loop doing
-# both would otherwise count every step twice). The first boundary from
-# a higher-ranked source takes the counter over; lower-ranked
-# boundaries are ignored from then on.
-_SOURCE_RANK = {"commit": 1, "optim": 2, "explicit": 3}
+# wins; a `wrap_step` call whose step holds an optimizer update beats
+# the update's own marker (the call IS that step's host boundary, so
+# its compiled program stages no marker: parallel/step.py); the
+# optimizer update path beats elastic commits (a loop doing both would
+# otherwise count every step twice). The first boundary from a
+# higher-ranked source takes the counter over; lower-ranked boundaries
+# are ignored from then on.
+_SOURCE_RANK = {"commit": 1, "optim": 2, "wrap_step": 3, "explicit": 4}
 
 
 class _StepScope:
@@ -96,7 +100,7 @@ class _StepScope:
             # dropped from step attribution too (the totals keep it).
             self._led._take_exposed_window()
             return False
-        self._led._finish_step(self._t0_ns, clock.mono_ns())
+        self._led._finish_step(self._t0_ns, clock.mono_ns(), "explicit")
         return False
 
 
@@ -223,6 +227,9 @@ class GoodputLedger:
         self._m_steps = registry.counter(
             "horovod_goodput_steps_total",
             "Training steps demarcated by the goodput ledger")
+        # The same steps by the source that drove them, made on a
+        # source's first step (docs/metrics.md).
+        self._m_source_steps = {}
         self._m_step_s = registry.histogram(
             "horovod_goodput_step_seconds",
             "Wall duration of demarcated training steps")
@@ -551,7 +558,8 @@ class GoodputLedger:
         return w, st
 
     def auto_step(self, source: str):
-        """Automatic step boundary (optimizer update / state commit):
+        """Automatic step boundary (`wrap_step` call / optimizer
+        update / state commit):
         the time since the previous boundary from the SAME winning
         source is one step. The first boundary after a disruption (or
         ever) closes a step whose start was never observed — it still
@@ -563,9 +571,9 @@ class GoodputLedger:
         with self._lock:
             t0 = self._boundary_ns
             self._boundary_ns = now_ns
-        self._finish_step(t0, now_ns)
+        self._finish_step(t0, now_ns, source)
 
-    def _finish_step(self, t0_ns: Optional[int], t1_ns: int):
+    def _finish_step(self, t0_ns: Optional[int], t1_ns: int, source: str):
         timed = t0_ns is not None
         dur = max(t1_ns - t0_ns, 0) / 1e9 if timed else 0.0
         with self._lock:
@@ -588,6 +596,14 @@ class GoodputLedger:
                 self.timed_steps += 1
                 self.step_seconds += dur
         self._m_steps.inc()
+        by_source = self._m_source_steps.get(source)
+        if by_source is None:
+            by_source = self._m_source_steps[source] = self.registry.counter(
+                "horovod_goodput_steps_by_source_total",
+                "Demarcated training steps by the boundary source that "
+                "drove them",
+                labels={"source": source})
+        by_source.inc()
         if timed:
             self._m_step_s.observe(dur)
             self._m_exposed_step_s.observe(min(exposed, dur))

@@ -85,24 +85,33 @@ def _goodput_mark(idx):
         led.auto_step("optim")
 
 
-def _stage_traced_step_marker():
+def _mark_traced_step():
     """Goodput demarcation for TRACED optimizer updates, at the host
     call boundary (docs/goodput.md). The update body runs once at trace
     time, so calling auto_step here directly would count one step per
-    COMPILATION; instead a jax.debug.callback is staged into the
-    compiled program and fires on the host each time the jitted step
-    executes. Under shard_map every shard runs the body, so the marker
-    is gated on the all-axes-origin shard (summed axis_index == 0 over
-    every bound axis); under plain jit/pjit the program is logical and
-    the callback fires once per call.
+    COMPILATION.
 
-    Known limitation (multi-controller pods): debug callbacks fire
-    only for a process's LOCAL shards, and the origin shard lives on
-    process 0 — so on a one-process-per-host mesh only rank 0's
-    ledger is auto-demarcated by this marker. Multi-controller loops
-    should use the explicit `hvd.step()` scope (or elastic commits),
-    which demarcate every process; the single-controller regime this
-    marker serves is where neither exists inside a jitted loop."""
+    Traced under a `wrap_step` call, that call is the boundary: it
+    marks the step from the host, per call and per process, and
+    nothing is staged (parallel/step.py).
+
+    Elsewhere (a bare jax.jit, a raw shard_map) a jax.debug.callback is
+    staged into the compiled program and fires on the host each time
+    the jitted step executes. Under shard_map every shard runs the
+    body, so the marker is gated on the all-axes-origin shard (summed
+    axis_index == 0 over every bound axis); under plain jit/pjit the
+    program is logical and the callback fires once per call.
+
+    Known limitation of the staged marker (multi-controller pods):
+    debug callbacks fire only for a process's LOCAL shards, and the
+    origin shard lives on process 0 — so on a one-process-per-host mesh
+    only rank 0's ledger is auto-demarcated by it. Multi-controller
+    loops should use `wrap_step`, the explicit `hvd.step()` scope or
+    elastic commits, which demarcate every process."""
+    from ..parallel.step import claim_step_boundary
+
+    if claim_step_boundary():
+        return
     from ..ops import _bound_axes
     from ..utils.compat import axis_index as _axis_index
 
@@ -170,9 +179,10 @@ def DistributedOptimizer(
     def update_fn(grads, state, params=None, **extra):
         # Goodput step demarcation (docs/goodput.md): every eager
         # optimizer update is one training step. Under jit this body
-        # runs once at trace time, so traced updates stage a
+        # runs once at trace time, so a traced update leaves the mark
+        # to the `wrap_step` call around it, or without one stages a
         # jax.debug.callback that fires per EXECUTED step at the host
-        # call boundary instead (jitted loops get goodput_ratio too).
+        # call boundary (jitted loops get goodput_ratio too).
         # The ledger check comes first: with the plane off (or before
         # init) at trace time the update path must not pay even the
         # tree flatten — and stages no callback (an explicit
@@ -184,7 +194,7 @@ def DistributedOptimizer(
         if led is not None and led.enabled:
             leaves = jax.tree.leaves(grads)
             if leaves and _is_tracer(leaves[0]):
-                _stage_traced_step_marker()
+                _mark_traced_step()
             else:
                 led.auto_step("optim")
         red = _allreduce_grads(

@@ -687,7 +687,7 @@ def zero_optimizer(
                          jnp.zeros((1, total), _acc_dtype(leaves)))
 
     def update_fn(grads, state, params=None, **extra):
-        from .distributed import _stage_traced_step_marker
+        from .distributed import _mark_traced_step
         from ..common import goodput
 
         if params is None:
@@ -703,7 +703,7 @@ def zero_optimizer(
                      for l in leaves + jax.tree.leaves(params))
         if led is not None and led.enabled:
             if traced:
-                _stage_traced_step_marker()
+                _mark_traced_step()
             else:
                 led.auto_step("optim")
         if traced:

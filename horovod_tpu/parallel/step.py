@@ -16,8 +16,38 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..common import basics, tracing
+from ..common import basics, goodput, tracing
 from ..utils.compat import shard_map
+
+# The `_Step` whose call is in flight on this thread, tracing included
+# (the first call of a built step traces the user's function here), and
+# None outside one. jax keys a trace by this value, so a function traced
+# under a step's call is traced again when called outside one.
+_step_in_flight = jax.make_user_context(None)
+
+
+class _Step:
+    """One built step of a `wrap_step` function. `holds_update` is set
+    by the optimizer update traced under its call: each call is then one
+    training step, marked on the goodput ledger from the host."""
+
+    __slots__ = ("call", "holds_update")
+
+    def __init__(self, call):
+        self.call = call
+        self.holds_update = False
+
+
+def claim_step_boundary() -> bool:
+    """For a traced optimizer update: whether a `wrap_step` call is in
+    flight around it. That call then marks the step on the goodput
+    ledger (docs/goodput.md) and the update stages no marker of its own
+    into the compiled program."""
+    step = _step_in_flight.value
+    if step is None:
+        return False
+    step.holds_update = True
+    return True
 
 
 def wrap_step(
@@ -105,7 +135,8 @@ def wrap_step(
 
     # Each call is one step span of the XLA profile, numbered from 0,
     # with the wrapper's own parts as its children (docs/tracing.md
-    # "Under jit").
+    # "Under jit"), and one step of the goodput ledger where the step
+    # holds an optimizer update.
     calls = itertools.count()
 
     @functools.wraps(fn)
@@ -124,11 +155,22 @@ def wrap_step(
                            str(getattr(l, "dtype", type(l))))
                           for l in leaves),
                 )
-                sm = cache.get(key)
-            if sm is None:
+                step = cache.get(key)
+                # Traced by an outer jax.jit this call runs once, not
+                # once per executed step: it is no boundary of a step,
+                # and the update's staged marker stands.
+                on_host = not any(
+                    isinstance(l, jax.core.Tracer) for l in leaves)
+            if step is None:
                 with tracing.annotate(tracing.SPAN_WRAP_BUILD):
-                    sm = cache[key] = build(m, an, args)
+                    step = cache[key] = _Step(build(m, an, args))
             with tracing.annotate(tracing.SPAN_WRAP_CALL):
-                return sm(*args)
+                if not on_host:
+                    return step.call(*args)
+                with _step_in_flight(step):
+                    out = step.call(*args)
+            if step.holds_update:
+                goodput.auto_step("wrap_step")
+            return out
 
     return wrapped
