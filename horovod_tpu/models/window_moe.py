@@ -55,7 +55,7 @@ import numpy as np
 
 from ..common import telemetry, tracing
 from ..ops.attention import attention
-from ..ops.flash_attention import key_tiles
+from ..ops.flash_attention import attention_scores, key_tiles
 from .latent_moe import (
     _KEEP_CHOICES, GatedMLP, RMSNorm, RoutedExperts, TokenEmbedding)
 from .transformer import _dense, default_kernel_init
@@ -69,6 +69,10 @@ _TILES_HELP = ("Key tiles one (batch, head) forward sweep of the flash "
                "kernel touches at the sequence length traced (what: "
                "visited, under_diagonal), by layer kind (kind: window, "
                "full)")
+_SCORES_HELP = ("Score entries one (batch, head) forward sweep of the flash "
+                "kernel computes at the sequence length traced, and the "
+                "visible pairs among them (what: computed, visible), by "
+                "layer kind (kind: window, full)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -295,6 +299,10 @@ class GatedAttention(nn.Module):
             for what, n in zip(("visited", "under_diagonal"), key_tiles(
                     S, cfg.head_dim, cfg.head_dim, window)):
                 telemetry.gauge("horovod_attention_key_tiles", _TILES_HELP,
+                                {"kind": kind, "what": what}).set(n)
+            for what, n in zip(("computed", "visible"), attention_scores(
+                    S, cfg.head_dim, cfg.head_dim, window)):
+                telemetry.gauge("horovod_attention_scores", _SCORES_HELP,
                                 {"kind": kind, "what": what}).set(n)
 
         def heads(n, name):

@@ -117,9 +117,145 @@ def _valid_t(mask_col, kb, qi, block_k: int, block_q: int, causal: bool,
     return valid
 
 
+def _band(window: int, sub: int) -> int:
+    """Sub-blocks in the band of a group of `sub` queries that starts at
+    a multiple of `sub`: from the one that holds the oldest key its
+    first query sees to its own, ceil((window - 1) / sub) + 1."""
+    return -(-(window - 1) // sub) + 1
+
+
+def _band_edges(window: int, sub: int) -> "list[bool]":
+    """Which sub-blocks of a band, oldest first, hold a pair that the
+    group of `sub` queries it ends with does not see: the diagonal one
+    and the oldest one or two.
+    Over sub-block j, q - k runs from (n - 2 - j) * sub + 1 to
+    (n - j) * sub - 1, and a pair is visible where 0 <= q - k < window."""
+    n = _band(window, sub)
+    return [j == n - 1 or (n - j) * sub - 1 >= window for j in range(n)]
+
+
+def _band_valid(shape, behind: int, window: int):
+    """Visibility in an edge sub-block, from static offsets: its keys
+    along sublanes, its queries along lanes, its first key `behind`
+    positions before its first query."""
+    dist = (behind + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    return jnp.logical_and(dist >= 0, dist < window)
+
+
+def _spans(t: int, n: int, groups: int) -> range:
+    """The groups of `sub` positions whose bands of `n` sub-blocks hold
+    sub-block `t`, all counted from the band of the tile's first group."""
+    return range(max(t - n + 1, 0), min(t, groups - 1) + 1)
+
+
+def _band_fwd(q, k_ref, v_ref, o_ref, lse_ref, qi, *, scale: float,
+              fold: bool, block_q: int, sub: int, window: int):
+    """The forward of one query tile at sub-block granularity. Group c
+    of `sub` queries sees keys of `_band` sub-blocks, c to c + n - 1
+    counted from the oldest key the tile's first query sees: no other
+    score is computed. Each key sub-block is multiplied with the lanes
+    of the groups whose band holds it in one product (as wide as the
+    tile where the band is), only the edge sub-blocks (`_band_edges`)
+    are masked, and each group's softmax runs over its band in one pass:
+    its diagonal key is there, so the maximum is finite and one select
+    suffices."""
+    n, groups = _band(window, sub), block_q // sub
+    edges = _band_edges(window, sub)
+    first = qi * block_q - (n - 1) * sub
+    scores = {}                                # (key sub-block, group)
+    for t in range(n - 1 + groups):
+        c0, cs = t - n + 1, _spans(t, n, groups)
+        keys = pl.ds(pl.multiple_of(first + t * sub, sub), sub)
+        s_t = _dot(k_ref[0, keys, :], q[cs[0] * sub:(cs[-1] + 1) * sub],
+                   (1, 1))                     # (sub, sub * len(cs))
+        if not fold:
+            s_t = s_t * scale
+        for c in cs:
+            s_c = s_t[:, (c - cs[0]) * sub:(c - cs[0] + 1) * sub]
+            if edges[t - c]:
+                s_c = jnp.where(
+                    _band_valid(s_c.shape, (c - c0) * sub, window), s_c,
+                    NEG_INF)
+            scores[t, c] = s_c
+    probs, m, l = {}, [], []
+    for c in range(groups):
+        band = range(c, c + n)
+        m.append(functools.reduce(jnp.maximum, [
+            jnp.max(scores[t, c], axis=0, keepdims=True) for t in band]))
+        for t in band:
+            probs[t, c] = jnp.exp(scores[t, c] - m[c])
+        l.append(sum(jnp.sum(probs[t, c], axis=0, keepdims=True)
+                     for t in band))
+    acc = [0.0] * groups                       # (Dv, sub) each
+    for t in range(n - 1 + groups):
+        cs = _spans(t, n, groups)
+        v_t = v_ref[0, pl.ds(pl.multiple_of(first + t * sub, sub), sub), :]
+        p_t = jnp.concatenate([probs[t, c] for c in cs], axis=1)
+        pv = _dot(v_t, p_t.astype(v_t.dtype), (0, 0))
+        for c in cs:
+            acc[c] = acc[c] + pv[:, (c - cs[0]) * sub:(c - cs[0] + 1) * sub]
+    for c in range(groups):
+        o_ref[0, c * sub:(c + 1) * sub, :] = (acc[c] / l[c]).T.astype(
+            o_ref.dtype)
+        lse_ref[0, :, c * sub:(c + 1) * sub] = m[c] + jnp.log(l[c])
+
+
+def _band_bwd(k, v, q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+              dq_acc, ki, *, scale: float, fold: bool, block_k: int,
+              sub: int, window: int):
+    """The backward of one key tile at sub-block granularity, the
+    forward's mirror: group g of `sub` keys is seen by the queries of
+    `_band` sub-blocks, its own and those after it. Scores, probabilities
+    and dQ are computed group by group over the band's lanes, with only
+    the edge sub-blocks masked; dK and dV query sub-block by query
+    sub-block, over the keys of the groups it sees, so that each of those
+    products is as wide as that."""
+    n, groups = _band(window, sub), block_k // sub
+    edges = _band_edges(window, sub)[::-1]
+    base = ki * block_k
+    probs, dss = {}, {}                        # (group, query sub-block)
+    for g in range(groups):
+        own = slice(g * sub, (g + 1) * sub)
+        cols = pl.ds(pl.multiple_of(base + g * sub, sub), n * sub)
+        k_g, v_g = k[own], v[own]
+        q_b = q_ref[0, cols, :]                        # (n * sub, Dqk)
+        s_t = _dot(k_g, q_b, (1, 1))                   # (sub, n * sub)
+        if not fold:
+            s_t = s_t * scale
+        p_t = jnp.exp(s_t - lse_ref[0, :, cols])
+        p_t = jnp.concatenate([
+            jnp.where(_band_valid(p_j.shape, j * sub, window), p_j, 0.0)
+            if edges[j] else p_j
+            for j, p_j in enumerate(jnp.split(p_t, n, axis=1))], axis=1)
+        dp_t = _dot(v_g, do_ref[0, cols, :], (1, 1))   # (sub, n * sub)
+        ds_t = p_t * (dp_t - delta_ref[0, :, cols])
+        dq_acc[:, cols] += _dot(k_g, ds_t.astype(q_b.dtype), (0, 0))
+        for j in range(n):
+            probs[g, g + j] = p_t[:, j * sub:(j + 1) * sub]
+            dss[g, g + j] = ds_t[:, j * sub:(j + 1) * sub]
+    dk, dv = [0.0] * groups, [0.0] * groups    # (D, sub) each
+    for t in range(n - 1 + groups):
+        gs = _spans(t, n, groups)
+        rows = pl.ds(pl.multiple_of(base + t * sub, sub), sub)
+        q_t, do_t = q_ref[0, rows, :], do_ref[0, rows, :]
+        p_t = jnp.concatenate([probs[g, t] for g in gs], axis=0)
+        ds_t = jnp.concatenate([dss[g, t] for g in gs], axis=0)
+        dv_t = _dot(do_t, p_t.astype(do_t.dtype), (0, 1))  # (Dv, keys)
+        dk_t = _dot(q_t, ds_t.astype(q_t.dtype), (0, 1))   # (Dqk, keys)
+        for g in gs:
+            lanes = slice((g - gs[0]) * sub, (g - gs[0] + 1) * sub)
+            dv[g] = dv[g] + dv_t[:, lanes]
+            dk[g] = dk[g] + dk_t[:, lanes]
+    for g in range(groups):
+        own = slice(g * sub, (g + 1) * sub)
+        dk_ref[0, own, :] = (dk[g] * scale).T.astype(dk_ref.dtype)
+        dv_ref[0, own, :] = dv[g].T.astype(dv_ref.dtype)
+
+
 def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
             causal: bool, block_q: int, block_k: int, plain: bool,
-            window: Optional[int] = None):
+            window: Optional[int] = None, sub: Optional[int] = None):
     """One (batch*head, q-block) grid step, streaming k-blocks.
 
     q_ref: (1, block_q, Dqk); k_ref: (1, S_pad, Dqk) and v_ref:
@@ -154,6 +290,10 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
     row's maximum stays NEG_INF, its probabilities read exp(0) = 1, and
     the first tile with a visible key (its own position's, at the
     latest) multiplies what they summed to by exp(NEG_INF - max) = 0.
+
+    `sub` (a window and no padding mask; `_band_block`): a query tile
+    whose bands lie inside the keys computes them at sub-block
+    granularity instead (`_band_fwd`); the others take the sweep.
     """
     qi = pl.program_id(1)
     fold = _folds_exactly(scale)
@@ -194,55 +334,73 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
         pv_t = _dot(v_blk, p_t.astype(v_blk.dtype), (0, 0))  # (Dv, block_q)
         return acc_t * corr + pv_t, m_new, l
 
-    num_kb = s_pad // block_k
-    if causal:
-        # k-blocks whose first key position exceeds this q-block's last
-        # query position are entirely masked: skip them.
-        last_q = (qi + 1) * block_q - 1
-        num_kb = jnp.minimum(num_kb, last_q // block_k + 1)
+    def sweep():
+        num_kb = s_pad // block_k
+        if causal:
+            # k-blocks whose first key position exceeds this q-block's last
+            # query position are entirely masked: skip them.
+            last_q = (qi + 1) * block_q - 1
+            num_kb = jnp.minimum(num_kb, last_q // block_k + 1)
 
-    carry = (
-        jnp.zeros((Dv, block_q), jnp.float32),
-        jnp.full((1, block_q), NEG_INF, jnp.float32),
-        jnp.zeros((1, block_q), jnp.float32),
-    )
-    n_full = 0
-    if window is not None:
-        first_q = qi * block_q
-        # The tile of the oldest key the first query sees.
-        n_full = jnp.minimum(
-            jnp.maximum(first_q - window + 1, 0) // block_k, num_kb)
-        if plain:
-            # Tiles from the first whose every key the LAST query still
-            # sees, up to the last wholly under the first query.
-            inside = jnp.clip(
-                (jnp.maximum(last_q + 1 - window, 0) + block_k - 1)
-                // block_k, n_full, num_kb)
-            under = jnp.clip(first_q // block_k, inside, num_kb)
+        carry = (
+            jnp.zeros((Dv, block_q), jnp.float32),
+            jnp.full((1, block_q), NEG_INF, jnp.float32),
+            jnp.zeros((1, block_q), jnp.float32),
+        )
+        n_full = 0
+        if window is not None:
+            first_q = qi * block_q
+            # The tile of the oldest key the first query sees.
+            n_full = jnp.minimum(
+                jnp.maximum(first_q - window + 1, 0) // block_k, num_kb)
+            if plain:
+                # Tiles from the first whose every key the LAST query still
+                # sees, up to the last wholly under the first query.
+                inside = jnp.clip(
+                    (jnp.maximum(last_q + 1 - window, 0) + block_k - 1)
+                    // block_k, n_full, num_kb)
+                under = jnp.clip(first_q // block_k, inside, num_kb)
+                carry = jax.lax.fori_loop(
+                    n_full, inside, lambda kb, c: tile(kb, c, masked=True),
+                    carry)
+                carry = jax.lax.fori_loop(
+                    inside, under, lambda kb, c: tile(kb, c, masked=False),
+                    carry)
+                n_full = under
+        elif plain and causal:
+            # Tiles whose last key row sits at/below this q-block's first
+            # query row need no causal masking at all.
+            n_full = (qi * block_q) // block_k
             carry = jax.lax.fori_loop(
-                n_full, inside, lambda kb, c: tile(kb, c, masked=True),
-                carry)
-            carry = jax.lax.fori_loop(
-                inside, under, lambda kb, c: tile(kb, c, masked=False),
-                carry)
-            n_full = under
-    elif plain and causal:
-        # Tiles whose last key row sits at/below this q-block's first
-        # query row need no causal masking at all.
-        n_full = (qi * block_q) // block_k
+                0, n_full, lambda kb, c: tile(kb, c, masked=False), carry)
         carry = jax.lax.fori_loop(
-            0, n_full, lambda kb, c: tile(kb, c, masked=False), carry)
-    carry = jax.lax.fori_loop(
-        n_full, num_kb,
-        lambda kb, c: tile(kb, c, masked=causal or not plain), carry)
-    acc_t, m, l = carry
+            n_full, num_kb,
+            lambda kb, c: tile(kb, c, masked=causal or not plain), carry)
+        acc_t, m, l = carry
 
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc_t / l_safe).T.astype(o_ref.dtype)
-    # Per-row logsumexp, the only residual the backward needs beyond the
-    # inputs (Dao et al. flash backward): p = exp(s - L) is already
-    # normalized.
-    lse_ref[0] = m + jnp.log(l_safe)
+        l_safe = jnp.maximum(l, 1e-30)
+        o_ref[0] = (acc_t / l_safe).T.astype(o_ref.dtype)
+        # Per-row logsumexp, the only residual the backward needs beyond the
+        # inputs (Dao et al. flash backward): p = exp(s - L) is already
+        # normalized.
+        lse_ref[0] = m + jnp.log(l_safe)
+
+    if sub is None:
+        sweep()
+        return
+    # The band of a query group starts n - 1 sub-blocks behind it and
+    # the tile's last group ends at its last query: tiles whose bands
+    # leave the keys (the first ones, or past padded keys) take the sweep.
+    behind = (_band(window, sub) - 1) * sub
+    if -(-behind // block_q) * block_q + block_q > s_pad:
+        sweep()             # no tile's bands lie inside the keys
+        return
+    fits = jnp.logical_and(qi * block_q >= behind,
+                           (qi + 1) * block_q <= s_pad)
+    pl.when(fits)(functools.partial(
+        _band_fwd, q, k_ref, v_ref, o_ref, lse_ref, qi, scale=scale,
+        fold=fold, block_q=block_q, sub=sub, window=window))
+    pl.when(jnp.logical_not(fits))(sweep)
 
 
 # Mosaic gives a kernel 16 MiB of VMEM unless told otherwise. The
@@ -300,6 +458,48 @@ def _blocks(S: int, Dqk: int, Dv: int) -> "tuple[int, int]":
     return block, block
 
 
+BAND_SUB_BLOCK = 128
+
+
+def _band_block(window: Optional[int], block_q: int, block_k: int
+                ) -> Optional[int]:
+    """The sub-block at which a windowed call computes its band
+    (`_band_fwd`, `_band_bwd`), or None where the tiles' sweep runs: no
+    window, tiles that the sub-block does not divide, or a band wider
+    than the two tiles a query tile's sweep visits at a window of one
+    tile (a wide window's tiles are mostly inside it).
+
+    Kernels alone on one v5e (PR 39; B 2, S 8192, 64 query heads over 8,
+    head 128, window 512, bf16; kernel ms from the profiler, call ms
+    from the host clock with `_prep`'s copies and the group sum):
+
+                                     kernel fwd   bwd   call fwd  fwd+bwd
+        512 x 512 tiles, two a tile        5.90  11.65      7.71    22.99
+        256 x 256 tiles                    8.87  13.42     10.69    27.74
+        band by query group, sub 128       6.20   8.65      8.01    20.32
+        band by query group, sub 256       5.54   8.13      7.35    19.13
+        band by key sub-block, sub 128     3.18   7.71      4.99    16.37
+        band by key sub-block, sub 256     3.55   7.90      5.35    16.91
+        (by query group, 1024 tiles        4.93   8.16      6.75    18.54)
+
+    By query group, each group of 128 queries takes its 640 keys in one
+    product 128 lanes wide; by key sub-block (`_band_fwd` as it stands),
+    each 128 keys take the lanes of every group whose band holds them,
+    up to the whole tile, and the backward's dK / dV go by query
+    sub-block the same way. Entries computed are the same, 1.25 a pair
+    at 128 and 1.5 at 256; the wide products are what won. Errors
+    against a blockwise float32 reference: 0.0047 of the largest entry
+    at most, in every variant. The 48-head full call read 15.67 / 27.79,
+    as in PR 35."""
+    if window is None:
+        return None
+    sub = BAND_SUB_BLOCK
+    if block_q % sub or block_k % sub or (
+            _band(window, sub) * sub > block_q + block_k):
+        return None
+    return sub
+
+
 def key_tiles(S: int, Dqk: int, Dv: int, window: Optional[int] = None
               ) -> "tuple[int, int]":
     """(visited, under_diagonal): key tiles the forward kernel's sweeps
@@ -316,6 +516,32 @@ def key_tiles(S: int, Dqk: int, Dv: int, window: Optional[int] = None
         visited += diagonal - first + 1
         under += diagonal + 1
     return visited, under
+
+
+def attention_scores(S: int, Dqk: int, Dv: int,
+                     window: Optional[int] = None) -> "tuple[int, int]":
+    """(computed, visible): score entries the forward kernel computes
+    for one (batch, head) at sequence length S, causal, no padding mask,
+    as its query tiles run (the band where `_band_block` gives a
+    sub-block and the tile's bands lie inside the keys, else the tiles
+    `key_tiles` counts), and the pairs among them that a query sees.
+    The backward kernel computes as many from the key side where its
+    first and last tiles do (at a window of one tile, one each)."""
+    bq, bk = _blocks(S, Dqk, Dv)
+    sub = _band_block(window, bq, bk)
+    s_k = -(-S // bk) * bk
+    computed = 0
+    for qi in range(-(-S // bq)):
+        if sub and qi * bq >= (_band(window, sub) - 1) * sub and (
+                (qi + 1) * bq <= s_k):
+            computed += bq * _band(window, sub) * sub
+            continue
+        diagonal = min(((qi + 1) * bq - 1) // bk, s_k // bk - 1)
+        first = 0 if window is None else max(qi * bq - window + 1, 0) // bk
+        computed += (diagonal - first + 1) * bq * bk
+    w = min(window or S, S)
+    visible = w * (w + 1) // 2 + (S - w) * w
+    return computed, visible
 
 
 def _prep(q, k, v, mask, block_q: Optional[int]):
@@ -407,12 +633,13 @@ def _fwd_call(q, k, v, mask, causal: bool, block_q: int,
                                                             mask, block_q)
     grid = (B * H, Sq // bq)
     kv = _kv_block(H, k.shape[2])
+    sub = _band_block(window, bq, bk) if mask is None else None
 
     def call(interp: bool):
         return pl.pallas_call(
             functools.partial(_kernel, scale=scale, causal=causal,
                               block_q=bq, block_k=bk, plain=plain,
-                              window=window),
+                              window=window, sub=sub),
             out_shape=[
                 jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
@@ -450,7 +677,7 @@ _fwd_call_once = jax.jit(_fwd_call, static_argnums=(4, 5, 6, 7))
 def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dk_ref, dv_ref, dq_acc, *, scale: float,
                  causal: bool, block_q: int, block_k: int, plain: bool,
-                 window: Optional[int] = None):
+                 window: Optional[int] = None, sub: Optional[int] = None):
     """FUSED backward: grid (B*H, k-block), ki innermost. One sweep
     computes dK/dV for this k-block AND accumulates every q-block's dQ
     contribution into a persistent f32 VMEM scratch (written out once,
@@ -476,7 +703,8 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     `window`: the sweep ends at the q-block of the last query that
     still sees this k-block's last key; the q-blocks the band's far
     edge crosses are masked like the diagonal's, those between run the
-    mask-free body.
+    mask-free body. `sub`: a key tile whose bands lie inside the queries
+    computes them at sub-block granularity (`_band_bwd`).
 
     Grouped heads: a program is a QUERY head's; it reads its group's K
     and V block (`_kv_block`) and writes dK / dV of its own, which
@@ -519,44 +747,58 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         dq_acc[:, rows] += _dot(k, ds_t, (0, 0))  # (Dqk, bq)
         return dk_t, dv_t
 
-    num_qb = sq_pad // block_q
-    start_qb = (ki * block_k) // block_q if causal else 0
-    carry = (jnp.zeros((D, block_k), jnp.float32),
-             jnp.zeros((Dv, block_k), jnp.float32))
-    if window is not None:
-        first_k = ki * block_k
-        # One past the q-block of the last query that sees the last key.
-        num_qb = jnp.minimum(
-            (first_k + block_k + window - 2) // block_q + 1, num_qb)
-    if window is not None and plain:
-        diag_end = jnp.minimum(
-            (first_k + block_k + block_q - 1) // block_q, num_qb)
-        # q-blocks whose LAST query still sees this k-block's first key.
-        inside = jnp.clip((first_k + window) // block_q, diag_end, num_qb)
+    def sweep():
+        num_qb = sq_pad // block_q
+        start_qb = (ki * block_k) // block_q if causal else 0
+        carry = (jnp.zeros((D, block_k), jnp.float32),
+                 jnp.zeros((Dv, block_k), jnp.float32))
+        if window is not None:
+            first_k = ki * block_k
+            # One past the q-block of the last query that sees the last key.
+            num_qb = jnp.minimum(
+                (first_k + block_k + window - 2) // block_q + 1, num_qb)
+        if window is not None and plain:
+            diag_end = jnp.minimum(
+                (first_k + block_k + block_q - 1) // block_q, num_qb)
+            # q-blocks whose LAST query still sees this k-block's first key.
+            inside = jnp.clip((first_k + window) // block_q, diag_end, num_qb)
+            carry = jax.lax.fori_loop(
+                start_qb, diag_end, lambda qi, c: tile(qi, c, masked=True),
+                carry)
+            carry = jax.lax.fori_loop(
+                diag_end, inside, lambda qi, c: tile(qi, c, masked=False),
+                carry)
+            carry = jax.lax.fori_loop(
+                inside, num_qb, lambda qi, c: tile(qi, c, masked=True), carry)
+            start_qb = num_qb
+        elif plain and causal:
+            # q-blocks straddling the diagonal first, then the mask-free rest.
+            diag_end = jnp.minimum(
+                ((ki + 1) * block_k + block_q - 1) // block_q, num_qb)
+            carry = jax.lax.fori_loop(
+                start_qb, diag_end, lambda qi, c: tile(qi, c, masked=True),
+                carry)
+            start_qb = diag_end
         carry = jax.lax.fori_loop(
-            start_qb, diag_end, lambda qi, c: tile(qi, c, masked=True),
+            start_qb, num_qb,
+            lambda qi, c: tile(qi, c, masked=not plain or window is not None),
             carry)
-        carry = jax.lax.fori_loop(
-            diag_end, inside, lambda qi, c: tile(qi, c, masked=False),
-            carry)
-        carry = jax.lax.fori_loop(
-            inside, num_qb, lambda qi, c: tile(qi, c, masked=True), carry)
-        start_qb = num_qb
-    elif plain and causal:
-        # q-blocks straddling the diagonal first, then the mask-free rest.
-        diag_end = jnp.minimum(
-            ((ki + 1) * block_k + block_q - 1) // block_q, num_qb)
-        carry = jax.lax.fori_loop(
-            start_qb, diag_end, lambda qi, c: tile(qi, c, masked=True),
-            carry)
-        start_qb = diag_end
-    carry = jax.lax.fori_loop(
-        start_qb, num_qb,
-        lambda qi, c: tile(qi, c, masked=not plain or window is not None),
-        carry)
-    dk_t, dv_t = carry
-    dk_ref[0] = (dk_t * scale).T.astype(dk_ref.dtype)
-    dv_ref[0] = dv_t.T.astype(dv_ref.dtype)
+        dk_t, dv_t = carry
+        dk_ref[0] = (dk_t * scale).T.astype(dk_ref.dtype)
+        dv_ref[0] = dv_t.T.astype(dv_ref.dtype)
+
+    # The band of a key group holds its own sub-block's queries and the
+    # next ones up to the last that sees its newest key.
+    ahead = None if sub is None else (_band(window, sub) - 1) * sub
+    if ahead is None or block_k + ahead > sq_pad:
+        sweep()             # no window, or no tile's bands lie inside
+    else:
+        fits = (ki + 1) * block_k + ahead <= sq_pad
+        pl.when(fits)(functools.partial(
+            _band_bwd, k, v, q_ref, do_ref, lse_ref, delta_ref, dk_ref,
+            dv_ref, dq_acc, ki, scale=scale, fold=fold, block_k=block_k,
+            sub=sub, window=window))
+        pl.when(jnp.logical_not(fits))(sweep)
 
     @pl.when(ki == num_kb - 1)
     def _flush_dq():
@@ -617,12 +859,13 @@ def _bwd_call(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
     kv = _kv_block(H, H_kv)
     in_k = pl.BlockSpec((1, bk, D), lambda bh, ki: (kv(bh), ki, 0))
     in_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: (kv(bh), ki, 0))
+    sub = _band_block(window, bq, bk) if mask is None else None
 
     def call(interp: bool):
         return pl.pallas_call(
             functools.partial(_dqkv_kernel, scale=scale, causal=causal,
                               block_q=bq, block_k=bk, plain=plain,
-                              window=window),
+                              window=window, sub=sub),
             out_shape=[
                 jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
                 jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
@@ -677,8 +920,10 @@ def flash_attention(q, k, v, mask=None, causal: bool = True,
     Dqk == Dv in GPT-2 / BERT / ViT; a latent attention has 192 beside
     128. H_kv divides H: query head h attends key/value head
     h // (H / H_kv). `window` (causal only): key j is visible to query
-    i iff 0 <= i - j < window; both kernels then visit the key tiles a
-    query tile's band intersects and no other.
+    i iff 0 <= i - j < window; both kernels then compute the scores of
+    the sub-blocks a band holds (`_band_block`), or visit the key tiles
+    a query tile's band intersects where that band leaves the sequence
+    or is wider than two tiles.
 
     Tile sizes come from `_blocks(S, Dqk, Dv)`; an explicit `block_q`
     replaces the first. Both vjp passes resolve the pair identically in

@@ -346,6 +346,73 @@ def test_key_tiles_outside_the_window_are_skipped_not_masked(small_tiles):
     assert np.isfinite(np.asarray(dv[:, keys])).all()
 
 
+@pytest.fixture
+def band_tiles(monkeypatch):
+    """32 x 32 tiles cut into sub-blocks of 8, so that a windowed call
+    computes its bands at the interpreter's cost: a sequence of 160
+    positions is five tiles each way."""
+    fa._fwd_call_once.clear_cache()
+    fa._bwd_call_once.clear_cache()
+    monkeypatch.setattr(fa, "_blocks", lambda S, Dqk, Dv: (32, 32))
+    monkeypatch.setattr(fa, "BAND_SUB_BLOCK", 8)
+    yield
+    fa._fwd_call_once.clear_cache()
+    fa._bwd_call_once.clear_cache()
+
+
+@pytest.mark.parametrize("S", [160, 150], ids=["whole-tiles", "S-padded"])
+@pytest.mark.parametrize("H,H_kv", [(4, 2), (6, 1)], ids=["4/2", "6/1"])
+@pytest.mark.parametrize("window", [5, 8, 20, 32, 48],
+                         ids=["under-a-sub-block", "a-sub-block",
+                              "not-a-multiple", "a-tile",
+                              "a-tile-and-a-half"])
+def test_band_matches_dense(band_tiles, window, H, H_kv, S):
+    """The tiles whose bands lie inside the sequence compute them at
+    sub-block granularity, the first (forward) and last (backward) take
+    the tiles' sweep: forward and all three gradients against the dense
+    mask."""
+    assert fa._band_block(window, 32, 32) == 8
+    q, k, v = _grouped_qkv(S, H, H_kv, seed=window)
+    _assert_flash_matches_windowed_dense(q, k, v, window)
+
+
+def test_band_sub_blocks_outside_every_band_are_not_computed(band_tiles):
+    """At a window of one sub-block, query tile 2 (queries 64-95) visits
+    key tiles 1 and 2 in the tiles' sweep, but its bands hold keys 56-95
+    only. Values poisoned with NaN in keys 32-55 (sub-blocks of a
+    visited tile that no query of the program sees) stay out of its rows
+    of the output and of dQ; a kernel that computed and masked them
+    would multiply 0 by NaN. From the key side, key group 64-71 is seen
+    by queries 64-78 only: a cotangent poisoned on queries 80-95 (the
+    rest of the query tile the sweep visits) stays out of its dK / dV."""
+    S, W = 160, 8
+    q, k, v = _grouped_qkv(S, 4, 2, seed=4)
+    rows = slice(64, 96)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, None, True, None, True, W)
+
+    out, vjp = jax.vjp(flash, q, k, v.at[:, 32:56].set(jnp.nan))
+    dq, _, _ = vjp(jnp.zeros_like(out).at[:, rows].set(1.0))
+    assert np.isfinite(np.asarray(out[:, rows])).all()
+    assert np.isfinite(np.asarray(dq[:, rows])).all()
+    assert np.isnan(np.asarray(out[:, 32:56])).any()
+    out, vjp = jax.vjp(flash, q, k, v)
+    _, dk, dv = vjp(jnp.ones_like(out).at[:, 80:96].set(jnp.nan))
+    assert np.isfinite(np.asarray(dk[:, 64:72])).all()
+    assert np.isfinite(np.asarray(dv[:, 64:72])).all()
+    assert np.isnan(np.asarray(dk[:, 72:80])).any()
+
+
+@pytest.mark.parametrize("S,window,want", [
+    (8192, None, (35_651_584, 33_558_528)),   # 136 tiles; S (S + 1) / 2
+    (8192, 512, (5_177_344, 4_063_488)),      # 1 tile, then 640 a query
+    (2048, 512, (1_245_184, 917_760)),
+])
+def test_attention_scores_counts_the_forward(S, window, want):
+    assert fa.attention_scores(S, 128, 128, window) == want
+
+
 @pytest.mark.parametrize("S,window,want", [
     (8192, None, (136, 136)),    # 16 x 17 / 2 tiles under the diagonal
     (8192, 512, (31, 136)),      # two a query tile but the first
@@ -373,11 +440,12 @@ _OLDER_CALLS = {
 }
 
 
-def _older_calls_jaxpr_sha(B, S, H, Dqk, Dv):
+def _older_calls_jaxpr_sha(B, S, H, Dqk, Dv, H_kv=None):
     import hashlib
 
     q = jax.ShapeDtypeStruct((B, S, H, Dqk), jnp.bfloat16)
-    v = jax.ShapeDtypeStruct((B, S, H, Dv), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((B, S, H_kv or H, Dqk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((B, S, H_kv or H, Dv), jnp.bfloat16)
 
     def both(q, k, v):
         out, vjp = jax.vjp(lambda q, k, v: flash_attention(
@@ -385,13 +453,21 @@ def _older_calls_jaxpr_sha(B, S, H, Dqk, Dv):
         return out, vjp(out)
 
     return hashlib.sha256(
-        str(jax.make_jaxpr(both)(q, q, v)).encode()).hexdigest()
+        str(jax.make_jaxpr(both)(q, k, v)).encode()).hexdigest()
 
 
 @pytest.mark.parametrize("shape", list(_OLDER_CALLS),
                          ids=["gpt2-s4096", "gpt2-s2048", "joyai"])
 def test_ungrouped_unwindowed_call_is_the_kernel_it_was(shape):
     assert _older_calls_jaxpr_sha(*shape) == _OLDER_CALLS[shape]
+
+
+def test_grouped_unwindowed_call_is_the_kernel_it_was():
+    """Laguna's full-layer kind (48 query heads over 8, head 128) at a
+    short sequence, made on PR 36's tree: a call without a window keeps
+    the kernel that computes whole tiles."""
+    assert _older_calls_jaxpr_sha(2, 2048, 48, 128, 128, H_kv=8) == (
+        "1f0a6dc0b1bfc06d1a1afb4cb7bdda21e664ed2c2d866c470c70ea3d04a0fc20")
 
 
 def test_transformer_flash_impl_matches_dense():

@@ -296,3 +296,24 @@ def test_attention_key_tiles_gauge_is_set_where_the_call_is_traced():
                                                      "under_diagonal")}
     assert found == {("window", "visited"): 5, ("window", "under_diagonal"): 6,
                      ("full", "visited"): 6, ("full", "under_diagonal"): 6}
+
+
+def test_attention_scores_gauge_is_set_where_the_call_is_traced():
+    """At 1100 positions and a window of 8 the second and third query
+    tiles compute 256 keys a query (two sub-blocks of 128), the first
+    its diagonal tile; the full layers compute whole tiles under the
+    diagonal. Visible: 8 keys a query but the first 7, and a triangle."""
+    from horovod_tpu.common import telemetry
+
+    model = _model(attn_impl="flash")
+    ids = jnp.zeros((1, 1100), jnp.int32)
+    jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))
+    found = {(kind, what): telemetry.gauge(
+        "horovod_attention_scores",
+        labels={"kind": kind, "what": what}).value
+        for kind in ("window", "full") for what in ("computed", "visible")}
+    assert found == {
+        ("window", "computed"): 512 * 512 + 2 * 512 * 256,
+        ("window", "visible"): 36 + 1092 * 8,
+        ("full", "computed"): 6 * 512 * 512,
+        ("full", "visible"): 1100 * 1101 // 2}
