@@ -98,16 +98,29 @@ SCOPE_OPTIMIZER = "hvd.optimizer"
 #: projections, the two inner norms, the rotary positions
 #: (`models/latent_moe.py::LatentAttention`); not the kernel.
 SCOPE_ATTN_LATENT = "hvd.attn.latent"
-#: A grouped-query attention's own work around the attention kernel: the
-#: q / k / v / gate / output projections, the rotary positions and the
-#: gate's product (`models/window_moe.py::GatedAttention`); not the
-#: kernel.
+#: An attention's own work around the attention call: the projections
+#: (`models/transformer.py::MultiHeadAttention`'s `qkv` / `out`;
+#: `models/window_moe.py::GatedAttention`'s q / k / v / gate / output,
+#: the rotary positions and the gate's product); not the call.
 SCOPE_ATTN_PROJ = "hvd.attn.proj"
 #: The attention call of a sliding-window layer, forward and backward
 #: (the kernels and the layout copies around them).
 SCOPE_ATTN_WINDOW = "hvd.attn.window"
-#: The attention call of a full (global) layer of the same model.
+#: The attention call of a full (global) layer: `GatedAttention`'s
+#: without a window, `MultiHeadAttention`'s (flash kernels and their
+#: layout copies, or XLA's dense attention).
 SCOPE_ATTN_FULL = "hvd.attn.full"
+#: A block's dense feed-forward, entered at its call site: its products,
+#: activation and dropout, forward and backward. Not a routed layer's
+#: shared expert, which is `SCOPE_MOE_EXPERTS`'.
+SCOPE_MLP = "hvd.mlp"
+#: A norm entered at its call site: a block's pre-attention and pre-FFN
+#: norms, the final norm, the prediction module's; and what XLA fuses
+#: under its root. Not a latent attention's inner norms.
+SCOPE_NORM = "hvd.norm"
+#: The token (and position) embedding: the gather forward, the scatter
+#: into the table backward.
+SCOPE_EMBED = "hvd.embed"
 #: A routed layer's routing: router, top-k, gates, the sorts, the
 #: kernels that move rows into the dispatch buffer and sum them back
 #: (`models/latent_moe.py::RoutedExperts`, `ops/routed_rows.py`).

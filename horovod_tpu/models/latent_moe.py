@@ -50,7 +50,8 @@ gradient). One expert group only (`n_group` = `topk_group` = 1).
 Discrete choices are sown into the collection `choices` as
 (batch, seq, k) int32 (benchmark/README.md "Discrete choices").
 Regions of the XLA profile: `hvd.attn.latent`, `hvd.moe.route`,
-`hvd.moe.experts`, `hvd.mtp` (docs/tracing.md "Under jit").
+`hvd.moe.experts`, `hvd.mtp`, `hvd.mlp`, `hvd.norm`, `hvd.embed`
+(docs/tracing.md "Under jit").
 """
 from __future__ import annotations
 
@@ -383,11 +384,17 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, rope):
         cfg = self.cfg
-        h = x + LatentAttention(cfg, name="attn")(
-            RMSNorm(cfg, name="attn_norm")(x), rope)
-        ffn = (RoutedExperts(cfg, name="moe") if self.routed
-               else GatedMLP(cfg, cfg.intermediate_size, name="mlp"))
-        out = h + ffn(RMSNorm(cfg, name="ffn_norm")(h))
+        with jax.named_scope(tracing.SCOPE_NORM):
+            y = RMSNorm(cfg, name="attn_norm")(x)
+        h = x + LatentAttention(cfg, name="attn")(y, rope)
+        with jax.named_scope(tracing.SCOPE_NORM):
+            y = RMSNorm(cfg, name="ffn_norm")(h)
+        if self.routed:
+            out = h + RoutedExperts(cfg, name="moe")(y)
+        else:
+            with jax.named_scope(tracing.SCOPE_MLP):
+                y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(y)
+            out = h + y
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -431,13 +438,15 @@ class MTPModule(nn.Module):
     @nn.compact
     def __call__(self, h, next_embedding, rope):
         cfg = self.cfg
-        joined = jnp.concatenate(
-            [RMSNorm(cfg, name="norm_h")(h),
-             RMSNorm(cfg, name="norm_e")(next_embedding)], axis=-1)
+        with jax.named_scope(tracing.SCOPE_NORM):
+            joined = jnp.concatenate(
+                [RMSNorm(cfg, name="norm_h")(h),
+                 RMSNorm(cfg, name="norm_e")(next_embedding)], axis=-1)
         x = _dense(cfg.hidden_size, cfg, "proj", (None, "embed"),
                    use_bias=False)(joined)
         x = _block(cfg)(cfg, True, name="block")(x, rope)
-        return RMSNorm(cfg, name="final_norm")(x)
+        with jax.named_scope(tracing.SCOPE_NORM):
+            return RMSNorm(cfg, name="final_norm")(x)
 
 
 class LatentMoELM(nn.Module):
@@ -457,7 +466,8 @@ class LatentMoELM(nn.Module):
                       use_bias=False)
         rope = rope_angles(jnp.arange(ids.shape[1]), cfg.qk_rope_head_dim,
                            cfg.rope_theta)
-        x = embed(ids)
+        with jax.named_scope(tracing.SCOPE_EMBED):
+            x = embed(ids)
         for i in range(cfg.num_hidden_layers):
             x = _block(cfg)(cfg, i >= cfg.first_k_dense_replace,
                             name=f"layer_{i}")(x, rope)
@@ -467,12 +477,15 @@ class LatentMoELM(nn.Module):
                 head(hidden).astype(cfg.logits_dtype),
                 ("batch", "seq", "vocab"))
 
-        logits = logits_of(RMSNorm(cfg, name="final_norm")(x))
+        with jax.named_scope(tracing.SCOPE_NORM):
+            normed = RMSNorm(cfg, name="final_norm")(x)
+        logits = logits_of(normed)
         if (cfg.num_nextn_predict_layers
                 and self.is_mutable_collection(AUX_COLLECTION)):
             with jax.named_scope(tracing.SCOPE_MTP):
-                hidden = MTPModule(cfg, name="mtp")(
-                    x, embed(jnp.roll(ids, -1, axis=1)), rope)
+                with jax.named_scope(tracing.SCOPE_EMBED):
+                    following = embed(jnp.roll(ids, -1, axis=1))
+                hidden = MTPModule(cfg, name="mtp")(x, following, rope)
                 self.sow(AUX_COLLECTION, "logits", logits_of(hidden))
         return logits
 

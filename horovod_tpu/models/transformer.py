@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common import tracing
 from ..ops.attention import attention
 
 Dtype = Any
@@ -135,43 +136,46 @@ class MultiHeadAttention(nn.Module):
         B, S, D = x.shape
         H, Hd = cfg.n_heads, cfg.head_dim
 
-        qkv = nn.DenseGeneral(
-            (3, H, Hd),
-            axis=-1,
-            use_bias=True,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            kernel_init=nn.with_logical_partitioning(
-                default_kernel_init, ("embed", None, "heads", "kv")
-            ),
-            bias_init=nn.with_logical_partitioning(
-                nn.initializers.zeros_init(), (None, "heads", "kv")
-            ),
-            name="qkv",
-        )(x)
-        q, k, v = (jnp.squeeze(a, axis=2)
-                   for a in jnp.split(qkv, 3, axis=2))  # (B,S,H,Hd)
-        q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
-        k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
-        v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
+        with jax.named_scope(tracing.SCOPE_ATTN_PROJ):
+            qkv = nn.DenseGeneral(
+                (3, H, Hd),
+                axis=-1,
+                use_bias=True,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    default_kernel_init, ("embed", None, "heads", "kv")
+                ),
+                bias_init=nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), (None, "heads", "kv")
+                ),
+                name="qkv",
+            )(x)
+            q, k, v = (jnp.squeeze(a, axis=2)
+                       for a in jnp.split(qkv, 3, axis=2))  # (B,S,H,Hd)
+            q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
+            k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
+            v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
 
-        ctx = _attention_dispatch(cfg, q, k, v, mask)
+        with jax.named_scope(tracing.SCOPE_ATTN_FULL):
+            ctx = _attention_dispatch(cfg, q, k, v, mask)
         ctx = nn.with_logical_constraint(ctx, ("batch", "seq", "heads", "kv"))
 
-        out = nn.DenseGeneral(
-            D,
-            axis=(-2, -1),
-            use_bias=True,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            kernel_init=nn.with_logical_partitioning(
-                default_kernel_init, ("heads", "kv", "embed")
-            ),
-            bias_init=nn.with_logical_partitioning(
-                nn.initializers.zeros_init(), ("embed",)
-            ),
-            name="out",
-        )(ctx)
+        with jax.named_scope(tracing.SCOPE_ATTN_PROJ):
+            out = nn.DenseGeneral(
+                D,
+                axis=(-2, -1),
+                use_bias=True,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    default_kernel_init, ("heads", "kv", "embed")
+                ),
+                bias_init=nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), ("embed",)
+                ),
+                name="out",
+            )(ctx)
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -275,15 +279,17 @@ class TransformerBlock(nn.Module):
     def __call__(self, x, mask=None, deterministic: bool = True):
         cfg = self.cfg
         ln = functools_partial_ln(cfg)
-        h = x + MultiHeadAttention(cfg, name="attn")(
-            ln(name="ln1")(x), mask, deterministic
-        )
-        ffn: nn.Module
+        with jax.named_scope(tracing.SCOPE_NORM):
+            y = ln(name="ln1")(x)
+        h = x + MultiHeadAttention(cfg, name="attn")(y, mask, deterministic)
+        with jax.named_scope(tracing.SCOPE_NORM):
+            y = ln(name="ln2")(h)
         if self.use_moe:
-            ffn = SwitchMoE(cfg, name="moe")
+            out = h + SwitchMoE(cfg, name="moe")(y, deterministic)
         else:
-            ffn = MlpBlock(cfg, name="mlp")
-        out = h + ffn(ln(name="ln2")(h), deterministic)
+            with jax.named_scope(tracing.SCOPE_MLP):
+                y = MlpBlock(cfg, name="mlp")(y, deterministic)
+            out = h + y
         out = nn.with_logical_constraint(out, ("batch", "seq", "embed"))
         return (out, None) if self.scanned else out
 
@@ -396,9 +402,11 @@ class TransformerLM(nn.Module):
     def __call__(self, ids, mask=None, deterministic: bool = True):
         cfg = self.cfg
         embedder = Embedder(cfg, name="embed")
-        x = embedder(ids)
+        with jax.named_scope(tracing.SCOPE_EMBED):
+            x = embedder(ids)
         x = TransformerStack(cfg, name="stack")(x, mask, deterministic)
-        x = functools_partial_ln(cfg)(name="ln_f")(x)
+        with jax.named_scope(tracing.SCOPE_NORM):
+            x = functools_partial_ln(cfg)(name="ln_f")(x)
         if cfg.logits_via_embedding:
             logits = embedder.attend(x)
         else:
@@ -420,9 +428,11 @@ class TransformerEncoder(nn.Module):
     @nn.compact
     def __call__(self, ids, mask=None, deterministic: bool = True):
         cfg = dataclasses.replace(self.cfg, causal=False)
-        x = Embedder(cfg, name="embed")(ids)
+        with jax.named_scope(tracing.SCOPE_EMBED):
+            x = Embedder(cfg, name="embed")(ids)
         x = TransformerStack(cfg, name="stack")(x, mask, deterministic)
-        x = functools_partial_ln(cfg)(name="ln_f")(x)
+        with jax.named_scope(tracing.SCOPE_NORM):
+            x = functools_partial_ln(cfg)(name="ln_f")(x)
         logits = _dense(cfg.vocab_size, cfg, "mlm_head", ("embed", "vocab"),
                         use_bias=False)(x)
         return logits.astype(cfg.logits_dtype)

@@ -39,8 +39,8 @@ names are the published `config.json` keys unless said otherwise):
 
 Discrete choices are sown into the collection `choices` as in
 `latent_moe`. Regions of the XLA profile: `hvd.attn.proj`,
-`hvd.attn.window`, `hvd.attn.full`, `hvd.moe.route`, `hvd.moe.experts`
-(docs/tracing.md "Under jit").
+`hvd.attn.window`, `hvd.attn.full`, `hvd.moe.route`, `hvd.moe.experts`,
+`hvd.mlp`, `hvd.norm`, `hvd.embed` (docs/tracing.md "Under jit").
 """
 from __future__ import annotations
 
@@ -346,11 +346,17 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, rope):
         cfg = self.cfg
-        h = x + GatedAttention(cfg, self.layer, name="attn")(
-            RMSNorm(cfg, name="attn_norm")(x), rope)
-        ffn = (RoutedExperts(cfg, name="moe") if self.layer.mlp == SPARSE
-               else GatedMLP(cfg, cfg.intermediate_size, name="mlp"))
-        out = h + ffn(RMSNorm(cfg, name="ffn_norm")(h))
+        with jax.named_scope(tracing.SCOPE_NORM):
+            y = RMSNorm(cfg, name="attn_norm")(x)
+        h = x + GatedAttention(cfg, self.layer, name="attn")(y, rope)
+        with jax.named_scope(tracing.SCOPE_NORM):
+            y = RMSNorm(cfg, name="ffn_norm")(h)
+        if self.layer.mlp == SPARSE:
+            out = h + RoutedExperts(cfg, name="moe")(y)
+        else:
+            with jax.named_scope(tracing.SCOPE_MLP):
+                y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(y)
+            out = h + y
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -375,12 +381,15 @@ class WindowMoELM(nn.Module):
         positions = jnp.arange(ids.shape[1])
         ropes = {kind: rotary_tables(positions, cfg.head_dim, rope)
                  for kind, rope in cfg.rope_parameters}
-        x = TokenEmbedding(cfg, name="embed")(ids)
+        with jax.named_scope(tracing.SCOPE_EMBED):
+            x = TokenEmbedding(cfg, name="embed")(ids)
         for i, layer in enumerate(cfg.layers):
             x = _block(cfg)(cfg, layer, name=f"layer_{i}")(
                 x, ropes[layer.attention])
+        with jax.named_scope(tracing.SCOPE_NORM):
+            x = RMSNorm(cfg, name="final_norm")(x)
         logits = _dense(cfg.vocab_size, cfg, "lm_head", ("embed", "vocab"),
-                        use_bias=False)(RMSNorm(cfg, name="final_norm")(x))
+                        use_bias=False)(x)
         return nn.with_logical_constraint(
             logits.astype(cfg.logits_dtype), ("batch", "seq", "vocab"))
 

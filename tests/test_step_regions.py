@@ -1,9 +1,10 @@
 """Under jit: the compiled step names its regions (`jax.named_scope`s
-`hvd.loss`, `hvd.optimizer`) and a step call records its spans in the
-XLA profile (`hvd.step` and `wrap_step`'s parts), docs/tracing.md
-"Under jit". All on the CPU at a tiny size: the names in the lowered
-program, the program unchanged by them, the spans read back from a
-profile of the host."""
+`hvd.loss`, `hvd.optimizer`, and in every model family the blocks'
+parts: `hvd.mlp`, `hvd.norm`, `hvd.embed`, `hvd.attn.*`) and a step
+call records its spans in the XLA profile (`hvd.step` and `wrap_step`'s
+parts), docs/tracing.md "Under jit". All on the CPU at a tiny size: the
+names in the lowered and compiled program, the program unchanged by
+them, the spans read back from a profile of the host."""
 import contextlib
 import pathlib
 import re
@@ -18,25 +19,42 @@ import horovod_tpu as hvd
 from horovod_tpu.common import tracing
 from horovod_tpu.models import get_model
 from horovod_tpu.parallel.mesh import create_mesh
-from horovod_tpu.parallel.train import lm_loss, make_train_step, softmax_xent
+from horovod_tpu.parallel.train import (
+    lm_loss, make_train_step, mtp_loss, softmax_xent)
 
 SEQ, BATCH, VOCAB = 16, 2, 64
 IDS = np.arange(BATCH * SEQ, dtype=np.int32).reshape(BATCH, SEQ) % VOCAB
 
 
-def _model():
-    return get_model("gpt2-tiny").make_model(
-        vocab_size=VOCAB, d_model=32, n_layers=1, n_heads=2, d_ff=64,
-        max_len=SEQ, attn_impl="dense")
+# The model families whose blocks name their parts, each at a tiny size.
+FAMILIES = ("gpt2", "bert", "window_moe", "latent_moe")
 
 
-def _gspmd_step():
+def _model(family="gpt2"):
+    if family in ("gpt2", "bert"):
+        return get_model(f"{family}-tiny").make_model(
+            vocab_size=VOCAB, d_model=32, n_layers=1, n_heads=2, d_ff=64,
+            max_len=SEQ, attn_impl="dense")
+    # The benchmark's cells recompute each block of these.
+    name = {"window_moe": "window-moe-tiny", "latent_moe": "latent-moe-tiny"}
+    return get_model(name[family]).make_model(remat=True)
+
+
+def _gspmd_step(family="gpt2"):
     """`make_train_step`'s init and step on one device."""
     mesh = create_mesh({"dp": 1}, devices=jax.devices()[:1])
+    extra = ({"aux_loss_fn": mtp_loss(0.3)} if family == "latent_moe"
+             else {})
     init, step, _ = make_train_step(
-        _model(), optax.adamw(1e-3), lm_loss, mesh=mesh, donate=False)(
-            jax.random.PRNGKey(0), IDS)
+        _model(family), optax.adamw(1e-3), lm_loss, mesh=mesh, donate=False,
+        **extra)(jax.random.PRNGKey(0), IDS)
     return init, step
+
+
+def _compiled_step(family="gpt2"):
+    init, step = _gspmd_step(family)
+    state = init(jax.random.PRNGKey(0))
+    return jax.jit(step.raw).lower(state, IDS).compile()
 
 
 def _user_step(model, tx):
@@ -57,14 +75,26 @@ def _text(lowered) -> str:
 
 # ------------------------------------------------------------ the scopes
 
-def test_the_vocabulary_is_six_names_under_one_prefix():
-    names = [tracing.SCOPE_LOSS, tracing.SCOPE_OPTIMIZER, tracing.SPAN_STEP,
-             tracing.SPAN_WRAP_PREPARE, tracing.SPAN_WRAP_BUILD,
-             tracing.SPAN_WRAP_CALL]
-    assert len(set(names)) == 6
+SCOPES = [tracing.SCOPE_LOSS, tracing.SCOPE_OPTIMIZER,
+          tracing.SCOPE_ATTN_LATENT, tracing.SCOPE_ATTN_PROJ,
+          tracing.SCOPE_ATTN_WINDOW, tracing.SCOPE_ATTN_FULL,
+          tracing.SCOPE_MOE_ROUTE, tracing.SCOPE_MOE_EXPERTS,
+          tracing.SCOPE_MTP, tracing.SCOPE_MLP, tracing.SCOPE_NORM,
+          tracing.SCOPE_EMBED]
+
+
+def test_the_vocabulary_is_sixteen_names_under_one_prefix():
+    spans = [tracing.SPAN_STEP, tracing.SPAN_WRAP_PREPARE,
+             tracing.SPAN_WRAP_BUILD, tracing.SPAN_WRAP_CALL]
+    names = SCOPES + spans
+    assert len(set(names)) == 16
     assert all(name.startswith("hvd.") for name in names)
     # A span of wrap_step's is told from the whole call by its prefix.
-    assert all(name.startswith("hvd.wrap_step.") for name in names[3:])
+    assert all(name.startswith("hvd.wrap_step.") for name in spans[1:])
+    # The readers find a scope in a name stack by its text: none may
+    # hold another.
+    assert not [(a, b) for a in SCOPES for b in SCOPES
+                if a != b and a in b]
 
 
 def test_softmax_xent_lowers_with_the_loss_scope_forward_and_backward():
@@ -81,8 +111,8 @@ def test_make_train_step_lowers_with_both_scopes():
     text = _text(jax.jit(step.raw).lower(state, IDS))
     assert f"jvp({tracing.SCOPE_LOSS})" in text
     assert f"{tracing.SCOPE_OPTIMIZER}/" in text
-    # The model's own names are flax's, untouched.
-    assert "jvp(TransformerLM)/stack/layer_0/mlp" in text
+    # The model's own names are flax's, a role's scope at its call site.
+    assert f"jvp(TransformerLM)/stack/layer_0/{tracing.SCOPE_MLP}/mlp" in text
     assert "transpose(jvp(TransformerLM))/stack/layer_0" in text
 
 
@@ -97,17 +127,19 @@ def test_distributed_optimizer_lowers_the_inner_update_with_the_scope(
     assert tracing.SCOPE_LOSS not in text
 
 
-@pytest.mark.parametrize("which", ["make_train_step", "wrap_step"])
+@pytest.mark.parametrize("which", ["make_train_step", "wrap_step",
+                                   "window_moe"])
 def test_the_scopes_change_nothing_of_the_program_but_its_metadata(
         which, monkeypatch, hvd_mesh):
     """The compiled step with the scopes and with them patched away:
     the same optimized HLO, instruction for instruction, and the same
-    cost by XLA's own analysis."""
+    cost by XLA's own analysis (a GPT-2 block, and a window-MoE model
+    whose blocks are recomputed)."""
     def compiled():
         if which == "make_train_step":
-            init, step = _gspmd_step()
-            state = init(jax.random.PRNGKey(0))
-            return jax.jit(step.raw).lower(state, IDS).compile()
+            return _compiled_step()
+        if which == "window_moe":
+            return _compiled_step("window_moe")
         model = _model()
         tx = hvd.DistributedOptimizer(optax.adamw(1e-3))
         params = jax.eval_shape(
@@ -124,13 +156,102 @@ def test_the_scopes_change_nothing_of_the_program_but_its_metadata(
         return ops, {k: cost[k] for k in ("flops", "bytes accessed")}
 
     with_scopes = compiled()
-    assert tracing.SCOPE_LOSS in with_scopes.as_text()
+    for scope in (tracing.SCOPE_LOSS, tracing.SCOPE_MLP, tracing.SCOPE_NORM,
+                  tracing.SCOPE_EMBED, tracing.SCOPE_ATTN_PROJ):
+        assert scope in with_scopes.as_text(), scope
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     without = compiled()
-    assert tracing.SCOPE_LOSS not in without.as_text()
+    assert "hvd." not in without.as_text()
     assert shape(with_scopes) == shape(without)
     assert len(shape(with_scopes)[0]) > 50
+
+
+# ---------------------------------------------- the blocks' parts by role
+
+def _named_ops(compiled) -> set:
+    """(opcode, name stack) of every instruction of the optimized HLO,
+    fused computations included: what the profile's `tf_op` is made of
+    (a nested jit is inlined there, so its ops carry the whole stack)."""
+    return {(m.group(1), m.group(2)) for m in re.finditer(
+        r"= \S+ ([\w-]+)\(.*?op_name=\"([^\"]*)\"", compiled.as_text())}
+
+
+def _passes(ops, opcode: str, part: str) -> set:
+    """The passes ("forward", "backward") that hold an op `opcode` (any
+    where None) whose name stack holds `part`."""
+    return {"backward" if "transpose(jvp(" in name else "forward"
+            for op, name in ops
+            if part in name and "jvp(" in name
+            and (opcode is None or op == opcode)}
+
+
+# (opcode or None, a piece of the name stack) that each family's step
+# must hold forward and backward.
+_PARTS = {
+    "gpt2": [("dot", "/hvd.attn.proj/qkv/"), ("dot", "/hvd.attn.proj/out/"),
+             ("dot", "/hvd.attn.full/"), ("dot", "/hvd.mlp/mlp/wi/"),
+             ("dot", "/hvd.mlp/mlp/wo/"), (None, "/hvd.norm/ln1/"),
+             (None, "/hvd.norm/ln2/"), (None, "/hvd.norm/ln_f/")],
+    "window_moe": [("dot", "/hvd.mlp/mlp/gate/"), ("dot", "/hvd.mlp/mlp/up/"),
+                   ("dot", "/hvd.mlp/mlp/down/"),
+                   (None, "/hvd.norm/attn_norm/"),
+                   (None, "/hvd.norm/ffn_norm/"),
+                   (None, "/hvd.norm/final_norm/")],
+}
+_PARTS["bert"] = _PARTS["gpt2"]
+_PARTS["latent_moe"] = _PARTS["window_moe"] + [
+    (None, "/hvd.mtp/mtp/hvd.norm/norm_h/"),
+    (None, "/hvd.mtp/mtp/hvd.norm/norm_e/"),
+    (None, "/hvd.mtp/mtp/hvd.norm/final_norm/"),
+    (None, "/hvd.mtp/hvd.embed/embed/")]
+
+
+@pytest.fixture(scope="module")
+def family_ops():
+    found = {}
+
+    def ops(family):
+        if family not in found:
+            found[family] = _named_ops(_compiled_step(family))
+        return found[family]
+    return ops
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_family_names_its_blocks_parts_forward_and_backward(
+        family, family_ops):
+    ops = family_ops(family)
+    for opcode, part in _PARTS[family]:
+        assert _passes(ops, opcode, part) == {"forward", "backward"}, part
+    # The embedding: the gather forward, the scatter into the table
+    # backward.
+    assert _passes(ops, "gather", f"/{tracing.SCOPE_EMBED}/embed/") == {
+        "forward"}
+    assert _passes(ops, "scatter", f"/{tracing.SCOPE_EMBED}/embed/") == {
+        "backward"}
+
+
+@pytest.mark.parametrize("family", ["window_moe", "latent_moe"])
+def test_a_shared_expert_is_expert_work_and_no_dense_feed_forward(
+        family, family_ops):
+    shared = [name for _, name in family_ops(family) if "/shared/" in name]
+    assert shared
+    assert all(tracing.SCOPE_MOE_EXPERTS in name for name in shared)
+    assert not [name for name in shared if tracing.SCOPE_MLP in name]
+    # Only the leading dense layer's feed-forward is `hvd.mlp`.
+    mlp = {re.search(r"/(layer_\d+)/", name).group(1)
+           for _, name in family_ops(family) if tracing.SCOPE_MLP in name}
+    assert mlp == {"layer_0"}
+
+
+def test_a_latent_attentions_inner_norms_are_its_own_work(family_ops):
+    inner = [name for _, name in family_ops("latent_moe")
+             if "_a_norm/" in name]
+    assert {"q_a_norm", "kv_a_norm"} <= {
+        n for name in inner for n in ("q_a_norm", "kv_a_norm") if n in name}
+    assert all(tracing.SCOPE_ATTN_LATENT in name for name in inner)
+    assert not [name for name in inner if tracing.SCOPE_NORM in name]
 
 
 # ------------------------------------------------------------- the spans
