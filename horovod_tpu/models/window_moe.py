@@ -18,7 +18,10 @@ names are the published `config.json` keys unless said otherwise):
   `0 <= i - j < sliding_window`; each head's output is multiplied by
   `sigmoid(x W_g)_h` (one gate a head and position) before the output
   projection. The call goes through `ops/attention.py`, whose flash
-  kernels visit only the key tiles a query tile's band intersects;
+  kernels visit only the key tiles a query tile's band intersects; it
+  is handed q unrotated, q's rotary tables, the gate and the output
+  projection's kernel, and the flash path keeps q, the output and
+  their gradients in the products' (B, S, H * head_dim) layout;
 * **rotary positions by layer kind** (`rope_parameters`): full layers
   rotate the first `partial_rotary_factor` of a head's dims with YaRN
   frequencies (`yarn_inv_freq`: per pair a blend of the plain frequency
@@ -54,8 +57,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import telemetry, tracing
-from ..ops.attention import attention
-from ..ops.flash_attention import attention_scores, key_tiles
+from ..ops.attention import apply_rotary, attention
+from ..ops.flash_attention import (
+    BH, COLUMNS, attention_scores, key_tiles, layout)
 from .latent_moe import (
     _KEEP_CHOICES, GatedMLP, RMSNorm, RoutedExperts, TokenEmbedding)
 from .transformer import _dense, default_kernel_init
@@ -73,6 +77,10 @@ _SCORES_HELP = ("Score entries one (batch, head) forward sweep of the flash "
                 "kernel computes at the sequence length traced, and the "
                 "visible pairs among them (what: computed, visible), by "
                 "layer kind (kind: window, full)")
+_LAYOUT_HELP = ("Flash calls a forward pass of the model makes, by layer kind "
+                "(kind: window, full) and by how the kernels hold the heads "
+                "(layout: columns, the projections' (B, S, H * D); bh, "
+                "transposed to (B * H, S, D))")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -266,20 +274,44 @@ def rotary_tables(positions, head_dim: int, rope: Rotary):
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
 
 
-def apply_rotary(x, cos, sin):
-    """Rotate-half: pairs (x[j], x[j + rot / 2]) of the first rot =
-    2 x cos.shape[-1] dims of the last axis; the rest passes. x:
-    (B, S, H, D)."""
-    half = cos.shape[-1]
-    a, b = (x[..., :half].astype(jnp.float32),
-            x[..., half:2 * half].astype(jnp.float32))
-    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-    return jnp.concatenate([turned.astype(x.dtype), x[..., 2 * half:]],
-                           axis=-1)
-
-
 # ------------------------------------------------------------------ pieces
+
+def _flat_product(x, kernel, dimension_numbers, precision=None):
+    """A heads projection's `DenseGeneral` product, (B, S, E) x (E, H, D),
+    as one product to (B, S, H * D), the layout the flash kernels hold
+    heads in (`ops/flash_attention.py::layout`). The 4-D view after it
+    is a reshape XLA cancels; a 4-D product's output is laid out with S
+    minor, and a relayout copy would sit between it and the kernel."""
+    del dimension_numbers                  # x's last axis, the kernel's first
+    out = jax.lax.dot_general(x, kernel.reshape(kernel.shape[0], -1),
+                              (((x.ndim - 1,), (0,)), ((), ())),
+                              precision=precision)
+    return out.reshape(*x.shape[:-1], *kernel.shape[1:])
+
+
+class HeadsKernel(nn.Module):
+    """The kernel (heads, head_dim, features) of a projection from the
+    heads, declared alone: name, shape, initializer and logical axes are
+    an `nn.DenseGeneral`'s over the last two axes (which draws it as the
+    flattened (heads * head_dim, features) matrix), and the product is
+    made by whoever reads it."""
+
+    heads: int
+    head_dim: int
+    features: int
+    param_dtype: Dtype
+
+    @nn.compact
+    def __call__(self):
+        def init(rng, shape, dtype):
+            flat = (shape[0] * shape[1], shape[2])
+            return default_kernel_init(rng, flat, dtype).reshape(shape)
+
+        return self.param(
+            "kernel", nn.with_logical_partitioning(
+                init, ("heads", "kv", "embed")),
+            (self.heads, self.head_dim, self.features), self.param_dtype)
+
 
 class GatedAttention(nn.Module):
     """Grouped-query attention of one layer kind with a per-head output
@@ -304,6 +336,13 @@ class GatedAttention(nn.Module):
                     S, cfg.head_dim, cfg.head_dim, window)):
                 telemetry.gauge("horovod_attention_scores", _SCORES_HELP,
                                 {"kind": kind, "what": what}).set(n)
+            calls = sum(layer.attention == self.layer.attention
+                        for layer in cfg.layers)
+            held = layout(cfg.head_dim, cfg.head_dim)
+            for name in (COLUMNS, BH):
+                telemetry.gauge("horovod_attention_layout", _LAYOUT_HELP,
+                                {"kind": kind, "layout": name}).set(
+                    calls if name == held else 0)
 
         def heads(n, name):
             return nn.DenseGeneral(
@@ -311,10 +350,10 @@ class GatedAttention(nn.Module):
                 param_dtype=cfg.param_dtype,
                 kernel_init=nn.with_logical_partitioning(
                     default_kernel_init, ("embed", "heads", "kv")),
-                name=name)
+                dot_general=_flat_product, name=name)
 
         with jax.named_scope(tracing.SCOPE_ATTN_PROJ):
-            q = apply_rotary(heads(H, "q")(x), *rope)            # (B,S,H,D)
+            q = heads(H, "q")(x)                # (B,S,H,D), rotated by the door
             k = apply_rotary(heads(cfg.num_key_value_heads, "k")(x), *rope)
             v = heads(cfg.num_key_value_heads, "v")(x)
             gate = jax.nn.sigmoid(_dense(H, cfg, "gate", ("embed", "heads"),
@@ -322,17 +361,15 @@ class GatedAttention(nn.Module):
             q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
             k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
             v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
-        with jax.named_scope(tracing.SCOPE_ATTN_WINDOW if sliding
-                             else tracing.SCOPE_ATTN_FULL):
-            ctx = attention(q, k, v, causal=True, window=window,
-                            impl=cfg.attn_impl)
-        with jax.named_scope(tracing.SCOPE_ATTN_PROJ):
-            out = nn.DenseGeneral(
-                cfg.hidden_size, axis=(-2, -1), use_bias=False,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                kernel_init=nn.with_logical_partitioning(
-                    default_kernel_init, ("heads", "kv", "embed")),
-                name="o")(ctx * gate[..., None])
+            o = HeadsKernel(H, cfg.head_dim, cfg.hidden_size,
+                            cfg.param_dtype, name="o")().astype(cfg.dtype)
+        # The door rotates q, gates the heads and projects them out, under
+        # the two scopes: the gated heads are not kept for the backward pass.
+        out = attention(q, k, v, causal=True, window=window,
+                        impl=cfg.attn_impl, rotary=rope, gate=gate, out=o,
+                        names=(tracing.SCOPE_ATTN_WINDOW if sliding
+                               else tracing.SCOPE_ATTN_FULL,
+                               tracing.SCOPE_ATTN_PROJ))
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 

@@ -2,10 +2,12 @@
 heads as they are; the kernel and the mesh axes to manualize are chosen
 here, from shapes and the ambient mesh.
 
-    attention(q, k, v, *, causal, window=None, mask=None, impl="dense")
+    attention(q, k, v, *, causal, window=None, mask=None, impl="dense",
+              rotary=None, gate=None, out=None, names=None)
 
 q (B, S, H, Dqk), k (B, S, H_kv, Dqk), v (B, S, H_kv, Dv) -> (B, S, H,
-Dv) in q's dtype; scores are scaled by 1/sqrt(Dqk).
+Dv) in q's dtype, or (B, S, E) given `out`; scores are scaled by
+1/sqrt(Dqk).
 
 * The mask is a description, never a tensor of scores: `causal` (key j
   visible to query i iff j <= i), `window` (and i - j < window; causal
@@ -14,6 +16,19 @@ Dv) in q's dtype; scores are scaled by 1/sqrt(Dqk).
 * The head layout is read off the shapes: `H_kv` divides `H`, query
   head h attends key/value head `h // (H / H_kv)`; `Dqk` may differ
   from `Dv` (a latent attention's 192 beside 128).
+* What a caller would otherwise do between its projections and this
+  call: `rotary`, q's (cos, sin) tables, each (S, rot / 2), q rotated
+  as `apply_rotary` does; `gate`, (B, S, H), each head's output
+  multiplied by it; `out`, the output projection's kernel (H, Dv, E).
+  Where the heads are whole lane tiles (`flash_attention.layout`) and
+  no mesh axis is manual, the flash call takes all three: q's rotary
+  on the kernels' tiles, so that nothing q-sized runs between the
+  projections and the kernels, the gate's backward in the kernel, and
+  the projection inside the call, so that the gated heads are formed
+  again for its gradient instead of kept. Every other path applies
+  them here, in XLA. `names`: scope names for the call's two parts,
+  the attention's and the rotary / gate / projection's (a caller that
+  names them calls the door outside both).
 * `impl`: "dense" (XLA, scores materialised), "flash" (the Pallas
   kernels of ops/flash_attention.py, which skip the key tiles a causal
   or windowed query tile cannot see), or the sequence-parallel "ring" /
@@ -36,7 +51,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..utils.compat import get_abstract_mesh, shard_map
-from .flash_attention import flash_attention
+from .flash_attention import (
+    COLUMNS, flash_attention, gated, layout, project, scope)
 
 IMPLS = ("dense", "flash", "ring", "ulysses")
 
@@ -44,6 +60,28 @@ IMPLS = ("dense", "flash", "ring", "ulysses")
 def _large_axes(mesh, *names) -> list:
     return [ax for ax in names if mesh is not None
             and ax in mesh.axis_names and mesh.shape[ax] > 1]
+
+
+def apply_rotary(x, cos, sin):
+    """Rotate-half: pairs (x[j], x[j + rot / 2]) of the first rot =
+    2 x cos.shape[-1] dims of the last axis; the rest passes. x:
+    (B, S, H, D)."""
+    half = cos.shape[-1]
+    a, b = (x[..., :half].astype(jnp.float32),
+            x[..., half:2 * half].astype(jnp.float32))
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return jnp.concatenate([turned.astype(x.dtype), x[..., 2 * half:]],
+                           axis=-1)
+
+
+def _finish(ctx, gate, out, name):
+    """ctx (B, S, H, Dv) times gate (B, S, H), head by head, and through
+    the output projection `out` (H, Dv, E), each where given, in XLA and
+    under the scope `name`."""
+    with scope(name):
+        y = gated(ctx, gate)
+        return y if out is None else project(y, out)
 
 
 def dense_attention(q, k, v, *, causal: bool, window: Optional[int] = None,
@@ -77,23 +115,43 @@ def dense_attention(q, k, v, *, causal: bool, window: Optional[int] = None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
 
 
-def _flash(q, k, v, causal, window, mask, mesh):
+def _flash(q, k, v, causal, window, mask, mesh, rotary, gate, out, names):
+    kernels, rest = names or (None, None)
     manual = _large_axes(mesh, "dp", "tp")
+    extra = {}                  # what the kernels' call takes
+    if layout(q.shape[-1], v.shape[-1]) == COLUMNS:
+        extra = {name: x for name, x in (("rotary", rotary), ("gate", gate))
+                 if x is not None}
+        if not manual:
+            return flash_attention(q, k, v, mask, causal=causal,
+                                   window=window, out=out, names=names,
+                                   **extra).astype(q.dtype)
+    elif rotary is not None:
+        with scope(rest):
+            q = apply_rotary(q, *rotary)
     kernel = functools.partial(flash_attention, causal=causal, window=window)
     args = (q, k, v) if mask is None else (q, k, v, mask)
-    if not manual:
-        return kernel(*args).astype(q.dtype)
-    dp = "dp" if "dp" in manual else None
-    tp = "tp" if "tp" in manual else None
-    if tp and k.shape[2] % mesh.shape[tp]:
-        raise ValueError(
-            f"{k.shape[2]} key/value heads do not divide over tp="
-            f"{mesh.shape[tp]}")
-    qkv_spec = P(dp, None, tp, None)   # (B, S, H, D)
-    specs = (qkv_spec,) * 3 + ((P(dp, None),) if mask is not None else ())
-    fn = shard_map(lambda *a: kernel(*a), mesh=mesh, in_specs=specs,
-                   out_specs=qkv_spec, axis_names=set(manual))
-    return fn(*args).astype(q.dtype)
+    with scope(kernels):
+        if not manual:
+            y = kernel(*args)
+        else:
+            dp = "dp" if "dp" in manual else None
+            tp = "tp" if "tp" in manual else None
+            if tp and k.shape[2] % mesh.shape[tp]:
+                raise ValueError(
+                    f"{k.shape[2]} key/value heads do not divide over tp="
+                    f"{mesh.shape[tp]}")
+            qkv_spec = P(dp, None, tp, None)   # (B, S, H, D)
+            specs = (qkv_spec,) * 3 + ((P(dp, None),) if mask is not None
+                                       else ())
+            extra_specs = {"rotary": (P(), P()), "gate": P(dp, None, tp)}
+            fn = shard_map(lambda a, e: kernel(*a, **e), mesh=mesh,
+                           in_specs=(specs,
+                                     {n: extra_specs[n] for n in extra}),
+                           out_specs=qkv_spec, axis_names=set(manual))
+            y = fn(args, extra)
+    return _finish(y.astype(q.dtype), None if "gate" in extra else gate,
+                   out, rest)
 
 
 def _sequence_parallel(q, k, v, causal, mask, impl, mesh, sp_axis,
@@ -132,7 +190,8 @@ def _sequence_parallel(q, k, v, causal, mask, impl, mesh, sp_axis,
 
 def attention(q, k, v, *, causal: bool, window: Optional[int] = None,
               mask=None, impl: str = "dense", sp_axis: str = "sp",
-              sp_use_flash: bool = False):
+              sp_use_flash: bool = False, rotary=None, gate=None, out=None,
+              names: Optional[tuple] = None):
     """Attention as the module docstring describes it."""
     if impl not in IMPLS:
         raise ValueError(f"impl {impl!r}: one of {IMPLS}")
@@ -143,13 +202,21 @@ def attention(q, k, v, *, causal: bool, window: Optional[int] = None,
     if window is not None and not causal:
         raise ValueError("a window is a causal window")
     if impl == "flash":
-        return _flash(q, k, v, causal, window, mask, get_abstract_mesh())
-    if impl == "dense" or not _large_axes(mesh := get_abstract_mesh(),
-                                          sp_axis):
-        return dense_attention(q, k, v, causal=causal, window=window,
-                               mask=mask)
-    if window is not None or H_kv != H:
-        raise NotImplementedError(
-            f"impl {impl!r} takes neither a window nor grouped heads")
-    return _sequence_parallel(q, k, v, causal, mask, impl, mesh, sp_axis,
-                              sp_use_flash)
+        return _flash(q, k, v, causal, window, mask, get_abstract_mesh(),
+                      rotary, gate, out, names)
+    kernels, rest = names or (None, None)
+    if rotary is not None:
+        with scope(rest):
+            q = apply_rotary(q, *rotary)
+    mesh = get_abstract_mesh()
+    with scope(kernels):
+        if impl == "dense" or not _large_axes(mesh, sp_axis):
+            ctx = dense_attention(q, k, v, causal=causal, window=window,
+                                  mask=mask)
+        elif window is not None or H_kv != H:
+            raise NotImplementedError(
+                f"impl {impl!r} takes neither a window nor grouped heads")
+        else:
+            ctx = _sequence_parallel(q, k, v, causal, mask, impl, mesh,
+                                     sp_axis, sp_use_flash)
+    return _finish(ctx, gate, out, rest)

@@ -48,6 +48,7 @@ tests/test_flash_attention.py, incl. interpret mode on CPU).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -253,9 +254,42 @@ def _band_bwd(k, v, q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
         dv_ref[0, own, :] = dv[g].T.astype(dv_ref.dtype)
 
 
+def _turn_t(xt, cos_t, sin_t, inverse: bool = False):
+    """Rotate-half of a transposed (D, n) float32 block: pair j is rows
+    (j, j + half) of the first 2 * half, half = cos_t.shape[0]; cos_t and
+    sin_t are (half, n). `inverse` applies the transpose of the map (the
+    gradient's way back), which is no rotation where the tables carry a
+    scale. The rows past 2 * half pass."""
+    half = cos_t.shape[0]
+    a, b = xt[:half], xt[half:2 * half]
+    if inverse:
+        parts = [a * cos_t + b * sin_t, b * cos_t - a * sin_t]
+    else:
+        parts = [a * cos_t - b * sin_t, b * cos_t + a * sin_t]
+    if 2 * half < xt.shape[0]:
+        parts.append(xt[2 * half:])
+    return jnp.concatenate(parts, axis=0)
+
+
+def _rotated(x, cos_t, sin_t):
+    """A (n, D) block of queries with their rotary positions, in x's
+    dtype: `ops/attention.py::apply_rotary`'s arithmetic, in float32 on
+    the transposed block, where the pairs are sublane slices."""
+    return _turn_t(x.astype(jnp.float32).T, cos_t, sin_t).T.astype(x.dtype)
+
+
+def _rotary_kernel(q_ref, k_ref, v_ref, mask_ref, cos_ref, sin_ref, o_ref,
+                   lse_ref, **kw):
+    """`_kernel` of a COLUMNS call given q's rotary tables (`_fwd_call`),
+    each (rot / 2, Sq) float32, held whole."""
+    _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+            rotary=(cos_ref, sin_ref), **kw)
+
+
 def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
             causal: bool, block_q: int, block_k: int, plain: bool,
-            window: Optional[int] = None, sub: Optional[int] = None):
+            window: Optional[int] = None, sub: Optional[int] = None,
+            rotary=None):
     """One (batch*head, q-block) grid step, streaming k-blocks.
 
     q_ref: (1, block_q, Dqk); k_ref: (1, S_pad, Dqk) and v_ref:
@@ -294,6 +328,9 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
     `sub` (a window and no padding mask; `_band_block`): a query tile
     whose bands lie inside the keys computes them at sub-block
     granularity instead (`_band_fwd`); the others take the sweep.
+
+    `rotary` (`_rotary_kernel`): the query tile is rotated once, before
+    anything reads it.
     """
     qi = pl.program_id(1)
     fold = _folds_exactly(scale)
@@ -301,6 +338,9 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale: float,
     # Native-dtype matmuls with f32 accumulation: bf16 inputs hit the
     # MXU's fast path; only the accumulator/softmax run in f32.
     q = q_ref[0]                               # (block_q, Dqk)
+    if rotary is not None:
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        q = _rotated(q, *(table[:, rows] for table in rotary))
     if fold:
         q = _scaled(q, scale)                  # once a program, exact
     Dv = v_ref.shape[-1]
@@ -544,16 +584,36 @@ def attention_scores(S: int, Dqk: int, Dv: int,
     return computed, visible
 
 
+COLUMNS, BH = "columns", "bh"
+
+
+def layout(Dqk: int, Dv: int) -> str:
+    """How a call hands its heads to the kernels, from its head sizes.
+
+    COLUMNS where both are whole 128-lane tiles: q, k, v, the output and
+    every gradient stay (B, S, H * D), the layout the projections write
+    and read, and head h is block column h of it; `_fwd_call` then also
+    takes q's rotary tables, and the backward forms rowsum(dO * O)
+    itself. BH elsewhere (heads of 64, 192 / 128): `_prep` transposes
+    to (B * H, S, D), since a column block of 128 lanes would hold two
+    heads or one and a half. No (B, S, H, D) layout of a TPU array is a
+    bitcast of the row-major (B, S, H * D), whose (8, 128) tiles run
+    over S: any q-sized op XLA runs on a 4-D view between the products
+    and the kernel costs a relayout copy, so the work there moves onto
+    the kernels' tiles (PERF.md section 6)."""
+    return COLUMNS if Dqk % 128 == 0 and Dv % 128 == 0 else BH
+
+
 def _prep(q, k, v, mask, block_q: Optional[int]):
     """Shared layout/padding for forward and backward: (B,S,H,D) ->
-    (B*H,S,D), each tensor at its own D and its own H (grouped keys and
-    values have fewer heads than the queries), with queries padded to a
-    block_q multiple (garbage rows sliced off after) and keys/values/
-    mask padded to a block_k multiple (padded keys carry mask 0, so they
-    never contribute). Both passes MUST use identical block/pad
-    arithmetic for the saved lse residual to line up with the
-    backward's blocks: `_blocks`, with an explicit `block_q` in place
-    of its first.
+    (B*H,S,D), or (B,S,H*D) where `layout` gives COLUMNS, each tensor at
+    its own D and its own H (grouped keys and values have fewer heads
+    than the queries), with queries padded to a block_q multiple
+    (garbage rows sliced off after) and keys/values/ mask padded to a
+    block_k multiple (padded keys carry mask 0, so they never
+    contribute). Both passes MUST use identical block/pad arithmetic for
+    the saved lse residual to line up with the backward's blocks:
+    `_blocks`, with an explicit `block_q` in place of its first.
 
     Also returns `plain`: True when no padding mask exists and keys
     needed no block padding — the kernels then take the mask-free fast
@@ -566,10 +626,14 @@ def _prep(q, k, v, mask, block_q: Optional[int]):
     pad_q = (-S) % bq
     pad_k = (-S) % bk
 
-    # (B, S, H, D) -> (B*H, S, D): attention is independent per (b, h).
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S,
-                                               x.shape[-1])
+    if layout(q.shape[-1], v.shape[-1]) == COLUMNS:
+        def to_bh(x):
+            return x.reshape(B, S, x.shape[2] * x.shape[3])
+    else:
+        # (B, S, H, D) -> (B*H, S, D): attention is independent per (b, h).
+        def to_bh(x):
+            return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S,
+                                                   x.shape[-1])
 
     qb, kb_arr, vb = to_bh(q), to_bh(k), to_bh(v)
     if pad_q:
@@ -605,56 +669,100 @@ def _kv_block(H: int, H_kv: int):
     return lambda bh: (bh // H) * H_kv + (bh % H) // group
 
 
-def _shares_the_older_calls_text(q, k, window) -> bool:
+def _head_blocks(columns: bool, H: int, H_kv: int):
+    """Block index maps `(own, kv)`, each `(bh, row block) -> index`, of
+    a (batch, query head) program's blocks in an array with one head a
+    query head, and in one with its key/value heads. (B * H, S, D):
+    block row `bh`, or `_kv_block`'s; COLUMNS, (B, S, H * D): batch row
+    and head column, through the same arithmetic."""
+    kv = _kv_block(H, H_kv)
+    if not columns:
+        return (lambda bh, i: (bh, i, 0)), (lambda bh, i: (kv(bh), i, 0))
+    return ((lambda bh, i: (bh // H, i, bh % H)),
+            (lambda bh, i: (kv(bh) // H_kv, i, kv(bh) % H_kv)))
+
+
+def _tables(rotary, length: int):
+    """q's rotary tables (cos, sin), each (S, rot / 2), as the kernels
+    hold them: float32, transposed to (rot / 2, S) so that a block of
+    positions is a lane slice, padded with zeros to `length`."""
+    S = rotary[0].shape[0]
+    return tuple(jnp.pad(t.astype(jnp.float32).T, ((0, 0), (0, length - S)))
+                 for t in rotary)
+
+
+def _whole(shape):
+    """A BlockSpec of the whole of a 2-D array, the same for every
+    program (fetched once)."""
+    return pl.BlockSpec(shape, lambda *_: (0, 0))
+
+
+def _shares_the_older_calls_text(q, k, v, window) -> bool:
     """Whether a call is one the kernels took before they knew grouped
-    heads and windows. Such a call is traced in line, as it always was:
-    the benchmark's older cells keep their lowered steps to the letter.
-    Every other call goes through a module-level `jax.jit`, so that a
-    model's layers of one shape trace and lower the kernel once (a
-    `custom_vjp` around a jit keeps recomputation's rules out of it;
-    PERF.md section 6, PR 34)."""
-    return window is None and k.shape[2] == q.shape[2]
+    heads, windows and column blocks. Such a call is traced in line, as
+    it always was: the benchmark's older cells keep their lowered steps
+    to the letter. Every other call goes through a module-level
+    `jax.jit`, so that a model's layers of one shape trace and lower the
+    kernel once (a `custom_vjp` around a jit keeps recomputation's rules
+    out of it; PERF.md section 6)."""
+    return (window is None and k.shape[2] == q.shape[2]
+            and layout(q.shape[-1], v.shape[-1]) == BH)
 
 
 def _flash_fwd(q, k, v, mask, causal: bool, block_q: int,
-               interpret: Optional[bool], window: Optional[int] = None
-               ) -> "tuple[jax.Array, jax.Array]":
-    if _shares_the_older_calls_text(q, k, window):
+               interpret: Optional[bool], window: Optional[int] = None,
+               rotary=None) -> "tuple[jax.Array, jax.Array]":
+    if _shares_the_older_calls_text(q, k, v, window):
         return _fwd_call(q, k, v, mask, causal, block_q, interpret, None)
-    return _fwd_call_once(q, k, v, mask, causal, block_q, interpret, window)
+    return _fwd_call_once(q, k, v, mask, causal, block_q, interpret, window,
+                          rotary)
+
+
+def _rows(x, B: int, H: int, length: int):
+    """A per-(batch, head) row (B, S, H) as the kernels hold it: float32,
+    (B * H, 1, S), padded with zeros to `length`."""
+    S = x.shape[1]
+    x = x.astype(jnp.float32).transpose(0, 2, 1).reshape(B * H, 1, S)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, length - S)))
 
 
 def _fwd_call(q, k, v, mask, causal: bool, block_q: int,
-              interpret: Optional[bool], window: Optional[int]):
+              interpret: Optional[bool], window: Optional[int], rotary=None):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
+    columns = layout(D, Dv) == COLUMNS
     qb, kb_arr, vb, mask2, _, bq, bk, Sq, Sk, plain = _prep(q, k, v,
                                                             mask, block_q)
     grid = (B * H, Sq // bq)
-    kv = _kv_block(H, k.shape[2])
+    own, kv = _head_blocks(columns, H, k.shape[2])
     sub = _band_block(window, bq, bk) if mask is None else None
+    kernel, tables = _kernel, ()
+    if rotary is not None:
+        kernel, tables = _rotary_kernel, _tables(rotary, Sq)
 
     def call(interp: bool):
         return pl.pallas_call(
-            functools.partial(_kernel, scale=scale, causal=causal,
+            functools.partial(kernel, scale=scale, causal=causal,
                               block_q=bq, block_k=bk, plain=plain,
                               window=window, sub=sub),
             out_shape=[
-                jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
+                jax.ShapeDtypeStruct(
+                    (B, Sq, H * Dv) if columns else (B * H, Sq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
             ],
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
-                pl.BlockSpec((1, Sk, D), lambda bh, qi: (kv(bh), 0, 0)),
-                pl.BlockSpec((1, Sk, Dv), lambda bh, qi: (kv(bh), 0, 0)),
+                pl.BlockSpec((1, bq, D), lambda bh, qi: own(bh, qi)),
+                pl.BlockSpec((1, Sk, D), lambda bh, qi: kv(bh, 0)),
+                pl.BlockSpec((1, Sk, Dv), lambda bh, qi: kv(bh, 0)),
                 # mask indexed by batch = bh // H (static H via closure).
                 pl.BlockSpec((1, 1, Sk),
                              lambda bh, qi, H=H: (bh // H, 0, 0)),
+                *(_whole(t.shape) for t in tables),
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, Dv), lambda bh, qi: (bh, qi, 0)),
+                pl.BlockSpec((1, bq, Dv), lambda bh, qi: own(bh, qi)),
                 pl.BlockSpec((1, 1, bq), lambda bh, qi: (bh, 0, qi)),
             ],
             interpret=interp,
@@ -662,22 +770,119 @@ def _fwd_call(q, k, v, mask, causal: bool, block_q: int,
             **_compiler_params(D, Dv, seq=Sk),
         )
 
-    out, lse = call_by_platform(call, qb, kb_arr, vb, mask2,
+    out, lse = call_by_platform(call, qb, kb_arr, vb, mask2, *tables,
                                 interpret=interpret)
 
     out = out[:, :S]
     # Slice lse to the real rows too, so the backward's re-pad is the
     # single true padding (padded-row lse is kernel garbage here).
+    if columns:
+        return out.reshape(B, S, H, Dv), lse[:, :, :S]
     return out.reshape(B, H, S, Dv).transpose(0, 2, 1, 3), lse[:, :, :S]
 
 
 _fwd_call_once = jax.jit(_fwd_call, static_argnums=(4, 5, 6, 7))
 
 
+def gated(out, gate):
+    """out (B, S, H, Dv) times gate (B, S, H), head by head, in XLA,
+    written over (B, S / 8, 8, H, Dv): laid out with H outside the 8,
+    that is the (8, 128) tiling of (B, S, H * Dv) itself, a COLUMNS
+    call's output as it lies. Over a 4-D view the product would be laid
+    out anew."""
+    if gate is None:
+        return out
+    B, S, H, Dv = out.shape
+    if S % 8:
+        return out * gate[..., None]
+    tiles = out.reshape(B, S // 8, 8, H, Dv)
+    return (tiles * gate.reshape(B, S // 8, 8, H, 1)).reshape(B, S, H, Dv)
+
+
+def project(y, kernel):
+    """(B, S, H, Dv) x (H, Dv, E) -> (B, S, E): an output projection as
+    one product over the flattened heads, reading the (B, S, H * Dv)
+    layout the kernels write."""
+    B, S, H, Dv = y.shape
+    return jax.lax.dot_general(y.reshape(B, S, H * Dv),
+                               kernel.reshape(H * Dv, -1),
+                               (((2,), (0,)), ((), ())))
+
+
+def _project_grads(flat, gate, kernel, dz):
+    """(dy, dkernel) of `project` given dz, each product over the
+    flattened heads as the forward's. `flat` is the ungated output as
+    the kernels write it, (B, S, H * Dv); the gated one is formed again
+    here."""
+    B, S, HDv = flat.shape
+    H = kernel.shape[0]
+    y = gated(flat.reshape(B, S, H, HDv // H), gate).reshape(flat.shape)
+    dkernel = jax.lax.dot_general(y, dz, (((0, 1), (0, 1)), ((), ())))
+    dy = jax.lax.dot_general(dz, kernel.reshape(HDv, -1),
+                             (((2,), (1,)), ((), ())))
+    return (dy.reshape(B, S, H, HDv // H), dkernel.reshape(kernel.shape))
+
+
+def scope(name: Optional[str]):
+    """`jax.named_scope(name)`, or nothing where `name` is None."""
+    return contextlib.nullcontext() if name is None else jax.named_scope(name)
+
+
+def _dqkv_columns_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
+                         o_ref, *refs, rotary: bool, gated: bool,
+                         block_q: int, **kw):
+    """`_dqkv_kernel` of a COLUMNS call. It takes the output O (the
+    ungated one) in place of delta; after it, where `rotary`, q's tables,
+    each (rot / 2, Sq), and where `gated` the gate's (1, 1, Sq) row, with
+    dO the gated output's cotangent dY and a fourth output, the gate's
+    (1, 1, Sq) gradient.
+
+    Before a (batch, head) program's first key tile it fills VMEM
+    scratches from its resident blocks, a query tile at a time: delta =
+    rowsum(dO * O) (gated: dgate = rowsum(dY * O), delta = gate * dgate
+    and dO = gate * dY, rounded as XLA's product would be), and q rotated
+    (`_rotated`). The sweep reads them where it read delta, dO and q, and
+    the flush turns dQ back to the unrotated q's gradient (`_turn_t`'s
+    inverse)."""
+    f32 = jnp.float32
+    tables, refs = refs[:2 * rotary], refs[2 * rotary:]
+    gate, refs = (refs[0] if gated else None), refs[gated:]
+    dq_ref, dk_ref, dv_ref, *refs = refs
+    dgate_ref, refs = (refs[0] if gated else None), refs[gated:]
+    dq_acc, delta_ref, *refs = refs
+    q_turned, refs = (refs[0] if rotary else q_ref), refs[rotary:]
+    dy_ref, do_ref = do_ref, (refs[0] if gated else do_ref)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _resident():
+        def fill(c, carry):
+            rows = pl.ds(pl.multiple_of(c * block_q, block_q), block_q)
+            dy_t = dy_ref[0, rows, :].astype(f32).T
+            dot = jnp.sum(dy_t * o_ref[0, rows, :].astype(f32).T, axis=0,
+                          keepdims=True)                # (1, block_q)
+            if gated:
+                g = gate[0, :, rows]
+                dgate_ref[0, :, rows] = dot
+                dot = g * dot
+                do_ref[0, rows, :] = (dy_t * g).T.astype(do_ref.dtype)
+            delta_ref[0, :, rows] = dot
+            if rotary:
+                q_turned[0, rows, :] = _rotated(
+                    q_ref[0, rows, :], *(t[:, rows] for t in tables))
+            return carry
+
+        jax.lax.fori_loop(0, q_ref.shape[1] // block_q, fill, 0)
+
+    _dqkv_kernel(q_turned, k_ref, v_ref, mask_ref, do_ref, lse_ref,
+                 delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, block_q=block_q,
+                 unrotate=tables or None, **kw)
+
+
 def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dk_ref, dv_ref, dq_acc, *, scale: float,
                  causal: bool, block_q: int, block_k: int, plain: bool,
-                 window: Optional[int] = None, sub: Optional[int] = None):
+                 window: Optional[int] = None, sub: Optional[int] = None,
+                 unrotate=None):
     """FUSED backward: grid (B*H, k-block), ki innermost. One sweep
     computes dK/dV for this k-block AND accumulates every q-block's dQ
     contribution into a persistent f32 VMEM scratch (written out once,
@@ -708,7 +913,10 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
     Grouped heads: a program is a QUERY head's; it reads its group's K
     and V block (`_kv_block`) and writes dK / dV of its own, which
-    `_flash_bwd` sums over the group."""
+    `_flash_bwd` sums over the group.
+
+    `unrotate` (`_dqkv_columns_kernel`): q's rotary tables, and dQ leaves
+    through the transpose of the rotation."""
     ki = pl.program_id(1)
     fold = _folds_exactly(scale)
     k = k_ref[0]                                 # (bk, Dqk)
@@ -803,22 +1011,30 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ki == num_kb - 1)
     def _flush_dq():
         dq = dq_acc[...] if fold else dq_acc[...] * scale
+        if unrotate is not None:
+            dq = _turn_t(dq, *(t[...] for t in unrotate), inverse=True)
         dq_ref[0] = dq.T.astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
-               interpret: Optional[bool], window: Optional[int] = None):
-    if _shares_the_older_calls_text(q, k, window):
+               interpret: Optional[bool], window: Optional[int] = None,
+               rotary=None, gate=None):
+    if _shares_the_older_calls_text(q, k, v, window):
         return _bwd_call(q, k, v, mask, out, lse, g, causal, block_q,
                          interpret, None)
     return _bwd_call_once(q, k, v, mask, out, lse, g, causal, block_q,
-                          interpret, window)
+                          interpret, window, rotary, gate)
 
 
 def _bwd_call(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
-              interpret: Optional[bool], window: Optional[int]):
+              interpret: Optional[bool], window: Optional[int], rotary=None,
+              gate=None):
     """Blockwise backward: same VMEM-bounded structure as the forward —
-    the (S, S) score matrix is never materialized in HBM.
+    the (S, S) score matrix is never materialized in HBM. In the COLUMNS
+    layout the kernel forms delta = rowsum(dO * O), and given them rotates
+    q and un-rotates dQ, and applies the gate to g and forms its gradient
+    (`_dqkv_columns_kernel`); `out` is then the ungated output. Returns
+    dQ, dK, dV and the gate's gradient (None without a gate).
 
     Grouped heads: the kernel writes dK and dV per QUERY head and the
     group's are summed here, in float32, where XLA fuses the sum into
@@ -834,6 +1050,7 @@ def _bwd_call(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
     H_kv = k.shape[2]
     Dv = v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
+    columns = layout(D, Dv) == COLUMNS
     qb, kb_arr, vb, mask2, to_bh, bq, bk, Sq, Sk, plain = _prep(
         q, k, v, mask, block_q)
     pad_q = Sq - S
@@ -846,74 +1063,111 @@ def _bwd_call(q, k, v, mask, out, lse, g, causal: bool, block_q: int,
         lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)),
                       constant_values=1e30)
 
-    # delta = rowsum(dO * O) (tiny elementwise; jnp outside the kernel).
-    delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
-                    axis=-1).reshape(B * H, 1, Sq)
-
     # Q, K, dQ, dK at Dqk; V, dO, dV at Dv.
-    full_q = pl.BlockSpec((1, Sq, D), lambda bh, ki: (bh, 0, 0))
-    full_do = pl.BlockSpec((1, Sq, Dv), lambda bh, ki: (bh, 0, 0))
+    own, kv = _head_blocks(columns, H, H_kv)
+    full_q = pl.BlockSpec((1, Sq, D), lambda bh, ki: own(bh, 0))
+    full_do = pl.BlockSpec((1, Sq, Dv), lambda bh, ki: own(bh, 0))
     row_q = pl.BlockSpec((1, 1, Sq), lambda bh, ki: (bh, 0, 0))
-    blk_k = pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0))
-    blk_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: (bh, ki, 0))
-    kv = _kv_block(H, H_kv)
-    in_k = pl.BlockSpec((1, bk, D), lambda bh, ki: (kv(bh), ki, 0))
-    in_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: (kv(bh), ki, 0))
+    blk_k = pl.BlockSpec((1, bk, D), lambda bh, ki: own(bh, ki))
+    blk_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: own(bh, ki))
+    in_k = pl.BlockSpec((1, bk, D), lambda bh, ki: kv(bh, ki))
+    in_v = pl.BlockSpec((1, bk, Dv), lambda bh, ki: kv(bh, ki))
     sub = _band_block(window, bq, bk) if mask is None else None
+
+    if columns:
+        kernel = functools.partial(_dqkv_columns_kernel,
+                                   rotary=rotary is not None,
+                                   gated=gate is not None)
+        last, last_specs = (ob,), [full_do]
+        outs, scratch = [], [pltpu.VMEM((1, 1, Sq), jnp.float32)]
+        if rotary is not None:
+            last += _tables(rotary, Sq)
+            last_specs += [_whole(t.shape) for t in last[-2:]]
+            scratch.append(pltpu.VMEM((1, Sq, D), q.dtype))
+        if gate is not None:
+            last += (_rows(gate, B, H, Sq),)
+            last_specs.append(row_q)
+            outs.append(jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32))
+            scratch.append(pltpu.VMEM((1, Sq, Dv), g.dtype))
+    else:
+        # delta = rowsum(dO * O) (tiny elementwise; jnp outside the kernel).
+        delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
+                        axis=-1).reshape(B * H, 1, Sq)
+        kernel, last, last_specs = _dqkv_kernel, (delta,), [row_q]
+        outs, scratch = [], []
 
     def call(interp: bool):
         return pl.pallas_call(
-            functools.partial(_dqkv_kernel, scale=scale, causal=causal,
+            functools.partial(kernel, scale=scale, causal=causal,
                               block_q=bq, block_k=bk, plain=plain,
                               window=window, sub=sub),
             out_shape=[
-                jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-                jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
-                jax.ShapeDtypeStruct((B * H, Sk, Dv), v.dtype),
-            ],
+                jax.ShapeDtypeStruct(shape, x.dtype) for shape, x in (
+                    ((B, Sq, H * D) if columns else (B * H, Sq, D), q),
+                    ((B, Sk, H * D) if columns else (B * H, Sk, D), k),
+                    ((B, Sk, H * Dv) if columns else (B * H, Sk, Dv), v))
+            ] + outs,
             grid=(B * H, Sk // bk),
             in_specs=[
                 full_q,
                 in_k, in_v,
                 pl.BlockSpec((1, 1, bk),
                              lambda bh, ki, H=H: (bh // H, 0, ki)),
-                full_do, row_q, row_q,
+                full_do, row_q,
+                *last_specs,
             ],
             out_specs=[
                 full_q,   # dq: one block per (b, h), flushed on last ki
                 blk_k, blk_v,
-            ],
-            scratch_shapes=[pltpu.VMEM((D, Sq), jnp.float32)],
+            ] + [row_q] * len(outs),
+            scratch_shapes=[pltpu.VMEM((D, Sq), jnp.float32), *scratch],
             interpret=interp,
             name="flash_attention_bwd",
             **_compiler_params(D, Dv, seq=Sq),
         )
 
-    dq, dk, dv = call_by_platform(call, qb, kb_arr, vb, mask2, dob, lse,
-                                  delta, interpret=interpret)
+    dq, dk, dv, *dgate = call_by_platform(
+        call, qb, kb_arr, vb, mask2, dob, lse, *last, interpret=interpret)
+    if dgate:
+        dgate = dgate[0][:, 0, :S].reshape(B, H, S).transpose(0, 2, 1)
+        dgate = dgate.astype(gate.dtype)
+    else:
+        dgate = None
 
     def from_bh(x, S_):
+        if columns:
+            return x[:, :S_].reshape(B, S_, H, x.shape[-1] // H)
         return x[:, :S_].reshape(B, H, S_, x.shape[-1]).transpose(0, 2, 1, 3)
 
     if H_kv == H:
-        return from_bh(dq, S), from_bh(dk, S), from_bh(dv, S)
+        return from_bh(dq, S), from_bh(dk, S), from_bh(dv, S), dgate
 
     def group_sum(x):
+        if columns:
+            # Block columns summed as slices of the (B, S, H * D) array
+            # itself: a reduce over a 5-D view of it is laid out anew.
+            group, width = H // H_kv, x.shape[-1] // H
+            sums = [sum(x[:, :S, (j * group + i) * width:
+                          (j * group + i + 1) * width].astype(jnp.float32)
+                        for i in range(group)).astype(x.dtype)
+                    for j in range(H_kv)]
+            return jnp.concatenate(sums, axis=-1).reshape(B, S, H_kv, width)
         per_head = x[:, :S].reshape(B, H_kv, H // H_kv, S, x.shape[-1])
         return jnp.sum(per_head.astype(jnp.float32), axis=2).astype(
             x.dtype).transpose(0, 2, 1, 3)
 
-    return from_bh(dq, S), group_sum(dk), group_sum(dv)
+    return from_bh(dq, S), group_sum(dk), group_sum(dv), dgate
 
 
 _bwd_call_once = jax.jit(_bwd_call, static_argnums=(7, 8, 9, 10))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 11))
 def flash_attention(q, k, v, mask=None, causal: bool = True,
                     block_q: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, rotary=None, gate=None,
+                    out=None, names: Optional[tuple] = None):
     """Fused attention. q: (B, S, H, Dqk), k: (B, S, H_kv, Dqk), v:
     (B, S, H_kv, Dv); mask: optional (B, S) key validity (1 = attend).
     Returns (B, S, H, Dv) in q.dtype; scores are scaled by 1/sqrt(Dqk).
@@ -925,6 +1179,22 @@ def flash_attention(q, k, v, mask=None, causal: bool = True,
     a query tile's band intersects where that band leaves the sequence
     or is wider than two tiles.
 
+    COLUMNS calls only (`layout`) take what a caller would otherwise do
+    between its projections and this call: `rotary`, q's (cos, sin)
+    tables, each (S, rot / 2), which the kernels apply as
+    `ops/attention.py::apply_rotary` does (the gradient is the unrotated
+    q's; the tables get none); `gate` (B, S, H), the output of head h at
+    position i multiplied by gate[:, i, h] (`gated`, in XLA; the
+    backward kernel applies it to the cotangent and forms its gradient
+    from the ungated output); `out` (H, Dv, E), the output projection's
+    kernel, and the call returns (B, S, E) (`project`). Given `out`, the
+    gated output is kept by nothing: the backward pass forms it again
+    for the kernel's gradient. Without it, the product that reads the
+    call's output would keep it alive through a recomputed block's
+    backward pass, a third q-sized array beside q and the ungated
+    output. `names`: two scope names the call's ops are traced under,
+    the kernels' and (gate and projection) the rest's.
+
     Tile sizes come from `_blocks(S, Dqk, Dv)`; an explicit `block_q`
     replaces the first. Both vjp passes resolve the pair identically in
     `_prep`.
@@ -933,20 +1203,48 @@ def flash_attention(q, k, v, mask=None, causal: bool = True,
     8-device mesh), the compiled kernel on anything else."""
     if window is not None and not causal:
         raise ValueError("a window is a causal window")
-    out, _ = _flash_fwd(q, k, v, mask, causal, block_q, interpret, window)
-    return out
+    if (rotary, gate, out) != (None,) * 3 and layout(
+            q.shape[-1], v.shape[-1]) != COLUMNS:
+        raise ValueError(f"rotary, gate and out go into the kernels' call "
+                         f"at head sizes of whole lane tiles, not "
+                         f"{q.shape[-1]} / {v.shape[-1]}")
+    result, _ = _fwd(q, k, v, mask, causal, block_q, interpret, window,
+                     rotary, gate, out, names)
+    return result
 
 
-def _fwd(q, k, v, mask, causal, block_q, interpret, window):
-    out, lse = _flash_fwd(q, k, v, mask, causal, block_q, interpret, window)
-    return out, (q, k, v, mask, out, lse)
+def _fwd(q, k, v, mask, causal, block_q, interpret, window, rotary, gate,
+         out, names):
+    kernels, rest = names or (None, None)
+    with scope(kernels):
+        ctx, lse = _flash_fwd(q, k, v, mask, causal, block_q, interpret,
+                              window, rotary)
+    with scope(rest):
+        y = gated(ctx, gate)
+        result = y if out is None else project(y, out)
+    return result, (q, k, v, mask, ctx, lse, rotary, gate, out)
 
 
-def _bwd(causal, block_q, interpret, window, residuals, g):
-    q, k, v, mask, out, lse = residuals
-    dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q,
-                            interpret, window)
-    return dq, dk, dv, None
+def _bwd(causal, block_q, interpret, window, names, residuals, g):
+    q, k, v, mask, ctx, lse, rotary, gate, out = residuals
+    kernels, rest = names or (None, None)
+    dout = None
+    if out is not None:
+        with scope(rest):
+            # The gated output is formed again behind a barrier that also
+            # holds the cotangent: without it XLA computes it once, in the
+            # forward, and keeps it; with the inputs alone in the barrier
+            # it forms it early all the same. Over the layout the kernels
+            # write, or it would be laid out anew.
+            B, S, H, Dv = ctx.shape
+            flat, gate_again, g = jax.lax.optimization_barrier(
+                (ctx.reshape(B, S, H * Dv), gate, g))
+            g, dout = _project_grads(flat, gate_again, out, g)
+    with scope(kernels):
+        dq, dk, dv, dgate = _flash_bwd(q, k, v, mask, ctx, lse, g, causal,
+                                       block_q, interpret, window, rotary,
+                                       gate)
+    return dq, dk, dv, None, None, dgate, dout
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -404,6 +404,120 @@ def test_band_sub_blocks_outside_every_band_are_not_computed(band_tiles):
     assert np.isnan(np.asarray(dk[:, 72:80])).any()
 
 
+# -------------------------------- column blocks: rotary and gate on the tiles
+
+@pytest.mark.parametrize("Dqk,Dv,want", [
+    (128, 128, fa.COLUMNS), (256, 128, fa.COLUMNS), (64, 64, fa.BH),
+    (192, 128, fa.BH), (96, 96, fa.BH), (128, 64, fa.BH)])
+def test_layout_is_read_off_the_head_sizes(Dqk, Dv, want):
+    """Heads of whole 128-lane tiles stay in the projections' layout; a
+    column block of 128 lanes would hold two heads of 64 or one and a
+    half of 192."""
+    assert fa.layout(Dqk, Dv) == want
+
+
+def _tables(S, kind, D=128):
+    from horovod_tpu.models import window_moe
+
+    rope = window_moe.Rotary(**({
+        "full": {}, "none": {},
+        "partial-yarn": dict(window_moe._LAGUNA_ROPE[window_moe.FULL],
+                             original_max_position_embeddings=64)}[kind]))
+    if kind == "none":
+        return None
+    return window_moe.rotary_tables(jnp.arange(S), D, rope)
+
+
+# S, window, q's rotary (full: all 128 dims at theta 10000; partial-yarn:
+# laguna's full layers, 64 of 128 dims), whether a gate is given, whether
+# the output projection is.
+_COLUMN_CASES = {
+    "window-full-rotary-projected": (160, 24, "full", True, True),
+    "window-partial-yarn-S-padded": (150, 8, "partial-yarn", True, False),
+    "causal-full-rotary-S-padded-projected": (150, None, "full", True,
+                                              True),
+    "causal-partial-yarn-projected": (160, None, "partial-yarn", True, True),
+    "window-no-rotary-no-gate": (160, 20, "none", False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_COLUMN_CASES))
+def test_columns_match_the_transposed_path(band_tiles, monkeypatch, case):
+    """Heads of 128, 6 query heads over 2: the COLUMNS call, with q's
+    rotary on the kernels' tiles, the gate's backward in the kernel and
+    the output projection in the call, against the path heads of 64
+    take (`apply_rotary`, the gate and the projection in XLA around the
+    (B * H, S, D) kernels): output and lse, dQ / dK / dV, the gate's and
+    the projection's gradients, to float32 rounding."""
+    from horovod_tpu.ops.attention import apply_rotary
+
+    S, window, kind, gated, projected = _COLUMN_CASES[case]
+    q, k, v = _grouped_qkv(S, 6, 2, D=128, seed=S)
+    rng = np.random.RandomState(1)
+    gate = jnp.asarray(rng.uniform(0.1, 1.0, (1, S, 6)), jnp.float32)
+    kernel = jnp.asarray(rng.randn(6, 128, 32) / 30, jnp.float32)
+    tables = _tables(S, kind)
+    w = jnp.asarray(rng.randn(1, S, *((32,) if projected else (6, 128))),
+                    jnp.float32)
+
+    def columns(q, k, v, gate, kernel):
+        return flash_attention(q, k, v, None, True, None, True, window,
+                               tables, gate if gated else None,
+                               kernel if projected else None)
+
+    def transposed(q, k, v, gate, kernel):
+        if tables is not None:
+            q = apply_rotary(q, *tables)
+        out = flash_attention(q, k, v, None, True, None, True, window)
+        out = out * gate[..., None] if gated else out
+        return fa.project(out, kernel) if projected else out
+
+    def run(f):
+        out, vjp = jax.vjp(f, q, k, v, gate, kernel)
+        rotated = q if tables is None else apply_rotary(q, *tables)
+        _, lse = fa._fwd_call(rotated if f is transposed else q, k, v, None,
+                              True, None, True, window,
+                              None if f is transposed else tables)
+        return [np.asarray(x) for x in (out, lse, *vjp(w))]
+
+    got = run(columns)
+    monkeypatch.setattr(fa, "layout", lambda Dqk, Dv: fa.BH)
+    fa._fwd_call_once.clear_cache()
+    fa._bwd_call_once.clear_cache()
+    want = run(transposed)
+    for name, a, b in zip(["out", "lse", "dq", "dk", "dv", "dgate", "dout"],
+                          got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def test_columns_under_a_dp_tp_mesh_match_one_device():
+    """The door's flash call under a dp x tp mesh manualizes both axes
+    around the kernels, rotary tables replicated and the gate split like
+    the heads, and projects the heads out under GSPMD; the same as on
+    one device, forward and gradients."""
+    from horovod_tpu.ops.attention import attention
+    from horovod_tpu.parallel.mesh import create_mesh
+
+    q, k, v = _grouped_qkv(64, 4, 2, D=128, B=2, seed=5)
+    rng = np.random.RandomState(2)
+    gate = jnp.asarray(rng.uniform(0.1, 1.0, (2, 64, 4)), jnp.float32)
+    kernel = jnp.asarray(rng.randn(4, 128, 16) / 20, jnp.float32)
+    tables = _tables(64, "partial-yarn")
+
+    def door(q, k, v, gate, kernel):
+        return jnp.sum(attention(q, k, v, causal=True, window=16,
+                                 impl="flash", rotary=tables, gate=gate,
+                                 out=kernel) ** 2)
+
+    grad = jax.value_and_grad(door, argnums=(0, 1, 2, 3, 4))
+    want = grad(q, k, v, gate, kernel)
+    with _set_mesh(create_mesh({"dp": 2, "tp": 2, "sp": 2})):
+        got = jax.jit(grad)(q, k, v, gate, kernel)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5)
+
+
 @pytest.mark.parametrize("S,window,want", [
     (8192, None, (35_651_584, 33_558_528)),   # 136 tiles; S (S + 1) / 2
     (8192, 512, (5_177_344, 4_063_488)),      # 1 tile, then 640 a query
@@ -464,10 +578,11 @@ def test_ungrouped_unwindowed_call_is_the_kernel_it_was(shape):
 
 def test_grouped_unwindowed_call_is_the_kernel_it_was():
     """Laguna's full-layer kind (48 query heads over 8, head 128) at a
-    short sequence, made on PR 36's tree: a call without a window keeps
-    the kernel that computes whole tiles."""
+    short sequence, made when heads of 128 became column blocks of the
+    projections' layout (`fa.layout`): a call without a window keeps the
+    kernel that computes whole tiles, in that layout."""
     assert _older_calls_jaxpr_sha(2, 2048, 48, 128, 128, H_kv=8) == (
-        "1f0a6dc0b1bfc06d1a1afb4cb7bdda21e664ed2c2d866c470c70ea3d04a0fc20")
+        "9618afa43d0882078590b85924c8554eb89217ce62d2d0529b5687665d9c1347")
 
 
 def test_transformer_flash_impl_matches_dense():

@@ -5,8 +5,13 @@ libtpu, and XLA + Mosaic compile against it on this CPU host. Nothing
 runs — ownership of the chip, placement, numerics and HBM at run time
 are `chip_smoke.py`'s business — but this is how a session without the
 chip learns that Mosaic refuses a kernel, which interpret mode never
-shows. Slow (the train steps take about half a minute each to compile).
+shows. The flash kernel and the train steps are slow (the steps take
+about half a minute each to compile); the layout guard of laguna's
+attention runs in tier 1 (about ten seconds a layer kind).
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,13 +19,11 @@ import optax
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from horovod_tpu.models import get_model
+from horovod_tpu.models import get_model, window_moe
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.mesh import create_mesh
 from horovod_tpu.parallel.train import lm_loss, make_train_step
 from horovod_tpu.utils.compat import set_mesh
-
-pytestmark = pytest.mark.slow
 
 
 @pytest.fixture(scope="module")
@@ -35,6 +38,84 @@ def v5e_devices():
     return topo.devices
 
 
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A deviceless executable can be written to the persistent cache
+    but not read back; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# `%name = dtype[dims]{layout} opcode(` of an optimized HLO instruction.
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\{[^}]*\} ([\w\-]+)\(")
+
+
+def _layout_moves(compiled_text: str, elements: int) -> list:
+    """The top-level `copy`, `transpose` and `*bitcast_fusion` ops of a
+    compiled program whose result holds `elements` elements: a relayout
+    of an array that size through HBM (inside a fusion a `copy` is the
+    fusion's own way of reading an operand, and the kernels are custom
+    calls)."""
+    entry = compiled_text[compiled_text.index("\nENTRY"):]
+    moved = []
+    for line in entry.splitlines():
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        name, dims, opcode = found.groups()
+        if (opcode in ("copy", "transpose") or "bitcast_fusion" in name) \
+                and np.prod([int(d) for d in dims.split(",") if d]) == elements:
+            moved.append(line.strip()[:160])
+    return moved
+
+
+@pytest.mark.parametrize("kind", [window_moe.SLIDING, window_moe.FULL])
+def test_laguna_attention_lays_out_nothing_q_sized(v5e_devices,
+                                                   no_compile_cache, kind):
+    """Laguna's GatedAttention at its cell's shape (B 2, S 8192, 64 or 48
+    query heads of 128 over 8), forward and backward: q, the output, dO
+    and dQ stay in the (B, S, H * D) layout the products write from the
+    q product to the o product. Relayouts there come from any q-sized op
+    XLA runs on a 4-D view (the rotary, the gate, the kernels'
+    (B * H, S, D) transposes, a reduce over the group); k's rotary and
+    the weight gradients move eighths of it."""
+    B, S = 2, 8192
+    cfg = dataclasses.replace(window_moe.WINDOW_MOE_CONFIGS["laguna-xs2"],
+                              attn_impl="flash")
+    layer = next(layer for layer in cfg.layers if layer.attention == kind)
+    attn = window_moe.GatedAttention(cfg, layer)
+    one = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("d",)), P())
+
+    def rope():
+        return window_moe.rotary_tables(jnp.arange(S), cfg.head_dim,
+                                        cfg.rotary(kind))
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: attn.init(
+            jax.random.PRNGKey(0),
+            jnp.zeros((1, S, cfg.hidden_size), jnp.bfloat16), rope())))
+    x = jax.ShapeDtypeStruct((B, S, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one)
+
+    def loss(params, x):
+        return jnp.sum(attn.apply(params, x, rope()).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert _layout_moves(text, B * S * layer.heads * cfg.head_dim) == []
+
+
+@pytest.mark.slow
 @pytest.mark.parametrize("S,causal,padded", [
     (2048, True, False),   # 4 x 4 tiles of 512
     (4096, True, False),   # 8 x 8 tiles of 512
@@ -62,6 +143,7 @@ def test_flash_forward_backward_compiles_for_v5e(v5e_devices, S, causal,
     lowered.compile()
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("n", [1, 4])
 def test_gpt2_small_train_step_compiles_for_v5e(v5e_devices, n):
     """The step chip_smoke.py runs: gpt2-small as

@@ -105,12 +105,11 @@ def _through_the_door(monkeypatch, broken):
 
 def _full_heads_on_a_sliding_layer(door, q, k, v, **kw):
     """A sliding layer computed with the full layers' head count: its
-    last query heads never attend."""
-    out = door(q, k, v, **kw)
-    if kw["window"] is None:
-        return out
-    full = TINY.num_attention_heads_per_layer[0]
-    return out.at[:, :, full:].set(0)
+    last query heads never attend (their outputs, gated, are zeros)."""
+    if kw["window"] is not None:
+        full = TINY.num_attention_heads_per_layer[0]
+        kw["gate"] = kw["gate"].at[:, :, full:].set(0)
+    return door(q, k, v, **kw)
 
 
 def _heads_mapped_modulo(door, q, k, v, **kw):
@@ -296,6 +295,25 @@ def test_attention_key_tiles_gauge_is_set_where_the_call_is_traced():
                                                      "under_diagonal")}
     assert found == {("window", "visited"): 5, ("window", "under_diagonal"): 6,
                      ("full", "visited"): 6, ("full", "under_diagonal"): 6}
+
+
+@pytest.mark.parametrize("head_dim,layout", [(16, "bh"), (128, "columns")])
+def test_attention_layout_gauge_counts_the_calls_by_layout(head_dim, layout):
+    """Two full layers and one sliding one: heads of 16 go to the kernels
+    transposed, heads of 128 as column blocks of the projections'
+    layout."""
+    from horovod_tpu.common import telemetry
+
+    model = _model(attn_impl="flash", head_dim=head_dim)
+    ids = jnp.zeros((1, 64), jnp.int32)
+    jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))
+    found = {(kind, held): telemetry.gauge(
+        "horovod_attention_layout",
+        labels={"kind": kind, "layout": held}).value
+        for kind in ("window", "full") for held in ("columns", "bh")}
+    other = "bh" if layout == "columns" else "columns"
+    assert found == {("window", layout): 1, ("full", layout): 2,
+                     ("window", other): 0, ("full", other): 0}
 
 
 def test_attention_scores_gauge_is_set_where_the_call_is_traced():
