@@ -103,6 +103,13 @@ SCOPE_ATTN_LATENT = "hvd.attn.latent"
 #: `models/window_moe.py::GatedAttention`'s q / k / v / gate / output,
 #: the rotary positions and the gate's product); not the call.
 SCOPE_ATTN_PROJ = "hvd.attn.proj"
+#: A delta-rule linear attention's own work between its projections
+#: (`models/linear_moe.py::DeltaAttention`): the short convolutions,
+#: the query / key norms, the decay and write-strength gates, the
+#: chunked recurrence's kernels (`ops/kda.py`), the per-head output
+#: norm and its gate; forward and backward. The projections are
+#: `SCOPE_ATTN_PROJ`'s.
+SCOPE_ATTN_KDA = "hvd.attn.kda"
 #: The attention call of a sliding-window layer, forward and backward
 #: (the kernels and the layout copies around them).
 SCOPE_ATTN_WINDOW = "hvd.attn.window"

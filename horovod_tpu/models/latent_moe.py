@@ -6,15 +6,15 @@ mechanism (field names are the published `config.json` keys):
 
 * RMSNorm, no biases, a gated (SwiGLU) feed-forward, an untied head;
 * **latent attention** (`LatentAttention`): queries through a low-rank
-  path `x W_qa -> RMSNorm -> W_qb`, keys and values through a shared
-  compressed vector `x W_kva -> [c_kv | k_rope]`, `RMSNorm(c_kv) W_kvb`.
-  A head's query / key is `[nope | rope]` (128 + 64 = 192 wide), its
-  value 128: the attention kernel gets heads of two sizes
-  (`ops/flash_attention.py`). Rotary positions act on the 64-wide rope
-  slice only, adjacent pairs rotated as complex numbers
-  (`rope_interleave`), and the one `k_rope` of a position is shared by
-  all heads. Training materialises `k` and `v` per head; the absorbed
-  form that attends over the latent cache is a serving matter;
+  path `x W_qa -> RMSNorm -> W_qb` (`x W_q` where `q_lora_rank` is None),
+  keys and values through a shared compressed vector
+  `x W_kva -> [c_kv | k_rope]`, `RMSNorm(c_kv) W_kvb`. A head's query /
+  key is `[nope | rope]` (128 + 64 = 192 wide), its value 128: the
+  attention kernel gets heads of two sizes (`ops/flash_attention.py`).
+  Rotary positions, where given, turn adjacent pairs of the 64-wide rope
+  slice as complex numbers (`rope_interleave`); one `k_rope` a position
+  is shared by all heads. Training materialises `k` and `v` per head; the
+  absorbed form that attends over the latent cache is a serving matter;
 * **a routed layer told which experts it holds** (`RoutedExperts`): a
   float32 sigmoid router over ALL `n_routed_experts`, top
   `num_experts_per_tok`, gates normalised over the chosen and scaled
@@ -93,7 +93,7 @@ class LatentMoEConfig:
     hidden_size: int = 2048
     num_hidden_layers: int = 40
     num_attention_heads: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -175,10 +175,11 @@ def rope_angles(positions, dim: int, theta: float):
 
 
 def apply_rope_interleaved(x, cos, sin):
-    """Rotate adjacent pairs (x[2j], x[2j+1]) of the last axis as
-    complex numbers by the angles of `rope_angles`. x: (B, S, H, dim).
-    (The published code de-interleaves queries and keys alike before a
-    half-split rotation: the same scores, another order of the slice.)"""
+    """Rotate adjacent pairs (x[2j], x[2j+1]) of x's last axis by the
+    angles of `rope_angles` (None: no positions). The published code
+    de-interleaves q and k alike first: the same scores."""
+    if cos is None:
+        return x
     pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
     a, b = pairs[..., 0], pairs[..., 1]
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
@@ -187,13 +188,14 @@ def apply_rope_interleaved(x, cos, sin):
 
 
 class LatentAttention(nn.Module):
-    """Multi-head latent attention, training form (module docstring)."""
+    """Multi-head latent attention, training form (module docstring);
+    `rope` None leaves the rope slices as they are (no positions)."""
 
     cfg: LatentMoEConfig
 
     @nn.compact
     def __call__(self, x, rope):
-        cfg = self.cfg
+        cfg, rope = self.cfg, rope or (None, None)
         B, S, _ = x.shape
         H = cfg.num_attention_heads
         nope, rot, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -208,10 +210,8 @@ class LatentAttention(nn.Module):
                 name=name)
 
         with jax.named_scope(tracing.SCOPE_ATTN_LATENT):
-            c_q = _dense(cfg.q_lora_rank, cfg, "q_a", ("embed", "latent"),
-                         use_bias=False)(x)
-            c_q = RMSNorm(cfg, "latent", name="q_a_norm")(c_q)
-            q = heads(nope + rot, "q_b", "latent")(c_q)      # (B,S,H,192)
+            q = _latent_query(cfg, x, heads(nope + rot, "q_b", "latent"),
+                              heads(nope + rot, "q", "embed"))  # (B,S,H,192)
             kv_a = _dense(cfg.kv_lora_rank + rot, cfg, "kv_a",
                           ("embed", "latent"), use_bias=False)(x)
             c_kv = RMSNorm(cfg, "latent", name="kv_a_norm")(
@@ -488,6 +488,16 @@ class LatentMoELM(nn.Module):
                 hidden = MTPModule(cfg, name="mtp")(x, following, rope)
                 self.sow(AUX_COLLECTION, "logits", logits_of(hidden))
         return logits
+
+
+def _latent_query(cfg, x, q_b, q):
+    """A latent attention's queries: `q_b(RMSNorm(x W_qa))`, or `q(x)`
+    where `q_lora_rank` is None."""
+    if cfg.q_lora_rank is None:
+        return q(x)
+    c_q = _dense(cfg.q_lora_rank, cfg, "q_a", ("embed", "latent"),
+                 use_bias=False)(x)
+    return q_b(RMSNorm(cfg, "latent", name="q_a_norm")(c_q))
 
 
 # The published model (huggingface.co/jdopensource/JoyAI-LLM-Flash,

@@ -13,6 +13,7 @@ from typing import Any, Callable, Dict, Tuple
 import numpy as np
 
 from .latent_moe import LATENT_MOE_CONFIGS, LatentMoELM
+from .linear_moe import LINEAR_MOE_CONFIGS, LinearMoELM
 from .mnist import MnistCNN, MnistMLP
 from .resnet import RESNET_CONFIGS
 from .transformer import (
@@ -86,6 +87,13 @@ def _registry() -> Dict[str, ModelSpec]:
         reg[name] = ModelSpec(
             name,
             (lambda c: (lambda **kw: WindowMoELM(
+                dataclasses.replace(c, **kw) if kw else c)))(cfg),
+            _token_batch(512, cfg.vocab_size), "lm",
+        )
+    for name, cfg in LINEAR_MOE_CONFIGS.items():
+        reg[name] = ModelSpec(
+            name,
+            (lambda c: (lambda **kw: LinearMoELM(
                 dataclasses.replace(c, **kw) if kw else c)))(cfg),
             _token_batch(512, cfg.vocab_size), "lm",
         )

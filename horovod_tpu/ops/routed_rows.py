@@ -86,8 +86,8 @@ def row_tiles(held_rows):
 
 def _lane_split(width: int) -> tuple:
     """(groups, lanes): a row of `width` as whole lane tiles. (Compiled
-    for the chip, a row's DMA wants whole packed tiles: `groups` a
-    multiple of 2 for bf16 rows. Narrower rows run interpreted only.)"""
+    for the chip, a row's DMA wants whole (8, 128) tiles: `_landing`
+    pads the groups. Narrower rows run interpreted only.)"""
     lanes = LANES if width % LANES == 0 else width
     return width // lanes, lanes
 
@@ -215,13 +215,13 @@ def _row_block(width: int):
 
 def _as_row_tiles(x):
     groups, lanes = _lane_split(x.shape[1])
-    return x.reshape(x.shape[0], groups, lanes)
+    return _whole_groups(x.reshape(x.shape[0], groups, lanes))
 
 
 def _gather_kernel(held_ref, token_ref, source_ref, out_ref, landing,
                    semaphore):
     _fetch_rows(token_ref, held_ref, source_ref, landing, semaphore)
-    groups, lanes = landing.shape[1:]
+    groups, lanes = out_ref.shape[1] // landing.shape[2], landing.shape[2]
     for c in range(groups):
         out_ref[:, c * lanes:(c + 1) * lanes] = landing[:, c, :]
 
@@ -239,7 +239,7 @@ def _gather_rows(held_rows, token, source):
                 in_specs=[_TOKENS_SPEC, pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=_row_block(width),
                 scratch_shapes=[
-                    pltpu.VMEM((ROW_TILE, *_lane_split(width)), source.dtype),
+                    pltpu.VMEM((ROW_TILE, *_landing(width)), source.dtype),
                     pltpu.SemaphoreType.DMA(())]),
             out_shape=jax.ShapeDtypeStruct(
                 (token.shape[0], width), source.dtype),
@@ -261,7 +261,7 @@ def _turn(vector, to_column: bool):
 def _combine_bwd_kernel(held_ref, token_ref, g_ref, y_ref, gate_ref,
                         d_y_ref, d_gate_ref, landing, semaphore):
     _fetch_rows(token_ref, held_ref, g_ref, landing, semaphore)
-    groups, lanes = landing.shape[1:]
+    groups, lanes = y_ref.shape[1] // landing.shape[2], landing.shape[2]
     dtype = d_y_ref.dtype
     # Gates and their gradients travel a row of 128 to a sublane: this
     # tile's are sublanes `first` onward of a block of ROW_GRANULE rows.
@@ -300,7 +300,7 @@ def _combine_bwd_rows(held_rows, token, gate, g, y):
                           _row_block(width), by_lane],
                 out_specs=[_row_block(width), by_lane],
                 scratch_shapes=[
-                    pltpu.VMEM((ROW_TILE, *_lane_split(width)), jnp.float32),
+                    pltpu.VMEM((ROW_TILE, *_landing(width)), jnp.float32),
                     pltpu.SemaphoreType.DMA(())]),
             out_shape=[jax.ShapeDtypeStruct((rows, width), y.dtype),
                        jax.ShapeDtypeStruct((rows // LANES, LANES),
@@ -521,3 +521,23 @@ def _gated_activation_bwd(residuals, g):
 
 
 gated_activation.defvjp(_gated_activation_fwd, _gated_activation_bwd)
+
+
+def _landing(width: int) -> tuple:
+    """(groups, lanes) of a token's row as the buffer-major kernels land
+    it: the lane tiles of `_lane_split` padded to a multiple of 8, the
+    rows of a whole (8, 128) tile, which a DMA of one row needs compiled
+    for the chip (a width of 2304 is 18 lane tiles, landed as 24; 2048
+    is 16 already). Narrower rows run interpreted only and stay as they
+    are."""
+    groups, lanes = _lane_split(width)
+    return (-(-groups // 8) * 8 if lanes == LANES else groups), lanes
+
+
+def _whole_groups(x):
+    """A token-sized source (T, groups, lanes) padded with zeros to the
+    groups `_landing` gives; as it is where it has them already."""
+    groups = _landing(x.shape[1] * x.shape[2])[0]
+    if groups == x.shape[1]:
+        return x
+    return jnp.pad(x, ((0, 0), (0, groups - x.shape[1]), (0, 0)))

@@ -10,6 +10,7 @@ step's second loss term and the scopes in the lowered step."""
 import collections
 import dataclasses
 import functools
+import hashlib
 import json
 import pathlib
 import re
@@ -374,6 +375,43 @@ def test_the_kernels_equal_the_formulas_they_replaced(case, dtype):
     assert d_y.dtype == dtype and d_gates.dtype == jnp.float32
     np.testing.assert_allclose(as_f32(d_y[:held_rows]),
                                as_f32(want_y[:held_rows]), **exact)
+    np.testing.assert_allclose(np.asarray(d_gates), np.asarray(want_gates),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_a_row_of_18_lane_tiles_moves_whole():
+    """A width of 2304 (Kimi-Linear's hidden size: 18 lane tiles, landed
+    as 24 for the chip's DMA of a row, `routed_rows._landing`): into the
+    buffer, back to the tokens, and the gradients of both, the plain
+    gathers' values on the rows that hold a pair."""
+    width = 18 * routed_rows.LANES
+    assert routed_rows._landing(width) == (24, routed_rows.LANES)
+    assert routed_rows._landing(2048) == (16, routed_rows.LANES)
+    key, gates, routing, held_rows = _routing("ragged")
+    pairs, rows = len(key), routed_rows.padded_rows(len(key))
+    order = np.argsort(key, kind="stable")
+    place, here = np.argsort(order), key < ROUTED_HELD
+    rng = np.random.default_rng(2)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape),
+                                        jnp.bfloat16)
+    tokens = normal(ROUTED_T, width)
+    buffer, pull = jax.vjp(lambda t: routed_rows.dispatch(t, routing), tokens)
+    np.testing.assert_array_equal(
+        np.asarray(buffer[:held_rows], np.float32),
+        np.asarray(_plain_dispatch(tokens, order)[:held_rows], np.float32))
+    y = _poisoned(normal(rows, width), held_rows)
+    out, pull = jax.vjp(lambda y, gates: routed_rows.combine(
+        y, gates, routing), y, gates)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_plain_combine(y[:pairs], gates, place,
+                                                   here)), rtol=1e-5,
+        atol=1e-5)
+    g = jnp.asarray(rng.standard_normal((ROUTED_T, width)), jnp.float32)
+    d_y, d_gates = pull(g)
+    want_y, want_gates = _plain_combine_bwd(y[:pairs], gates, order, place,
+                                            here, g)
+    np.testing.assert_array_equal(np.asarray(d_y[:held_rows], np.float32),
+                                  np.asarray(want_y[:held_rows], np.float32))
     np.testing.assert_allclose(np.asarray(d_gates), np.asarray(want_gates),
                                rtol=1e-4, atol=1e-4)
 
@@ -763,3 +801,53 @@ def test_the_registry_entry_is_the_published_configuration():
     assert published.vocab_size == config["published"]["vocab_size"]
     assert published.qk_head_dim == config["qk_head_dim"] == 192
     assert published.held == 256 and published.expert_share == 0
+
+
+# ---------------------------------------------- the latent attention's options
+
+# sha256 of the lowered text of the tiny model's recomputed gradient, as
+# the tree lowered it before `LatentAttention` took a query projection
+# without low rank (`q_lora_rank` None) and `rope` None.
+LOWERED_BEFORE_THE_OPTIONS = {
+    "dense": "2cac1028bf9bbbfb9732710a73678e21"
+             "edb13ab51d529d8d6e63923d1d547e2e",
+    "flash": "6d750191627b1272d34778dd265cae48"
+             "4a70bc929f76d32b86700927f942b4e6",
+}
+
+
+@pytest.mark.parametrize("impl", sorted(LOWERED_BEFORE_THE_OPTIONS))
+def test_the_options_leave_the_low_rank_rotary_attention_as_it_lowered(impl):
+    """A configuration with a query low rank and rotary positions (this
+    family's) lowers to the text it lowered to before the options."""
+    model = _model(attn_impl=impl, remat=True)
+    ids = jnp.zeros((1, SEQ), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids)["params"])
+
+    def loss(p, ids):
+        return jnp.sum(model.apply({"params": p}, ids).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss)).lower(params, ids).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        LOWERED_BEFORE_THE_OPTIONS[impl]
+
+
+def test_without_low_rank_or_positions_the_query_is_one_product():
+    """`q_lora_rank` None: one projection `q` (no `q_a`, `q_a_norm`,
+    `q_b`); `rope` None: the shared key slice is `x W_kva`'s own, the
+    same at every position for the same input."""
+    cfg = dataclasses.replace(TINY, q_lora_rank=None, dtype=jnp.float32)
+    x = jnp.tile(jax.random.normal(jax.random.PRNGKey(0), (1, 1, 64)),
+                 (1, 8, 1))
+    params = nn.unbox(latent_moe.LatentAttention(cfg).init(
+        jax.random.PRNGKey(1), x, None))["params"]
+    assert set(params) == {"q", "kv_a", "kv_a_norm", "kv_b", "o"}
+    assert params["q"]["kernel"].shape == (64, 2, 24)
+    out = latent_moe.LatentAttention(cfg).apply({"params": params}, x, None)
+    # Identical inputs at every position and no positions: causal
+    # attention over equal keys and values gives equal outputs.
+    np.testing.assert_allclose(np.asarray(out[0, 1:]),
+                               np.asarray(jnp.broadcast_to(out[0, :1],
+                                                           (7, 64))),
+                               rtol=1e-5, atol=1e-6)

@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.models import get_model, window_moe
+from horovod_tpu.ops import kda
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.mesh import create_mesh
 from horovod_tpu.parallel.train import lm_loss, make_train_step
@@ -113,6 +114,56 @@ def test_laguna_attention_lays_out_nothing_q_sized(v5e_devices,
     text = compiled.as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
     assert _layout_moves(text, B * S * layer.heads * cfg.head_dim) == []
+
+
+def test_kda_kernels_compile_for_v5e_at_the_cell_shape(v5e_devices,
+                                                      no_compile_cache):
+    """The delta rule's two kernels at Kimi-Linear's cell shape (B 2,
+    S 8192, 32 heads of 128, the output normed and gated), forward and
+    backward: Mosaic takes them, and what the call keeps beside its
+    arguments is the chunk-start states (0.54 GB) and the gradients, not
+    chunk-sized intermediates of every chunk."""
+    B, S, H, D = 2, 8192, 32, 128
+    one = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("d",)), P())
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    args = [shape(B, S, H * D)] * 3 + [
+        shape(B, S, H * D, dtype=jnp.float32),
+        shape(B, S, H, dtype=jnp.float32), shape(B, S, H * D),
+        shape(D, dtype=jnp.float32)]
+
+    def loss(q, k, v, g, beta, gate, scale):
+        return jnp.sum(kda.kda(q, k, v, g, beta, gate=gate, scale=scale)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(7))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < 2.5e9, temporaries
+
+
+def test_the_delta_rule_leaves_no_loop_in_the_step(v5e_devices,
+                                                   no_compile_cache):
+    """A step of delta-rule layers (dense feed-forwards, so that nothing
+    else in it chooses or routes) compiled for a v5e at a small shape the
+    kernels take: the recurrence runs in its kernels, and the optimized
+    program holds no `while` (what a `scan` or a `fori_loop` becomes) and
+    no `conditional`, which the benchmark's region reader refuses."""
+    from benchmark.trainers import gspmd
+
+    model = get_model("kimi-linear-48b-a3b").make_model(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=2, first_k_dense_replace=2,
+        linear_attn_config={"full_attn_layers": [], "kda_layers": [1, 2],
+                            "head_dim": 128, "num_heads": 2,
+                            "short_conv_kernel_size": 4}, remat=True)
+    text = gspmd.lower(model, {"seq": 256, "batch_per_chip": 2,
+                               "mesh": {"dp": 1}}, v5e_devices[:1]
+                       ).compile().as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    assert re.findall(r" (while|conditional)\(", text) == []
 
 
 @pytest.mark.slow
