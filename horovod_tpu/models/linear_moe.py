@@ -77,6 +77,8 @@ _STATE_HELP = ("Bytes of float32 states of a delta-rule attention's kernel "
                "call at the shape traced (what: carried, one a batch row and "
                "head, chunk to chunk; stored, every chunk's starting state, "
                "written for the backward pass)")
+_HEADS_HELP = ("Heads one program of a delta-rule attention's kernels "
+               "takes, at the head count and widths traced")
 
 # The initial ranges of the decay's parameters (the family's convention,
 # as Mamba's): exp(A_log) uniform in [1, 16] a head; softplus(dt_bias)
@@ -244,6 +246,8 @@ class DeltaAttention(nn.Module):
         width = H * D
         chunks = kda.chunks_of(S)
         telemetry.gauge("horovod_kda_chunks", _CHUNKS_HELP).set(chunks)
+        telemetry.gauge("horovod_kda_heads_per_program", _HEADS_HELP).set(
+            kda.heads_per_program(H, D, D))
         carried = B * kda.state_bytes(H, D, D)
         for what, n in (("carried", carried), ("stored", carried * chunks)):
             telemetry.gauge("horovod_kda_state_bytes", _STATE_HELP,

@@ -20,9 +20,13 @@ the chunk starts from, K^ = K e^Gamma, Q^ = Q e^Gamma / sqrt(Dk):
     O = Q^ S_0 + P U~
     S_C = e^Gamma_C * S_0 + (K e^(Gamma_C - Gamma))^T U~
 
-**The kernels.** One program takes one (batch row, head, chunk); the
-grid runs the chunks innermost and in order, and the state, held
-transposed (Dv, Dk) so that the decay scales its columns, stays in VMEM
+**The kernels.** One program takes Hb heads of one (batch row, chunk),
+Hb = `heads_per_program(...)`: the heads are independent, state and all,
+so each quantity below is a (Hb, ...) stack and each product a batched
+one, a head's arithmetic the same op for op as alone, and the scheduler
+has Hb chains to fill the slots one head's dependent chain leaves empty.
+The grid runs the chunks innermost and in order, and the states, held
+transposed (Dv, Dk) so that the decay scales their columns, stay in VMEM
 scratch from one chunk to the next (`_forward`). The forward kernel
 also writes the state each chunk starts from, in float32 (0.54 GB a
 layer at 8192 positions, 2 rows and 32 heads of 128: the backward pass
@@ -78,9 +82,16 @@ CHUNK = 64
 SUB = 16
 L2_EPS = 1e-6
 
-_NN = ((1,), (0,))      # a b
-_TN = ((0,), (0,))      # a^T b
-_NT = ((1,), (1,))      # a b^T
+# The most heads one program takes: the largest count timed on a v5e
+# that paid (PERF.md); the VMEM the kernels ask for (the v5e's default
+# is 16 MiB, which the backward outgrows at 4 heads).
+HEADS_PER_PROGRAM = 4
+VMEM_LIMIT = 32 * 2 ** 20
+
+# Contractions of two (heads, m, n) stacks, one product a head.
+_NN = ((2,), (1,))      # a b
+_TN = ((1,), (1,))      # a^T b
+_NT = ((2,), (2,))      # a b^T
 
 
 def chunks_of(seq: int, chunk: int = CHUNK) -> int:
@@ -96,8 +107,27 @@ def state_bytes(heads: int, key_dim: int, value_dim: int) -> int:
     return heads * key_dim * value_dim * 4
 
 
+def heads_per_program(heads: int, key_dim: int, value_dim: int) -> int:
+    """Heads one program of the kernels takes: the most, up to
+    HEADS_PER_PROGRAM, that divide `heads` and whose blocks, states and
+    temporaries fit VMEM_LIMIT (`_vmem_bytes`)."""
+    for n in range(min(heads, HEADS_PER_PROGRAM), 1, -1):
+        if heads % n == 0 and _vmem_bytes(n, key_dim, value_dim) \
+                <= VMEM_LIMIT:
+            return n
+    return 1
+
+
+def _vmem_bytes(heads: int, key_dim: int, value_dim: int) -> int:
+    """VMEM the backward kernel, the larger, holds at `heads` a program:
+    168 float32 (CHUNK, D) arrays a head. Mosaic's compile for a v5e at
+    chunks of 64 and heads of 128 took 5.25, 8.72, 19.88 and 41.25 MiB
+    at 1, 2, 4 and 8 heads (the forward 1.78-16.69)."""
+    return heads * 168 * CHUNK * max(key_dim, value_dim) * 4
+
+
 def _dot(a, b, contract=_NN):
-    return jax.lax.dot_general(a, b, (contract, ((), ())),
+    return jax.lax.dot_general(a, b, (contract, ((0,), (0,))),
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
@@ -111,23 +141,25 @@ def _eye(n):
 
 
 def _neumann(a, order: int):
-    """(I + a)^-1 of a matrix with a^order = 0, order a power of two:
-    (I - a)(I + a^2)(I + a^4)... (I + a^(order / 2))."""
-    t, power = _eye(a.shape[0]) - a, a
+    """(I + a)^-1 of a stack of matrices with a^order = 0, order a power
+    of two: (I - a)(I + a^2)(I + a^4)... (I + a^(order / 2))."""
+    t, power = _eye(a.shape[-1]) - a, a
     for _ in range(int(math.log2(order)) - 1):
         power = _dot(power, power)
         t = t + _dot(t, power)
     return t
 
 
-# ----------------------------------------------------- one chunk of a head
+# --------------------------------------------------- one chunk of Hb heads
 
 class _Chunk:
-    """The chunk's quantities, from float32 q, k, g (C, Dk), v (C, Dv),
-    beta (C, 1) (module docstring; `qs` is q / sqrt(Dk))."""
+    """The chunk's quantities, a stack of Hb heads each, from float32 q,
+    k, g (Hb, C, Dk), v (Hb, C, Dv), beta (Hb, C, 1) (module docstring;
+    `qs` is q / sqrt(Dk)). Masks are (C, C) or (C, 1), broadcast over
+    the heads."""
 
     def __init__(self, q, k, v, g, beta, sub: int):
-        C, Dk = k.shape
+        n, C, Dk = k.shape
         self.sub, self.k, self.v, self.beta = sub, k, v, beta
         self.qs = q * (1.0 / math.sqrt(Dk))
         self.rows = _iota((C, 1), 0)
@@ -135,8 +167,9 @@ class _Chunk:
         self.lower = (i >= j).astype(jnp.float32)
         self.strict = (i > j).astype(jnp.float32)
         self.same = (i // sub == j // sub)
-        self.G = _dot(self.lower, g)                          # Gamma
-        self.last = self.G[C - 1:C, :]                        # (1, Dk)
+        self.stacked_lower = jnp.broadcast_to(self.lower, (n, C, C))
+        self.G = _dot(self.stacked_lower, g)                  # Gamma
+        self.last = self.G[:, C - 1:C, :]                     # (Hb, 1, Dk)
         self.E = jnp.exp(self.G)
         self.qh, self.kh = self.qs * self.E, k * self.E
         self.tail = jnp.exp(self.last - self.G)
@@ -156,7 +189,7 @@ class _Chunk:
     def anchored(self, a: int):
         """(down, up): e^(Gamma_i - r) on sub-chunk a's rows and
         e^(r - Gamma_j) on the rows before it, zeros elsewhere."""
-        r = self.G[a * self.sub:a * self.sub + 1, :]
+        r = self.G[:, a * self.sub:a * self.sub + 1, :]
         block = self.rows // self.sub
         down = jnp.where(block == a,
                          jnp.exp(jnp.minimum(self.G - r, 0.0)), 0.0)
@@ -169,8 +202,8 @@ class _Chunk:
         sub-chunk that have a row delta above them in it (else 0), and
         the (C, C) mask of the diagonal i - j = delta within
         sub-chunks."""
-        C = self.G.shape[0]
-        shifted = pltpu.roll(self.G, delta, 0) if delta else self.G
+        C = self.G.shape[1]
+        shifted = pltpu.roll(self.G, delta, 1) if delta else self.G
         e = jnp.where(self.rows % self.sub >= delta,
                       jnp.exp(jnp.minimum(self.G - shifted, 0.0)), 0.0)
         i, j = _iota((C, C), 0), _iota((C, C), 1)
@@ -178,12 +211,12 @@ class _Chunk:
         return e, on
 
     def shifted_k(self, delta: int):
-        return pltpu.roll(self.k, delta, 0) if delta else self.k
+        return pltpu.roll(self.k, delta, 1) if delta else self.k
 
     def scores(self):
-        """P (i >= j) and A before beta (i > j), (C, C) each."""
-        C = self.k.shape[0]
-        P = A = jnp.zeros((C, C), jnp.float32)
+        """P (i >= j) and A before beta (i > j), (Hb, C, C) each."""
+        n, C, _ = self.k.shape
+        P = A = jnp.zeros((n, C, C), jnp.float32)
         for a in range(1, C // self.sub):
             down, up = self.anchored(a)
             keys = self.k * up
@@ -192,25 +225,25 @@ class _Chunk:
         for delta in range(self.sub):
             e, on = self.diagonal(delta)
             kd = self.shifted_k(delta) * e
-            P = P + jnp.sum(self.qs * kd, axis=1, keepdims=True) * on
+            P = P + jnp.sum(self.qs * kd, axis=-1, keepdims=True) * on
             if delta:
-                A = A + jnp.sum(self.k * kd, axis=1, keepdims=True) * on
+                A = A + jnp.sum(self.k * kd, axis=-1, keepdims=True) * on
         return P, A
 
     def step(self, st):
-        """(O, the state after the chunk, U~) from the transposed state
-        st (Dv, Dk) the chunk starts from."""
+        """(O, the states after the chunk, U~) from the transposed states
+        st (Hb, Dv, Dk) the chunk starts from."""
         u = self.U - _dot(self.W, st, _NT)
         o = _dot(self.qh, st, _NT) + _dot(self.P, u)
         return o, st * self.gamma + _dot(u, self.kb, _TN), u
 
     def backward(self, st, u, d_o, d_after):
         """(dq, dk, dv, dg, dbeta, d_st): the gradients of the chunk's
-        inputs and of the state it starts from, given those of its
-        output and of the state after it (both transposed)."""
-        C = self.k.shape[0]
+        inputs and of the states it starts from, given those of its
+        output and of the states after it (both transposed)."""
+        C = self.k.shape[1]
         # The state's update and the output.
-        d_gamma = jnp.sum(st * d_after, axis=0, keepdims=True)
+        d_gamma = jnp.sum(st * d_after, axis=1, keepdims=True)
         d_kb = _dot(u, d_after)
         d_u = _dot(self.kb, d_after, _NT) + _dot(self.P, d_o, _TN)
         d_qh = _dot(d_o, st)
@@ -225,9 +258,9 @@ class _Chunk:
                 + _dot(d_rk, self.W, _NT)) * self.strict
         d_v = self.beta * d_rv
         d_kh = self.beta * d_rk
-        d_beta = (jnp.sum(d_rv * self.v + d_rk * self.kh, axis=1,
+        d_beta = (jnp.sum(d_rv * self.v + d_rk * self.kh, axis=-1,
                           keepdims=True)
-                  + jnp.sum(d_A * self.A0, axis=1, keepdims=True))
+                  + jnp.sum(d_A * self.A0, axis=-1, keepdims=True))
         d_A = self.beta * d_A
         # The scores; Gamma enters a score as + Gamma_i - Gamma_j, so its
         # gradient is x * dx - k * dk_column (row side x = qs or k).
@@ -242,12 +275,12 @@ class _Chunk:
         for delta in range(self.sub):
             e, on = self.diagonal(delta)
             kd = self.shifted_k(delta) * e
-            dp = jnp.sum(d_P * on, axis=1, keepdims=True)
-            da = jnp.sum(d_A * on, axis=1, keepdims=True)
+            dp = jnp.sum(d_P * on, axis=-1, keepdims=True)
+            da = jnp.sum(d_A * on, axis=-1, keepdims=True)
             d_qs = d_qs + dp * kd
             d_k_row = d_k_row + da * kd
             back = (dp * self.qs + da * self.k) * e
-            d_k_col = d_k_col + (pltpu.roll(back, C - delta, 0) if delta
+            d_k_col = d_k_col + (pltpu.roll(back, C - delta, 1) if delta
                                  else back)
         d_G = self.qs * d_qs + self.k * (d_k_row - d_k_col)
         d_k = d_k_row + d_k_col
@@ -256,11 +289,11 @@ class _Chunk:
         d_qs = d_qs + d_qh * self.E
         d_k = d_k + d_kh * self.E + d_kb * self.tail
         d_G = d_G + d_qh * self.qh + d_kh * self.kh - d_kb * self.kb
-        d_last = (jnp.sum(d_kb * self.kb, axis=0, keepdims=True)
+        d_last = (jnp.sum(d_kb * self.kb, axis=1, keepdims=True)
                   + d_gamma * self.gamma)
         d_G = d_G + jnp.where(self.rows == C - 1, d_last, 0.0)
-        d_g = _dot(self.lower, d_G, _TN)
-        d_q = d_qs * (1.0 / math.sqrt(self.k.shape[1]))
+        d_g = _dot(self.stacked_lower, d_G, _TN)
+        d_q = d_qs * (1.0 / math.sqrt(self.k.shape[2]))
         return d_q, d_k, d_v, d_g, d_beta, d_st
 
 
@@ -268,43 +301,59 @@ class _Chunk:
 
 def _unit(x):
     """(x / |x|, 1 / |x|) of each row, |x| = sqrt(sum x^2 + L2_EPS)."""
-    r = jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + L2_EPS)
+    r = jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
     return x * r, r
 
 
 def _unit_bwd(unit, r, d_unit):
     """The gradient of a row before `_unit` from that of the unit row."""
-    return r * (d_unit - unit * jnp.sum(d_unit * unit, axis=1, keepdims=True))
+    return r * (d_unit - unit * jnp.sum(d_unit * unit, axis=-1,
+                                        keepdims=True))
 
 
 def _gated_norm(o, gate, scale, eps):
     """(RMSNorm(o) * scale * sigmoid(gate), the normed rows, the
     reciprocal RMS, sigmoid(gate)): the norm over each row of a head."""
-    r = jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
+    r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
     normed, sig = o * r, jax.nn.sigmoid(gate)
     return normed * scale * sig, normed, r, sig
 
 
 def _gated_norm_bwd(normed, r, sig, scale, d_y):
-    """(dO, dgate, dscale summed over the rows) from dY."""
+    """(dO, dgate, dscale summed over each head's rows) from dY."""
     d_normed = d_y * scale * sig
-    d_o = r * (d_normed - normed * jnp.mean(d_normed * normed, axis=1,
+    d_o = r * (d_normed - normed * jnp.mean(d_normed * normed, axis=-1,
                                             keepdims=True))
     d_gate = d_y * scale * normed * sig * (1.0 - sig)
-    return d_o, d_gate, jnp.sum(d_y * normed * sig, axis=0, keepdims=True)
+    return d_o, d_gate, jnp.sum(d_y * normed * sig, axis=1, keepdims=True)
 
 
 # -------------------------------------------------------------- the kernels
+
+def _heads(ref, heads: int):
+    """The (heads, C, D) float32 stack of a (1, C, heads * D) column
+    block's heads."""
+    D = ref.shape[2] // heads
+    return jnp.stack([ref[0, :, h * D:(h + 1) * D].astype(jnp.float32)
+                      for h in range(heads)])
+
+
+def _store(ref, x):
+    """Writes the (Hb, C, D) stack x to its (1, C, Hb * D) column block."""
+    D = x.shape[2]
+    for h in range(x.shape[0]):
+        ref[0, :, h * D:(h + 1) * D] = x[h].astype(ref.dtype)
+
 
 def _load(refs, sub):
     """The chunk of q, k (L2-normed here), v, g, beta from their refs:
     (chunk, q / |q|, 1 / |q|, k / |k|, 1 / |k|)."""
     q_ref, k_ref, v_ref, g_ref, beta_ref = refs
-    f32 = lambda ref: ref[0].astype(jnp.float32)
-    q, rq = _unit(f32(q_ref))
-    k, rk = _unit(f32(k_ref))
-    return _Chunk(q, k, f32(v_ref), f32(g_ref), beta_ref[0, 0], sub), \
-        q, rq, k, rk
+    heads = beta_ref.shape[1]
+    q, rq = _unit(_heads(q_ref, heads))
+    k, rk = _unit(_heads(k_ref, heads))
+    return _Chunk(q, k, _heads(v_ref, heads), _heads(g_ref, heads),
+                  beta_ref[0], sub), q, rq, k, rk
 
 
 def _forward_kernel(*refs, sub, gated, eps):
@@ -319,12 +368,12 @@ def _forward_kernel(*refs, sub, gated, eps):
 
     chunk = _load(inputs, sub)[0]
     st = st_ref[...]
-    start_ref[0, 0, 0] = st
+    start_ref[0, :, 0] = st
     o, st_ref[...], _ = chunk.step(st)
     if gated:
-        o = _gated_norm(o, gate_ref[0].astype(jnp.float32), scale_ref[...],
+        o = _gated_norm(o, _heads(gate_ref, o.shape[0]), scale_ref[...],
                         eps)[0]
-    o_ref[0] = o.astype(o_ref.dtype)
+    _store(o_ref, o)
 
 
 def _backward_kernel(*refs, sub, gated, eps):
@@ -340,57 +389,64 @@ def _backward_kernel(*refs, sub, gated, eps):
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
     chunk, q, rq, k, rk = _load(inputs, sub)
-    st = start_ref[0, 0, 0]
+    heads = q.shape[0]
+    st = start_ref[0, :, 0]
     o, _, u = chunk.step(st)
-    d_o = dy_ref[0].astype(jnp.float32)
+    d_o = _heads(dy_ref, heads)
     if gated:
         scale = scale_ref[...]
-        _, normed, r, sig = _gated_norm(o, gate_ref[0].astype(jnp.float32),
-                                        scale, eps)
-        d_o, d_gate, dscale_ref[0, 0, 0] = _gated_norm_bwd(
+        _, normed, r, sig = _gated_norm(o, _heads(gate_ref, heads), scale,
+                                        eps)
+        d_o, d_gate, dscale_ref[0, :, 0] = _gated_norm_bwd(
             normed, r, sig, scale, d_o)
-        dgate_ref[0] = d_gate.astype(dgate_ref.dtype)
+        _store(dgate_ref, d_gate)
     d_q, d_k, d_v, d_g, d_beta, ds_ref[...] = chunk.backward(
         st, u, d_o, ds_ref[...])
-    dq_ref[0] = _unit_bwd(q, rq, d_q).astype(dq_ref.dtype)
-    dk_ref[0] = _unit_bwd(k, rk, d_k).astype(dk_ref.dtype)
-    dv_ref[0] = d_v.astype(dv_ref.dtype)
-    dg_ref[0] = d_g
-    dbeta_ref[0, 0] = d_beta
+    _store(dq_ref, _unit_bwd(q, rq, d_q))
+    _store(dk_ref, _unit_bwd(k, rk, d_k))
+    _store(dv_ref, d_v)
+    _store(dg_ref, d_g)
+    dbeta_ref[0] = d_beta
 
 
-def _specs(C, Dk, Dv, N, reverse: bool):
+def _specs(C, Dk, Dv, N, heads, reverse: bool):
     """Blocks at grid point (b, h, n), the chunks backwards where
-    `reverse`: of one (batch row, head, chunk) of a (B, S, H * D) array
-    at D = Dk and Dv, of beta (B, H, S, 1), of the states (B, H, N, Dv,
-    Dk) and of the per-chunk sums (B, H, N, 1, Dv); the scale (1, Dv)
-    whole."""
+    `reverse`: of the h-th `heads` heads of one (batch row, chunk) of a
+    (B, S, H * D) array at D = Dk and Dv, of beta (B, H, S, 1), of the
+    states (B, H, N, Dv, Dk) and of the per-chunk sums (B, H, N, 1, Dv);
+    the scale (1, Dv) whole."""
     at = (lambda n: N - 1 - n) if reverse else (lambda n: n)
-    columns = lambda D: pl.BlockSpec((1, C, D), lambda b, h, n: (b, at(n), h))
+    columns = lambda D: pl.BlockSpec((1, C, heads * D),
+                                     lambda b, h, n: (b, at(n), h))
     return types.SimpleNamespace(
         keys=columns(Dk), values=columns(Dv),
-        rows=pl.BlockSpec((1, 1, C, 1), lambda b, h, n: (b, h, at(n), 0)),
-        states=pl.BlockSpec((1, 1, 1, Dv, Dk),
+        rows=pl.BlockSpec((1, heads, C, 1),
+                          lambda b, h, n: (b, h, at(n), 0)),
+        states=pl.BlockSpec((1, heads, 1, Dv, Dk),
                             lambda b, h, n: (b, h, at(n), 0, 0)),
-        sums=pl.BlockSpec((1, 1, 1, 1, Dv),
+        sums=pl.BlockSpec((1, heads, 1, 1, Dv),
                           lambda b, h, n: (b, h, at(n), 0, 0)),
         scale=pl.BlockSpec((1, Dv), lambda b, h, n: (0, 0)))
 
 
 def _params():
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("chunk", "eps", "interpret"))
-def _forward(q, k, v, g, beta, gate, scale, *, chunk, eps, interpret=None):
+                   static_argnames=("chunk", "eps", "heads", "interpret"))
+def _forward(q, k, v, g, beta, gate, scale, *, chunk, eps, heads=None,
+             interpret=None):
     """(the output (B, S, H * Dv) and the states each chunk starts from
     (B, H, N, Dv, Dk)), the first in v's dtype, the states in float32.
-    `gate` and `scale` None: o."""
+    `gate` and `scale` None: o. `heads` a program, a divisor of H:
+    `heads_per_program`'s where None."""
     B, H, S = beta.shape[:3]
     Dk, Dv, N = q.shape[-1] // H, v.shape[-1] // H, S // chunk
-    spec = _specs(chunk, Dk, Dv, N, False)
+    heads = heads or heads_per_program(H, Dk, Dv)
+    spec = _specs(chunk, Dk, Dv, N, heads, False)
     gated = gate is not None
     extra = [gate, scale.reshape(1, Dv)] if gated else []
 
@@ -398,7 +454,7 @@ def _forward(q, k, v, g, beta, gate, scale, *, chunk, eps, interpret=None):
         return pl.pallas_call(
             functools.partial(_forward_kernel, sub=min(SUB, chunk),
                               gated=gated, eps=eps),
-            grid=(B, H, N),
+            grid=(B, H // heads, N),
             in_specs=[spec.keys, spec.keys, spec.values, spec.keys,
                       spec.rows] + ([spec.values, spec.scale] if gated
                                     else []),
@@ -406,7 +462,7 @@ def _forward(q, k, v, g, beta, gate, scale, *, chunk, eps, interpret=None):
             out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                        jax.ShapeDtypeStruct((B, H, N, Dv, Dk),
                                             jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((Dv, Dk), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((heads, Dv, Dk), jnp.float32)],
             compiler_params=_params(), interpret=interp, name="kda_fwd")
 
     return call_by_platform(call, q, k, v, g, beta, *extra,
@@ -414,14 +470,15 @@ def _forward(q, k, v, g, beta, gate, scale, *, chunk, eps, interpret=None):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("chunk", "eps", "interpret"))
+                   static_argnames=("chunk", "eps", "heads", "interpret"))
 def _backward(q, k, v, g, beta, gate, scale, starts, d_y, *, chunk, eps,
-              interpret=None):
+              heads=None, interpret=None):
     """The gradients of q, k, v, g, beta (and gate, scale), in their
-    shapes and dtypes."""
+    shapes and dtypes; `heads` as `_forward`'s."""
     B, H, S = beta.shape[:3]
     Dk, Dv, N = q.shape[-1] // H, v.shape[-1] // H, S // chunk
-    spec = _specs(chunk, Dk, Dv, N, True)
+    heads = heads or heads_per_program(H, Dk, Dv)
+    spec = _specs(chunk, Dk, Dv, N, heads, True)
     gated = gate is not None
     extra = [gate, scale.reshape(1, Dv)] if gated else []
     grads = [q, k, v, g, beta] + ([gate] if gated else [])
@@ -430,7 +487,7 @@ def _backward(q, k, v, g, beta, gate, scale, starts, d_y, *, chunk, eps,
         return pl.pallas_call(
             functools.partial(_backward_kernel, sub=min(SUB, chunk),
                               gated=gated, eps=eps),
-            grid=(B, H, N),
+            grid=(B, H // heads, N),
             in_specs=[spec.keys, spec.keys, spec.values, spec.keys,
                       spec.rows] + ([spec.values, spec.scale] if gated
                                     else []) + [spec.states, spec.values],
@@ -441,7 +498,7 @@ def _backward(q, k, v, g, beta, gate, scale, starts, d_y, *, chunk, eps,
                        for x in grads] + (
                 [jax.ShapeDtypeStruct((B, H, N, 1, Dv), jnp.float32)]
                 if gated else []),
-            scratch_shapes=[pltpu.VMEM((Dv, Dk), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((heads, Dv, Dk), jnp.float32)],
             compiler_params=_params(), interpret=interp, name="kda_bwd")
 
     out = call_by_platform(call, q, k, v, g, beta, *extra, starts, d_y,

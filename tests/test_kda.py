@@ -124,3 +124,71 @@ def test_the_state_is_what_the_gauges_count():
     assert kda.chunks_of(8192) == 128
     assert kda.chunks_of(8193) == 129
     assert kda.state_bytes(32, 128, 128) == 32 * 128 * 128 * 4
+
+
+def _pinned(args, heads, chunk=16):
+    """The kernels' output, chunk-start states and every gradient at
+    `heads` a program (None: `heads_per_program`'s), the sequence padded
+    to whole chunks as `kda.kda` pads it."""
+    q, k, v, g, beta, gate, scale = args
+    S = q.shape[1]
+    pad = kda.chunks_of(S, chunk) * chunk - S
+    fill = lambda x: None if x is None else jnp.pad(
+        x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+    q, k, v, g, gate = map(fill, (q, k, v, g, gate))
+    rows = fill(beta).transpose(0, 2, 1)[..., None]
+    out, starts = kda._forward(q, k, v, g, rows, gate, scale, chunk=chunk,
+                               eps=EPS, heads=heads)
+    d_y = jnp.asarray(np.random.default_rng(1).normal(size=out.shape),
+                      jnp.float32)
+    grads = kda._backward(q, k, v, g, rows, gate, scale, starts, d_y,
+                          chunk=chunk, eps=EPS, heads=heads)
+    return [out, starts] + [x for x in grads if x is not None]
+
+
+@pytest.mark.parametrize("seq", [24, 32])
+@pytest.mark.parametrize("gated", [False, True])
+@pytest.mark.parametrize("heads", [1, 2, 3, 4, 6, 8])
+def test_several_heads_a_program_equal_one(heads, gated, seq):
+    """A program that takes Hb heads computes each head as a program of
+    one does: outputs, chunk-start states and gradients at every divisor
+    Hb of the heads, and at `heads_per_program`'s choice, against Hb = 1.
+    On the chip they are equal to the bit; interpreted on the CPU, XLA's
+    batched and unbatched products may round a last bit apart, so the
+    limit here is 1e-6 of each array's largest entry."""
+    rng = np.random.default_rng(heads)
+    shape = (1, seq, heads * D)
+    q, v, gate = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                  for _ in range(3))
+    k = jnp.asarray(np.abs(rng.normal(size=shape)) + 0.3, jnp.float32)
+    g = jnp.asarray(-rng.uniform(0.0, 2.0, size=shape), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, size=(1, seq, heads)),
+                       jnp.float32)
+    scale = jnp.asarray(rng.uniform(0.5, 1.5, size=(D,)), jnp.float32)
+    args = (q, k, v, g, beta) + ((gate, scale) if gated else (None, None))
+    want = _pinned(args, 1)
+    chosen = kda.heads_per_program(heads, D, D)
+    for n in [n for n in range(2, heads + 1)
+              if heads % n == 0 and n != chosen] + [None]:
+        got = _pinned(args, n)
+        assert len(got) == len(want) == (9 if gated else 7)
+        for i, (x, y) in enumerate(zip(got, want)):
+            assert x.shape == y.shape and x.dtype == y.dtype, (n, i)
+            limit = 1e-6 * float(jnp.max(jnp.abs(y)))
+            assert float(jnp.max(jnp.abs(x - y))) <= limit, (n, i)
+
+
+def test_heads_per_program_divides_the_heads():
+    """Kimi's 32 heads of 128 take the timed choice; a count it does not
+    divide falls to the largest divisor below it; one head takes one;
+    heads whose stacks overflow the kernels' VMEM take fewer."""
+    assert kda.heads_per_program(32, 128, 128) == kda.HEADS_PER_PROGRAM
+    assert kda.heads_per_program(6, 128, 128) == 3
+    assert kda.heads_per_program(5, 128, 128) == 1
+    assert kda.heads_per_program(2, 128, 128) == 2
+    assert kda.heads_per_program(1, 128, 128) == 1
+    assert kda.heads_per_program(32, 2048, 2048) == 1
+    for heads in range(1, 65):
+        n = kda.heads_per_program(heads, 128, 128)
+        assert heads % n == 0 and 1 <= n <= kda.HEADS_PER_PROGRAM
+        assert kda._vmem_bytes(n, 128, 128) <= kda.VMEM_LIMIT

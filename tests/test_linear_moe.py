@@ -233,11 +233,13 @@ def test_the_lowered_step_names_the_delta_rule_forward_and_backward(params):
 
 def test_the_gauges_count_chunks_and_states_where_the_block_is_traced():
     """1000 positions in chunks of 64 (16, the last one padded), two
-    batch rows of 4 heads of 16 x 16 float32 states."""
+    batch rows of 4 heads of 16 x 16 float32 states, all 4 heads in one
+    program."""
     model = _model()
     ids = jnp.zeros((2, 1000), jnp.int32)
     jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))
     assert telemetry.gauge("horovod_kda_chunks").value == 16
+    assert telemetry.gauge("horovod_kda_heads_per_program").value == 4
     carried = 2 * 4 * 16 * 16 * 4
     assert {what: telemetry.gauge("horovod_kda_state_bytes",
                                   labels={"what": what}).value

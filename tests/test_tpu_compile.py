@@ -144,6 +144,30 @@ def test_kda_kernels_compile_for_v5e_at_the_cell_shape(v5e_devices,
     assert temporaries < 2.5e9, temporaries
 
 
+def test_kda_kernels_compile_for_v5e_two_heads_a_program(v5e_devices,
+                                                        no_compile_cache):
+    """Ten heads of 128 take two a program (`heads_per_program`: four
+    does not divide them), so the narrower stacks lower for a v5e too,
+    forward and backward, at 1024 positions."""
+    B, S, H, D = 2, 1024, 10, 128
+    assert kda.heads_per_program(H, D, D) == 2
+    one = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("d",)), P())
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    args = [shape(B, S, H * D)] * 3 + [
+        shape(B, S, H * D, dtype=jnp.float32),
+        shape(B, S, H, dtype=jnp.float32), shape(B, S, H * D),
+        shape(D, dtype=jnp.float32)]
+
+    def loss(q, k, v, g, beta, gate, scale):
+        return jnp.sum(kda.kda(q, k, v, g, beta, gate=gate, scale=scale)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=range(7))).lower(
+        *args).compile().as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+
+
 def test_the_delta_rule_leaves_no_loop_in_the_step(v5e_devices,
                                                    no_compile_cache):
     """A step of delta-rule layers (dense feed-forwards, so that nothing
